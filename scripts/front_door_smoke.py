@@ -102,4 +102,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from textsummarization_on_flink_tpu.utils import (
+        set_default_compile_cache,
+    )
+
+    set_default_compile_cache()
     main()

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""Roofline lower bounds for the sweep's train configs, from XLA's own
-cost model.
+"""Roofline lower bounds for the train configs, from XLA's own cost
+model.
 
     python scripts/roofline.py [--configs train_b16,train_b64,...]
                                [--chip v5e] [--json]
-                               [--bench BENCH_ALL.jsonl]
+                               [--bench ROWS.jsonl]
 
 For each config this compiles the REAL train step on the current
 backend (CPU works: HLO flop counts are backend-portable; bytes
@@ -18,16 +18,16 @@ reports:
   * the compute floor (flops / peak bf16) and bandwidth floor
     (bytes / peak HBM) on the target chip, whichever is larger being
     the minimum achievable step time, with the implied max samples/s;
-  * the measured step time from BENCH_ALL.jsonl when a live record
-    with the matching run tag exists (measured/floor says how much of
-    the gap is left for dispatch latency and scan overhead).
+  * with `--bench FILE` (a JSONL of bench.py rows tagged by
+    BENCH_RUN_TAG), the measured step time of the newest row whose run
+    tag matches (measured/floor says how much of the gap is left for
+    dispatch latency and scan overhead).
 
-Why it exists (VERDICT r3 #4): an MFU number alone ("3.1%") reads as an
-indictment; the roofline says how much of that is physics.  E.g. at
-reference scale the pointer-generator step accesses ~12 GB — a ~15 ms
-bandwidth floor on one v5e regardless of FLOPs — so the measured 29 ms
-step was within 2x of the memory roofline, and the remaining levers
-(unroll, bf16 streams) attack bytes and scan latency, not FLOPs.
+Why it exists: an MFU number alone ("3.1%") reads as an indictment; the
+roofline says how much of that is physics.  E.g. at reference scale the
+pointer-generator step accesses ~12 GB — a ~15 ms bandwidth floor on
+one v5e regardless of FLOPs — so the remaining levers (unroll, bf16
+streams) attack bytes and scan latency, not FLOPs.
 
 The reference has no counterpart: its only instrumentation is per-step
 wall clock (run_summarization.py:223-226) on a CPU-pinned graph.
@@ -53,10 +53,10 @@ CHIPS = {
     "v6e": (918.0, 1640.0),
 }
 
-# sweep-row tag -> the SAME env mapping scripts/bench_all.sh uses; the
-# actual shapes come from bench._preset_overrides via hps_for(), so the
-# roofline always describes exactly the config the sweep measures (no
-# hand-duplicated values to drift).  train_tiny exists for fast tests
+# row tag -> the bench.py env that measures it; the actual shapes come
+# from bench._preset_overrides via hps_for(), so the roofline always
+# describes exactly the config the bench measures (no hand-duplicated
+# values to drift).  train_tiny exists for fast tests
 # (unroll=1: tracing cost scales with the unrolled scan body; the
 # flop/byte counts are unroll-invariant).
 CONFIGS = {
@@ -120,7 +120,7 @@ _BYTE_DIET_BASELINES = {
 
 
 def hps_for(tag: str, bench_mod):
-    """The exact HParams the sweep row measures: bench_all.sh's env
+    """The exact HParams the tagged bench row measures: the tag's env
     mapping + bench.bench_train's own construction."""
     from textsummarization_on_flink_tpu.config import HParams
 
@@ -283,8 +283,6 @@ def _cost_of(fn, *args):
     import jax
 
     ca = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}
 
@@ -350,14 +348,31 @@ def attribution_of(hps, full_step_cost=None):
     return phases
 
 
-def measured_rows(path: str) -> dict:
-    """Newest live measurement per run tag (bench_latest's definition)."""
-    if not os.path.exists(path):
+def measured_rows(path) -> dict:
+    """Newest measurement per run tag in a JSONL of bench.py rows (rows
+    with an "error" field are not measurements); {} without a file."""
+    if not path:
         return {}
-    from bench_latest import latest_by_tag
-
-    return {tag: rec for tag, rec in latest_by_tag(path).items()
-            if "error" not in rec and not rec.get("stale")}
+    best: dict = {}
+    with open(path) as f:
+        for line in f:
+            line = line.strip()
+            if not line:
+                continue
+            try:
+                rec = json.loads(line)
+            except ValueError:
+                continue
+            if (not isinstance(rec, dict) or "run" not in rec
+                    or "error" in rec):
+                continue
+            prev = best.get(rec["run"])
+            # captured_at is ISO-8601 UTC (lexicographic == time order);
+            # ties fall back to file order
+            if prev is None or str(rec.get("captured_at", "")) >= \
+                    str(prev.get("captured_at", "")):
+                best[rec["run"]] = rec
+    return best
 
 
 def main(argv=None):
@@ -372,7 +387,9 @@ def main(argv=None):
     ap.add_argument("--configs", default=default_cfgs)
     ap.add_argument("--chip", default="v5e", choices=sorted(CHIPS))
     ap.add_argument("--json", action="store_true")
-    ap.add_argument("--bench", default=os.path.join(REPO, "BENCH_ALL.jsonl"))
+    ap.add_argument("--bench", default=None,
+                    help="JSONL of bench.py rows to join measured step "
+                         "times from (no default: absent = no join)")
     ap.add_argument("--attribute", action="store_true",
                     help="also compile forward and fwd+bwd per config "
                          "(full-step cost is reused) and report the "

@@ -159,6 +159,11 @@ def distill_main() -> None:
 
 
 if __name__ == "__main__":
+    from textsummarization_on_flink_tpu.utils import (
+        set_default_compile_cache,
+    )
+
+    set_default_compile_cache()
     if "--distill" in sys.argv[1:]:
         distill_main()
     else:

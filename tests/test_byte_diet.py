@@ -40,14 +40,34 @@ def family_hps(family: str, **kw) -> HParams:
     return HParams(**base)
 
 
-def _grad_parity(loss_fn, params, hps_a, hps_b, rel=1e-6, atol=0.0):
+def _grad_parity(loss_fn, params, hps_a, hps_b, rel=1e-6, atol=0.0,
+                 bf16_leaves=()):
+    """Leaf-wise gradient agreement: rel of the leaf's scale plus atol.
+    A leaf whose path is in `bf16_leaves` has its gradient ROUNDED to
+    bf16 on the way out, so each of ITS elements may in addition land
+    one bf16 ulp (2^-7 of the element at most) away."""
     ga = jax.grad(loss_fn)(params, hps_a)
     gb = jax.grad(loss_fn)(params, hps_b)
-    for a, b in zip(jax.tree_util.tree_leaves(ga),
-                    jax.tree_util.tree_leaves(gb)):
+    seen = set()
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(ga)[0],
+                            jax.tree_util.tree_leaves(gb)):
         a, b = np.asarray(a), np.asarray(b)
         scale = np.max(np.abs(a)) + 1e-12
-        assert np.max(np.abs(a - b)) <= rel * scale + atol
+        allowed = rel * scale + atol
+        name = jax.tree_util.keystr(path)
+        if name in bf16_leaves:
+            seen.add(name)
+            allowed = allowed + 2.0 ** -7 * np.abs(a)
+        assert np.all(np.abs(a - b) <= allowed), name
+    assert seen == set(bf16_leaves)
+
+
+# the leaves whose gradient leaves the vocabulary projection's bf16
+# matmul already rounded to bf16 (compute_dtype="bfloat16")
+BF16_ROUNDED_GRADS = {
+    "pointer_generator": ("['output_projection']['w']",),
+    "transformer": ("['embedding']",),  # tied: it IS the projection
+}
 
 
 class TestStreamingLossParity:
@@ -87,9 +107,16 @@ class TestStreamingLossParity:
             pytest.approx(float(loss(params, hps)), rel=1e-5)
         # looser than the f32 pin: bf16-rounded operands make the chunked
         # dw accumulation order visible at ~1e-4 rel, and near-zero
-        # leaves (max ~1e-6) need an atol floor
+        # leaves (max ~1e-6) need an atol floor.  Only the
+        # vocab-projection weight's gradient is itself rounded to bf16,
+        # so a different accumulation order lands one bf16 ulp away
+        # (2.1e-8 on pg's w at magnitude 5.6e-6, 1.6e-3 on the
+        # transformer's tied embedding at 0.27): that leaf alone gets
+        # the per-element ulp, every other leaf keeps rel/atol as they
+        # were
         _grad_parity(loss, params, hps, hps.replace(loss_chunk=CHUNK),
-                     rel=1e-4, atol=1e-8)
+                     rel=1e-4, atol=1e-8,
+                     bf16_leaves=BF16_ROUNDED_GRADS[family])
 
     def test_chunk_larger_than_t_and_chunk_one(self):
         """Degenerate chunk sizes: 1 (maximum streaming) and > T_dec
@@ -157,7 +184,11 @@ class TestStreamingLossParity:
 
         scores_bytes = T * B * V * 4
         assert temp_of(mat_loss) > 1.5 * scores_bytes
-        assert temp_of(chunk_loss) < 0.5 * scores_bytes
+        # four [chunk, B, V] buffers live at once under the installed
+        # XLA:CPU: 0.502 of the scores tensor (the bound was 0.5 when an
+        # older XLA kept it just under), held here with a margin of one
+        # more small buffer, not of a fifth [chunk, B, V] (0.625)
+        assert temp_of(chunk_loss) < 0.52 * scores_bytes
 
 
 class TestBf16OptState:

@@ -604,3 +604,40 @@ class TestIngressLockDiscipline:
             remote._send_ingress("hello")
         assert attempts == [False, False]
         assert not remote._ingress_lock.locked()
+
+
+# -- the child entry point itself (PR 22) -----------------------------------
+
+def test_stub_child_serves_through_the_real_entry_point(tmp_path):
+    """A REAL OS child: `python -m ...cli serve-replica` with the stub
+    engine, through the portfile handshake, one request out and back,
+    then the SIGTERM ladder.  Everything above fakes the child; a child
+    entry point that dies at its first line must not pass tier-1."""
+    fleet = procfleet.ProcFleet(
+        _hps(serve_mode="continuous"), registry=Registry(),
+        state_dir=str(tmp_path), stub=True, replicas=1).start()
+    try:
+        assert fleet.wait_ready(timeout=60.0), (
+            f"stub child never became ready: exit code "
+            f"{fleet.procs[0].last_exit_code}")
+        child = fleet.procs[0]
+        assert child.ports()["pid"] == child.pid() != os.getpid()
+        result = fleet.router.submit("w1 w2 w3", uuid="u1").result(
+            timeout=30.0)
+        assert result.uuid == "u1" and result.decoded_words == ["ok", "."]
+    finally:
+        fleet.stop()
+    assert child.state == child.STOPPED and child.deaths == 0
+
+
+def test_tpu_claims_reads_the_installed_jax():
+    """`_tpu_claims` reads two private corners of jax; this runs it
+    unfaked so a jax that moves either fails here, not on the chip."""
+    import jax
+    from jax._src import xla_bridge
+
+    assert jax.devices()[0].platform == "cpu"
+    # the table start()'s guard reads is the one jax fills on first use
+    assert "cpu" in xla_bridge._backends
+    held, chips = procfleet._tpu_claims()
+    assert held is False and isinstance(chips, int) and chips >= 0

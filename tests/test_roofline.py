@@ -52,29 +52,28 @@ def test_byte_diet_lever_configs_resolve():
         assert tag in roofline.CONFIGS and base in roofline.CONFIGS
 
 
-def test_measured_join_uses_live_records_only(tmp_path):
-    path = tmp_path / "BENCH_ALL.jsonl"
+def test_measured_join_uses_measurements_only(tmp_path):
+    path = tmp_path / "rows.jsonl"
     rows = [
         {"metric": "train_samples_per_sec", "run": "train_b16",
          "value": 600.0, "step_time_ms": 26.7,
          "captured_at": "2026-07-30T10:00:00Z"},
         {"metric": "train_samples_per_sec", "run": "train_b64",
          "value": 0.0, "error": "timed out"},
-        {"metric": "train_samples_per_sec", "run": "train_scaled",
-         "value": 300.0, "step_time_ms": 50.0, "stale": True,
+        {"metric": "train_samples_per_sec", "run": "train_b16",
+         "value": 500.0, "step_time_ms": 32.0,
          "captured_at": "2026-07-30T09:00:00Z"},
     ]
     path.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
     m = roofline.measured_rows(str(path))
-    assert set(m) == {"train_b16"}  # error + stale rows excluded
-    assert m["train_b16"]["step_time_ms"] == 26.7
-    assert roofline.measured_rows(str(tmp_path / "missing.jsonl")) == {}
+    assert set(m) == {"train_b16"}  # error rows are not measurements
+    assert m["train_b16"]["step_time_ms"] == 26.7  # newest wins
+    assert roofline.measured_rows(None) == {}  # no file, no join
 
 
 @pytest.mark.slow
 def test_cli_json_smoke(capsys):
-    rc = roofline.main(["--configs", "train_tiny", "--json",
-                        "--bench", "/nonexistent"])
+    rc = roofline.main(["--configs", "train_tiny", "--json"])
     assert rc == 0
     out = [json.loads(l) for l in
            capsys.readouterr().out.strip().splitlines()]

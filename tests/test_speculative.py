@@ -142,6 +142,14 @@ def test_spec_exact_under_reject_at_0():
     draft["out_bias"] = draft["out_bias"].at[7].set(1e4)
     params = dict(params)
     params["out_bias"] = params["out_bias"].at[7].set(-1e4)
+    # ...and pin both models' p_gen to 1: through the pointer mixture
+    # the draft can still COPY some other token past its vocabulary
+    # bias (and the full model copy a 7), and which side wins that
+    # near-tie depends on the host's CPU codegen
+    for model in (draft, params):
+        model["pgen_linear"] = dict(
+            model["pgen_linear"],
+            bias=model["pgen_linear"]["bias"] + 1e4)
     arrays = make_arrays(hps, 3)
     spec = assert_spec_matches_greedy(params, draft, hps, arrays)
     assert int(spec.accepted.sum()) == 0

@@ -35,6 +35,7 @@ from textsummarization_on_flink_tpu.data.etl import raw_text_example_source
 from textsummarization_on_flink_tpu.data.vocab import Vocab
 from textsummarization_on_flink_tpu.decode.decoder import BeamSearchDecoder
 from textsummarization_on_flink_tpu.train import trainer as trainer_lib
+from textsummarization_on_flink_tpu.utils import set_default_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -131,6 +132,7 @@ def run_decode(hps: HParams, vocab: Vocab,
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    set_default_compile_cache()
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(levelname)s %(name)s: %(message)s")

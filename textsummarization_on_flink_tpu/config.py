@@ -119,15 +119,14 @@ class HParams:
     # Train-loop steps per host->device dispatch (the TPU-idiomatic
     # steps_per_execution pattern): k>1 runs k optimizer steps as ONE
     # on-device lax.scan over k stacked batches, cutting host round
-    # trips k-fold — decisive on RPC-proxied backends where every
-    # dispatch pays a tunnel round trip.  Numerically identical to k=1
+    # trips k-fold.  Numerically identical to k=1
     # (same ops, same order).  Checkpoint/metrics cadences quantize to
     # dispatch boundaries; --debug forces k=1 (step-exact NaN watchdog).
     steps_per_dispatch: int = 1
     # lax.scan unroll factor for the LSTM encoder / decoder recurrences
     # (pointer-generator family).  The step is LATENCY-bound: ~500
-    # sequential scan iterations of small matmuls dominate the 29 ms
-    # measured step (BASELINE.md), so amortizing per-iteration loop
+    # sequential scan iterations of small matmuls dominate the step
+    # (PERF.md Findings), so amortizing per-iteration loop
     # overhead across k unrolled bodies is the lever XLA can't pull
     # itself.  Numerically identical at any value; raises compile time
     # with k.  1 = no unrolling.
@@ -939,6 +938,36 @@ def resolve_tenant_burst(hps: "HParams") -> int:
     if hps.serve_tenant_burst:
         return hps.serve_tenant_burst
     return max(1, int(hps.serve_tenant_rate + 0.999999))
+
+
+def resolve_beam_loop(kind: Optional[str] = None) -> str:
+    """Resolve the decode-loop construct: 'while' (early exit once every
+    article's beam finishes), 'scan' (fixed max_dec_steps trip count),
+    or 'chunked' (while over TS_BEAM_CHUNK-step scan chunks — early exit
+    at chunk granularity with only ceil(T/C) dynamic iterations).
+
+    All three produce IDENTICAL results: under vmap a while_loop already
+    applies masked per-article updates until the slowest article's cond
+    goes false; scan merely fixes the trip count at the worst case, and
+    chunked interleaves the two at chunk granularity (pinned token-exact
+    by test_beam_search's tail-chunk parity suite and, on the chip, by
+    chip_smoke.py's decode phase).
+
+    TS_BEAM_LOOP=while|scan|chunked|auto; auto (the default) is chunked
+    on every backend — no environment or backend probe decides it.
+    The ONE resolver: decode/beam_search.py routes on it (as
+    `_loop_kind`) and bench.py's row label records it.
+    """
+    import os
+
+    kind = (kind or os.environ.get("TS_BEAM_LOOP", "auto")).lower()
+    if kind == "auto":
+        return "chunked"
+    if kind not in ("while", "scan", "chunked"):
+        raise ValueError(
+            f"beam loop kind must be while|scan|chunked|auto, got {kind!r} "
+            f"(TS_BEAM_LOOP or the loop= argument)")
+    return kind
 
 
 def beam_chunk_from_env() -> int:

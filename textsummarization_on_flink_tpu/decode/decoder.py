@@ -101,7 +101,8 @@ class DecodedResult:
                  attn_dists: Optional[np.ndarray] = None,
                  p_gens: Optional[np.ndarray] = None,
                  degraded: bool = False, tier: str = "beam",
-                 params_fingerprint: str = ""):
+                 params_fingerprint: str = "",
+                 avg_log_prob: float = 0.0):
         self.uuid = uuid
         self.article = article
         self.decoded_words = decoded_words
@@ -121,6 +122,11 @@ class DecodedResult:
         # snapshot that made it, never the one that replaced it ("" =
         # producer without the surface: stubs, sim engines)
         self.params_fingerprint = params_fingerprint
+        # the winning hypothesis' length-normalized log probability
+        # (BeamSearchOutput.avg_log_prob): what a cross-engine or
+        # cross-device comparison needs to tell a near-tie flipped by
+        # reduced-precision matmuls from a wrong search (chip_smoke.py)
+        self.avg_log_prob = avg_log_prob
 
     @property
     def decoded_sents(self) -> List[str]:
@@ -511,7 +517,8 @@ class BeamSearchDecoder:
                 continue
             results.append(self._make_result(
                 out.tokens[b], int(out.length[b]), out.attn_dists[b],
-                out.p_gens[b], uuid=batch.uuids[b],
+                out.p_gens[b], avg_log_prob=float(out.avg_log_prob[b]),
+                uuid=batch.uuids[b],
                 article=batch.original_articles[b],
                 reference=batch.references[b],
                 abstract_sents=batch.original_abstracts_sents[b],
@@ -522,7 +529,7 @@ class BeamSearchDecoder:
                      uuid: str, article: str, reference: str,
                      abstract_sents: List[str],
                      art_oovs: List[str], tier: str = "beam",
-                     ) -> DecodedResult:
+                     avg_log_prob: float = 0.0) -> DecodedResult:
         """One article's raw beam output -> DecodedResult: START strip,
         id->word mapping through the article's OOVs, [STOP] truncation
         (decode.py:112-118).  Shared by the batch path and the slot
@@ -543,7 +550,7 @@ class BeamSearchDecoder:
             abstract_sents=abstract_sents,
             attn_dists=attn_dists[: max(len(decoded_words), 1)],
             p_gens=p_gens[: max(len(decoded_words), 1)],
-            tier=tier,
+            tier=tier, avg_log_prob=avg_log_prob,
             # the fingerprint memo is keyed on the snapshot object, so
             # this is a dict read per result, not a sha — and a swap
             # landing mid-batch at worst stamps the NEW snapshot on a
@@ -1014,6 +1021,7 @@ class SlotDecodeEngine:
         res = self._dec._make_result(
             np.asarray(out.tokens), int(out.length),
             np.asarray(out.attn_dists), np.asarray(out.p_gens),
+            avg_log_prob=float(out.avg_log_prob),
             uuid=example.uuid, article=example.original_article,
             reference=example.reference,
             abstract_sents=example.original_abstract_sents,

@@ -11,7 +11,7 @@ family-agnostic:
 
 Select with ``hps.model_family`` (the reference has a single hardcoded
 model, run_summarization.py:376; the family seam is a rebuild addition
-that the BASELINE.md stretch config requires).
+for the second, transformer family).
 
 The third family, ``avg_attention``, is the speculative tier's draft
 (O(1)-in-history decode state); it honors two extra HParams the other

@@ -2,8 +2,8 @@
 
 The reference repository's only model is the LSTM pointer-generator
 (/root/reference/src/main/python/pointer-generator/model.py); this module
-is the framework's second model family — the BASELINE.md stretch row
-("BART-base behind the same Estimator/Model API") — sharing every
+is the framework's second model family ("BART-base behind the same
+Estimator/Model API") — sharing every
 surrounding subsystem: the same ``HParams``, the same ``Batch`` arrays,
 the same ``TrainOutput`` contract consumed by the Trainer/Evaluator, the
 same on-device beam search (via the beam-adapter protocol in
@@ -193,12 +193,13 @@ def _use_flash(hps: HParams, T: int) -> bool:
     it on ANY shape — unaligned T/head_dim are zero-padded to the 128
     grid by the caller (exact numerics; extra FLOPs), which is the
     roofline-motivated A/B for the bandwidth-bound reference scale
-    (T=400, hd=32 — BASELINE.md: the einsum path's materialized f32
-    score tensors dominate the transformer step's bytes).  =off
+    (T=400, hd=32 — scripts/roofline.py: the einsum path's materialized
+    f32 score tensors dominate the transformer step's bytes).  =off
     disables; auto (the FROZEN default) keeps the conservative
-    natively-aligned T>=1024 rule.  Either way the kernel is TPU-only
-    (its Mosaic lowering has no CPU/GPU path), so a non-TPU backend
-    always falls through to the einsum formula.  Cross-attention never
+    natively-aligned T>=1024 rule.  The kernel is TPU-only (its Mosaic
+    lowering has no CPU/GPU path): under auto a non-TPU backend takes
+    the einsum formula, and TS_FLASH=on there is an error, never a
+    silent formula run under the kernel's name.  Cross-attention never
     uses it — its probabilities ARE the copy distribution and must be
     materialized anyway."""
     from textsummarization_on_flink_tpu.config import flash_mode_from_env
@@ -206,14 +207,16 @@ def _use_flash(hps: HParams, T: int) -> bool:
     mode = flash_mode_from_env()
     if mode == "off":
         return False
+    on_tpu = jax.default_backend() == "tpu"
+    if mode == "on":
+        if not on_tpu:
+            raise ValueError(
+                f"TS_FLASH=on needs a TPU backend (the Pallas flash "
+                f"kernel has no {jax.default_backend()!r} lowering); "
+                f"unset it or use TS_FLASH=auto|off")
+        return True
     hd = _head_dim(hps)
     aligned = T % 128 == 0 and hd % 128 == 0
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - tslint: disable=TS005 — backend probe: any init failure means "not TPU"
-        on_tpu = False
-    if mode == "on":
-        return on_tpu
     return on_tpu and aligned and T >= 1024
 
 

@@ -320,7 +320,8 @@ def _use_pallas() -> bool:
     """auto (default) prefers the XLA formula: on-device A/B at both
     reference scale (B16 T400 D512) and long context (B4 T4096 D512)
     measured the Pallas kernels at 0.99x / 0.94x of XLA on TPU v5e
-    (BASELINE.md round-2 attention_ab) — XLA's own fusion of this
+    (a 2026-07 run on code older than today's; PERF.md Findings) —
+    XLA's own fusion of this
     additive-attention chain is already near-roofline, so the kernels
     stay opt-in (TS_PALLAS=on) and serve the VMEM-constrained sp path
     (blocked variant) rather than the default train step."""

@@ -15,7 +15,7 @@ program over a `jax.sharding.Mesh`:
     embedding table are sharded over the vocab axis; XLA inserts the
     all-gather / reduce-scatter.
   * **sp** axis: context parallelism over the encoder sequence axis for the
-    long-context configs (BASELINE.json configs[3]) — encoder states,
+    long-context configs (hidden 512, enc 800) — encoder states,
     attention energies, and coverage shard over T_enc; the per-step context
     reduction becomes a psum.  (The LSTM time scan itself is sequential, so
     sp shards the *attention/feature* tensors, which dominate memory at
@@ -171,18 +171,16 @@ def _make_wire_grad_fn(plan: MeshPlan, reg: sharding_lib.ShardingRegistry,
     """(params, arrays) -> (grads, scalar losses) with the dp gradient
     all-reduce riding the wire in the registry's annotated dtype.
 
-    Mechanism (ISSUE 8; see sharding.py's module docstring for why the
-    shard_map route is closed on this jax): the batch regroups
-    ``[B] -> [dp, B/dp]`` under a `P("dp", ...)` constraint, per-group
-    grads come from ONE vmap'd jax.grad (each dp shard computes exactly
-    its local rows, as under shard_map), the stacked grads are cast to
+    Mechanism (ISSUE 8; sharding.py's module docstring): the batch
+    regroups ``[B] -> [dp, B/dp]`` under a `P("dp", ...)` constraint,
+    per-group grads come from ONE vmap'd jax.grad (each dp shard
+    computes exactly its local rows), the stacked grads are cast to
     the wire dtype under a ``P("dp", *param_spec)`` constraint, and the
     group-axis sum is partitioned by XLA into the dp all-reduce at that
     dtype — spec-level wire annotation, collective inserted by the
     partitioner.  f32 is restored before clip/Adagrad; forward-internal
     tp collectives stay wherever GSPMD puts them, which is what makes
-    this compose with dp x tp meshes (the retired shard_map step was
-    pure-dp-only).
+    this compose with dp x tp meshes.
 
     Requirements (validated in HParams.validate and here): sp == 1, and
     pointer_gen losses — their per-example normalization makes the

@@ -47,32 +47,6 @@ def _block_attn(q: Array, k: Array, kmask: Array,
     return logits
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis, across jax versions: lax.axis_size
-    where it exists (>= 0.6), else the classic psum-of-1 idiom (a static
-    python int under shard_map on 0.4.x)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def compat_shard_map(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions — the ONE dispatch site (used here
-    and by parallel/mesh.py's low-precision all-reduce step): jax >= 0.6
-    has first-class jax.shard_map (check_vma); 0.4.x has the
-    experimental module (check_rep), where a scalar's spec must be a
-    fully-replicated P() rather than None."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    if isinstance(in_specs, tuple):
-        in_specs = tuple(P() if s is None else s for s in in_specs)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def ring_self_attention(q: Array, k: Array, v: Array, kv_mask: Array,
                         axis_name: str, sm_scale: float) -> Array:
     """One shard's view: q/k/v [B, T_blk, nh, hd], kv_mask [B, T_blk].
@@ -81,7 +55,7 @@ def ring_self_attention(q: Array, k: Array, v: Array, kv_mask: Array,
     ring of sp devices.  Returns the attention output [B, T_blk, nh, hd]
     for the local queries against the GLOBAL key/value sequence.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, Tb, nh, hd = q.shape
     perm = [(j, (j + 1) % n) for j in range(n)]
 
@@ -178,9 +152,9 @@ def make_sp_attention(mesh: Mesh, mode: str, axis_name: str = "sp"):
     batch = "dp" if mesh.shape.get("dp", 1) > 1 else None
     spec4 = P(batch, axis_name, None, None)
     spec2 = P(batch, axis_name)
-    return compat_shard_map(fn, mesh,
-                            in_specs=(spec4, spec4, spec4, spec2, None),
-                            out_specs=spec4)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(spec4, spec4, spec4, spec2, None),
+                         out_specs=spec4, check_vma=False)
 
 
 # --------------------------------------------------------------------------

@@ -39,9 +39,9 @@ the batch ``[B] -> [dp, B/dp]``, computes per-group grads under `vmap`,
 casts the stacked grads to the wire dtype under a sharding constraint
 ``P("dp", *param_spec)``, and sums over the group axis — XLA's
 partitioner turns that sum into the dp all-reduce at the wire dtype.
-(jax 0.4.x's `shard_map(auto=...)` hard-crashes XLA's partitioner on
-this scan-heavy model, so the manual-collective route is closed; the
-constraint+sum route keeps the whole step ONE pjit program.)
+The constraint+sum route keeps the whole step ONE pjit program: no
+manual collective, so forward-internal tp collectives stay wherever
+GSPMD puts them.
 
 Note on CPU HLO: the CPU backend promotes sub-f32 all-reduces to f32
 around a convert pair, so a faked-mesh compile shows an f32 wire with

@@ -27,6 +27,13 @@ RuntimeError/OSError, so callers can route on failure *class*:
     (not in decode/) so the jax-free serve scheduler can catch it
     without importing the jax-heavy decode package.
 
+  * ``DeviceOwnershipError`` — a launch would put more than one process
+    on one accelerator (serve/procfleet.ProcFleet.start: the parent
+    already holds a TPU backend, or several real children would each
+    claim the host's chips).  Raised BEFORE any child spawns: the
+    alternative on a TPU host is a child that fails or hangs at its
+    first device touch.
+
 ``NanLossError`` (divergence recovery gave up) lives in
 train/trainer.py next to its ``NonFiniteLossError`` base — the trainer
 owns the watchdog contract and this package must stay import-light.
@@ -71,3 +78,7 @@ class ArenaExhaustedError(ResilienceError):
         super().__init__(message)
         self.needed = int(needed)
         self.free = int(free)
+
+
+class DeviceOwnershipError(ResilienceError):
+    """A launch would put more than one process on one accelerator."""

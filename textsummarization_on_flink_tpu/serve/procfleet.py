@@ -78,6 +78,9 @@ from textsummarization_on_flink_tpu.obs import http as obs_http
 from textsummarization_on_flink_tpu.obs import locksan
 from textsummarization_on_flink_tpu.pipeline.io import Message, ResilientSource
 from textsummarization_on_flink_tpu.resilience import faultinject
+from textsummarization_on_flink_tpu.resilience.errors import (
+    DeviceOwnershipError,
+)
 from textsummarization_on_flink_tpu.resilience.policy import (
     CircuitBreaker,
     RetryPolicy,
@@ -91,6 +94,7 @@ from textsummarization_on_flink_tpu.serve.errors import (
 )
 from textsummarization_on_flink_tpu.serve.queue import ServeFuture
 from textsummarization_on_flink_tpu.serve.router import ReplicaHandle
+from textsummarization_on_flink_tpu.utils import set_default_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -991,6 +995,41 @@ class RemoteReplicaHandle(ReplicaHandle):
 # The assembled process fleet
 # --------------------------------------------------------------------------
 
+def _tpu_claims() -> Tuple[bool, int]:
+    """(this process has initialised a TPU backend, TPU chips on this
+    host) — without initialising a backend.  The ONE place that reads
+    jax's private modules: its backend table (a parent that never
+    imported jax holds nothing) and its pre-init PCI probe.
+    tests/test_procfleet.py::test_tpu_claims_reads_the_installed_jax
+    fails when either moves."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    held = xb is not None and "tpu" in xb._backends
+    from jax._src import hardware_utils
+
+    return held, hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _chip_conflict(n_children: int, child_platforms: str) -> Optional[str]:
+    """Why `n_children` REAL replica children cannot each own a chip
+    when launched from this process, or None.  A TPU belongs to one
+    process at a time and no child is pinned to a chip, so on a TPU
+    host a child either finds the parent holding the device or races
+    its siblings for all of the host's chips — it fails or hangs at its
+    first device touch.  Children forced onto the CPU never touch it."""
+    platforms = [p.strip() for p in child_platforms.lower().split(",")
+                 if p.strip()]
+    if platforms and "tpu" not in platforms:
+        return None
+    held, chips = _tpu_claims()
+    if held:
+        return ("this process has already initialised a TPU backend and "
+                "holds the chip; a replica child cannot open it")
+    if n_children > 1 and chips > 0:
+        return (f"{n_children} replica children would each claim this "
+                f"host's TPU chips (no child is pinned to a chip)")
+    return None
+
+
 class ProcFleet:
     """N supervised child replicas behind one FleetRouter.
 
@@ -1043,6 +1082,10 @@ class ProcFleet:
         base_env[ENV_IN_FLEET] = "1"
         if stub:
             base_env[ENV_STUB] = "1"
+        # what start()'s one-process-per-chip guard needs: stub
+        # children run no model and never touch a device
+        self._real_children = base_env.get(ENV_STUB) != "1"
+        self._child_platforms = base_env.get("JAX_PLATFORMS", "")
         scrape_timeout_s = max(
             0.001, getattr(hps, "serve_scrape_timeout_ms", 250.0) / 1000.0)
         self.procs: List[ReplicaProcess] = []
@@ -1084,7 +1127,14 @@ class ProcFleet:
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> "ProcFleet":
-        """Spawn every child + reader, start routing + supervision."""
+        """Spawn every child + reader, start routing + supervision.
+        Raises the typed ``DeviceOwnershipError`` before spawning when
+        real children could not each own a chip (``_chip_conflict``)."""
+        if self._real_children:
+            reason = _chip_conflict(len(self.procs), self._child_platforms)
+            if reason is not None:
+                raise DeviceOwnershipError(
+                    f"process fleet refused to start: {reason}")
         self.router.start()  # calls RemoteReplica.start() per replica
         if self._thread is None or not self._thread.is_alive():
             self._stop_evt.clear()
@@ -1381,6 +1431,7 @@ def replica_child_main(argv: Optional[List[str]] = None) -> int:
     from TS_HPS_JSON, bind obs-HTTP + ingress + reply sockets on
     ephemeral ports, publish them through the portfile handshake, serve
     until SIGTERM."""
+    set_default_compile_cache()
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(levelname)s %(name)s: %(message)s")
