@@ -26,6 +26,7 @@ hit/miss counters + ledger keys) and the /profile HTTP route.
 import gc
 import json
 import sys
+import time
 import urllib.request
 
 import jax
@@ -427,3 +428,305 @@ class TestProfileRoute:
             assert payload["phases"] == []
         finally:
             srv.close()
+
+
+# ---- the one bracket: Profiler.phase / Profiler.wall -------------------
+
+class _RecordingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: same constructor and
+    context protocol, keeps what was opened and closed."""
+
+    log = []
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.attrs))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.attrs))
+
+
+class TestPhaseBracket:
+    def _prof(self, monkeypatch):
+        reg = Registry()
+        clock = ScriptClock()
+        prof = profile_lib.install_profiler(reg, clock=clock.now)
+        _RecordingAnnotation.log = []
+        monkeypatch.setattr(profile_lib, "_trace_annotation",
+                            lambda: _RecordingAnnotation)
+        return reg, clock, prof
+
+    def test_books_ledger_span_and_annotation_once_each_and_nests(
+            self, monkeypatch):
+        reg, clock, prof = self._prof(monkeypatch)
+        with prof.wall("serve/tick"):
+            with prof.phase("serve/harvest", trace_id="tr-9") as outer:
+                clock.advance(0.001)
+                with prof.phase("serve/harvest/unpack", slot=3) as inner:
+                    clock.advance(0.002)
+        assert inner.dt == pytest.approx(0.002)
+        assert outer.dt == pytest.approx(0.003)
+        # ledger: both booked once; the child is in the table and out of
+        # coverage (its parent already accounts for the interval)
+        stats = prof.phase_stats()
+        assert stats["serve/harvest"][0] == 1
+        assert stats["serve/harvest/unpack"] == (
+            1, pytest.approx(0.002), pytest.approx(0.002))
+        assert prof.coverage() == pytest.approx(1.0)
+        assert reg.histogram("profile/phase_seconds").labels(
+            phase="serve/harvest/unpack").count == 1
+        # the ring (the harness names idle gaps from it) keeps top-level
+        # phases only, with the exemplar
+        assert [(r[1], r[3]) for r in prof.recent_phases()] == [
+            ("serve/harvest", "tr-9")]
+        # spans: one each, nested, attrs on the child
+        spans = {s.name: s for s in obs.tracer_for(reg).finished()}
+        assert set(spans) == {"serve/harvest", "serve/harvest/unpack"}
+        assert spans["serve/harvest/unpack"].parent == "serve/harvest"
+        assert spans["serve/harvest/unpack"].attrs == {"slot": 3}
+        assert spans["serve/harvest"].parent is None
+        # annotations: wall ⊃ phase ⊃ child, each opened and closed once
+        assert [(k, n) for k, n, _ in _RecordingAnnotation.log] == [
+            ("enter", "serve/tick"), ("enter", "serve/harvest"),
+            ("enter", "serve/harvest/unpack"),
+            ("exit", "serve/harvest/unpack"), ("exit", "serve/harvest"),
+            ("exit", "serve/tick")]
+        assert _RecordingAnnotation.log[2][2] == {"slot": 3}
+
+    def test_flat_end_inside_a_phase_is_a_child_too(self, monkeypatch):
+        """compiled_call(phase=...) books through start()/end(): inside
+        a phase() it must not count twice toward coverage."""
+        _, clock, prof = self._prof(monkeypatch)
+        with prof.wall("serve/tick"):
+            with prof.phase("serve/dispatch"):
+                t0 = prof.start()
+                clock.advance(0.004)
+                prof.end("decode/beam_search", t0)
+        assert prof.phase_stats()["decode/beam_search"][0] == 1
+        assert prof.coverage() == pytest.approx(1.0)
+        assert [r[1] for r in prof.recent_phases()] == ["serve/dispatch"]
+
+    def test_a_raising_body_still_closes_everything(self, monkeypatch):
+        reg, clock, prof = self._prof(monkeypatch)
+        with pytest.raises(RuntimeError):
+            with prof.phase("serve/dispatch"):
+                clock.advance(0.005)
+                raise RuntimeError("boom")
+        assert prof.phase_stats()["serve/dispatch"][1] == \
+            pytest.approx(0.005)
+        assert [k for k, _, _ in _RecordingAnnotation.log] == [
+            "enter", "exit"]
+        assert len(obs.tracer_for(reg).finished()) == 1
+        # the depth unwound: the next phase is top-level again
+        with prof.phase("serve/pack"):
+            pass
+        assert [r[1] for r in prof.recent_phases()] == [
+            "serve/dispatch", "serve/pack"]
+
+    def test_cancelled_wall_books_nothing(self, monkeypatch):
+        _, clock, prof = self._prof(monkeypatch)
+        with prof.wall("serve/tick") as wall:
+            clock.advance(0.050)  # an idle tick's queue poll
+            wall.cancel()
+        assert profile_lib.profile_payload(prof._reg)["walls"] == []
+        with prof.wall("serve/tick"):
+            clock.advance(0.010)
+        walls = profile_lib.profile_payload(prof._reg)["walls"]
+        assert [(w["wall"], w["count"]) for w in walls] == [
+            ("serve/tick", 1)]
+
+    def test_phases_land_in_the_profilers_host_plane(self, tmp_path):
+        """With jax loaded the bracket IS a TraceAnnotation: a capture
+        holds parent and child on one thread's line, the child inside
+        the parent, on the profiler's clock."""
+        from jax.profiler import ProfileData
+        import glob
+
+        assert profile_lib._trace_annotation() is \
+            jax.profiler.TraceAnnotation
+        prof = profile_lib.install_profiler(Registry())
+        jax.profiler.start_trace(str(tmp_path))
+        with prof.phase("serve/harvest"):
+            with prof.phase("serve/harvest/unpack", slot=1):
+                time.sleep(0.001)
+        jax.profiler.stop_trace()
+        path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+        found = []
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                evs = {e.name: e for e in line.events
+                       if e.name.startswith("serve/harvest")}
+                if evs:
+                    found.append(evs)
+        assert len(found) == 1  # one thread, one line
+        outer, inner = (found[0]["serve/harvest"],
+                        found[0]["serve/harvest/unpack"])
+        assert outer.start_ns <= inner.start_ns
+        assert (inner.start_ns + inner.duration_ns
+                <= outer.start_ns + outer.duration_ns)
+        assert dict(inner.stats)["slot"] == 1
+
+    def test_obs_still_imports_without_jax(self):
+        import subprocess
+
+        code = ("import sys; "
+                "from textsummarization_on_flink_tpu.obs import profile; "
+                "from textsummarization_on_flink_tpu.obs.registry import "
+                "Registry; "
+                "p = profile.install_profiler(Registry()); "
+                "c = p.phase('serve/pack'); c.__enter__(); "
+                "c.__exit__(None, None, None); "
+                "assert 'jax' not in sys.modules, 'obs pulled jax in'; "
+                "assert c._ann is None")
+        out = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+
+    def test_null_brackets_are_shared_and_allocate_nothing(self):
+        prof = profile_lib.NULL_PROFILER
+        assert prof.phase("serve/pack", trace_id="t", slot=1) is \
+            profile_lib.NULL_BRACKET
+        assert prof.wall("serve/tick") is profile_lib.NULL_BRACKET
+
+        def burst(n):
+            for i in range(n):
+                with prof.wall("serve/tick") as wall:
+                    with prof.phase("serve/dispatch", fill=3) as ph:
+                        pass
+                    prof.observe_dispatch("serve/dispatch", "k", ph.dt)
+                    wall.cancel()
+
+        burst(64)
+        gc.collect()
+        before = sys.getallocatedblocks()
+        burst(512)
+        assert sys.getallocatedblocks() - before <= 16
+
+
+# ---- the request's stage clock -----------------------------------------
+
+class _StageEngine(_SimEngine):
+    """_SimEngine with a prefill surface, so all five stages exist, and
+    a little real time in each operation (the stage clock is the
+    request's monotonic clock, not the profiler's)."""
+
+    def prefill(self, example):
+        time.sleep(0.001)
+        return example
+
+    def pack(self, idx, example):
+        time.sleep(0.001)
+        super().pack(idx, example)
+
+    def unpack(self, idx, example):
+        time.sleep(0.002)
+        return super().unpack(idx, example)
+
+
+STAGES = ("queue", "prefill", "slot_wait", "resident", "harvest")
+
+
+class TestRequestStages:
+    def _server(self, tmp_path, reg, **hps_kw):
+        from textsummarization_on_flink_tpu.serve.server import (
+            ServingServer,
+        )
+        vocab = Vocab(words=["w"])
+        vclock = _VClock()
+        hps = HParams(
+            mode="decode", batch_size=2, vocab_size=vocab.size(),
+            max_enc_steps=8, max_dec_steps=8, beam_size=2,
+            min_dec_steps=1, max_oov_buckets=4, serve_max_queue=16,
+            serve_mode="continuous", serve_slots=2,
+            serve_refill_chunk=4, log_root=str(tmp_path),
+            exp_name="stage_gate", **hps_kw)
+        sim = _StageEngine(vclock, slots=2, chunk=4, steps_per_req=8,
+                           step_cost_ms=5.0, pack_cost_ms=1.0)
+        return ServingServer(hps, vocab, decoder=_NullDecoder(),
+                             engine=sim, registry=reg, clock=vclock.now)
+
+    def _stage(self, reg, stage):
+        return reg.histogram("serve/request_stage_seconds").labels(
+            stage=stage)
+
+    def _events(self, tmp_path):
+        by_uuid = {}
+        for ln in open(tmp_path / "events.jsonl", encoding="utf-8"):
+            r = json.loads(ln)
+            if r.get("kind") == "request":
+                by_uuid.setdefault(r["uuid"], []).append(r)
+        return by_uuid
+
+    def test_five_stages_sum_to_enqueue_to_resolve(self, tmp_path):
+        reg = Registry()
+        sink = obs.install_event_sink(str(tmp_path), flush_secs=0.05,
+                                      reg=reg)
+        server = self._server(tmp_path, reg)
+        futures = [server.submit("w w w", uuid=f"s{i}") for i in range(5)]
+        for _ in range(64):
+            if all(f.done() for f in futures):
+                break
+            server.tick_once(poll=0.0)
+        assert all(f.done() for f in futures)
+        server.stop()
+        sink.close()
+        # in the registry: every stage observed once per request, and the
+        # five sums add up to the latency histogram's (same marks)
+        for s in STAGES:
+            assert self._stage(reg, s).count == 5, s
+        e2e = reg.histogram("serve/e2e_latency_seconds")
+        assert e2e.count == 5
+        assert sum(self._stage(reg, s).sum for s in STAGES) == \
+            pytest.approx(e2e.sum, rel=1e-9)
+        # the harvest is inside the latency now: the second request of a
+        # harvest waited behind the first one's 2 ms unpack
+        assert self._stage(reg, "harvest").snapshot()["max"] >= 0.004
+        # in events.jsonl: each site carries its stage's milliseconds,
+        # and per request they add up to enqueue -> resolve
+        ms_of = {"admit": ["queue_ms"], "prefill": ["prefill_ms"],
+                 "slot": ["slot_wait_ms"],
+                 "finish": ["resident_ms", "harvest_ms"]}
+        for uuid, events in self._events(tmp_path).items():
+            total = 0.0
+            for e in events:
+                for key in ms_of.get(e["event"], []):
+                    total += e["attrs"][key]
+            fin = next(e for e in events if e["event"] == "finish")
+            assert total == pytest.approx(fin["attrs"]["e2e_ms"],
+                                          abs=0.004), uuid
+            span_ms = (events[-1]["ts_us"] - events[0]["ts_us"]) / 1e3
+            assert events[-1]["event"] == "resolve"
+            assert total == pytest.approx(span_ms, abs=2.0), uuid
+
+    def test_an_evicted_request_closes_the_stage_it_died_in(
+            self, tmp_path):
+        from textsummarization_on_flink_tpu.resilience.errors import (
+            DeadlineExceededError,
+        )
+        reg = Registry()
+        server = self._server(tmp_path, reg, decode_deadline_secs=0.03)
+        # dies in the queue: only `queue` is ever observed for it
+        dead = server.submit("w w w", uuid="dead")
+        time.sleep(0.04)
+        server.tick_once(poll=0.0)
+        with pytest.raises(DeadlineExceededError):
+            dead.result(timeout=0)
+        assert {s: self._stage(reg, s).count for s in STAGES} == {
+            "queue": 1, "prefill": 0, "slot_wait": 0, "resident": 0,
+            "harvest": 0}
+        # dies resident: closes `resident` at the eviction, never
+        # `harvest`
+        slow = server.submit("w w w", uuid="slow")
+        server.tick_once(poll=0.0)  # admitted, one chunk of two
+        assert not slow.done()
+        time.sleep(0.04)
+        server.tick_once(poll=0.0)
+        with pytest.raises(DeadlineExceededError):
+            slow.result(timeout=0)
+        server.stop()
+        assert {s: self._stage(reg, s).count for s in STAGES} == {
+            "queue": 2, "prefill": 1, "slot_wait": 1, "resident": 1,
+            "harvest": 0}

@@ -176,69 +176,72 @@ def _make_beam_body(params, hps: HParams, step_fn, enc_one, enc_mask,
                            s.latest)  # beam_search.py:112
         step = step_fn(params, enc_one, enc_mask, ext_ids, s.t, latest,
                        s.dec_state)
-        # candidate pool: every live hyp x its 2K continuations
-        cand_lp = s.sum_lp[:, None] + step.topk_log_probs  # [K, 2K]
-        # step 0: all hyps identical -> expand only hyp 0 (beam_search.py:125)
-        first = jnp.arange(K)[:, None] == 0
-        cand_lp = jnp.where(jnp.logical_or(s.t > 0, first), cand_lp, NEG)
-        flat_lp = cand_lp.reshape(S)
-        flat_tok = step.topk_ids.reshape(S)
-        order = jnp.argsort(-flat_lp)  # stable descending
-        srt_lp = flat_lp[order]
-        srt_tok = flat_tok[order]
-        parent = order // (2 * K)  # originating live hyp
+        with jax.named_scope("beam_select"):
+            # candidate pool: every live hyp x its 2K continuations
+            cand_lp = s.sum_lp[:, None] + step.topk_log_probs  # [K, 2K]
+            # step 0: all hyps identical -> expand only hyp 0
+            # (beam_search.py:125)
+            first = jnp.arange(K)[:, None] == 0
+            cand_lp = jnp.where(jnp.logical_or(s.t > 0, first), cand_lp, NEG)
+            flat_lp = cand_lp.reshape(S)
+            flat_tok = step.topk_ids.reshape(S)
+            order = jnp.argsort(-flat_lp)  # stable descending
+            srt_lp = flat_lp[order]
+            srt_tok = flat_tok[order]
+            parent = order // (2 * K)  # originating live hyp
 
-        # sequential triage (beam_search.py:143-154) as cumsums: counts only
-        # advance for selected candidates, and a candidate is processed only
-        # while both pools are still short of K.
-        is_stop = srt_tok == STOP_ID
-        valid_stop = jnp.logical_and(is_stop, s.t >= hps.min_dec_steps)
-        non_stop = jnp.logical_not(is_stop)
-        live_rank = jnp.cumsum(non_stop)  # inclusive
-        res_rank = jnp.cumsum(valid_stop)
-        live_sel = non_stop & (live_rank <= K) & (s.n_res + res_rank < K)
-        res_sel = valid_stop & (s.n_res + res_rank <= K) & (live_rank < K)
+            # sequential triage (beam_search.py:143-154) as cumsums: counts
+            # only advance for selected candidates, and a candidate is
+            # processed only while both pools are still short of K.
+            is_stop = srt_tok == STOP_ID
+            valid_stop = jnp.logical_and(is_stop, s.t >= hps.min_dec_steps)
+            non_stop = jnp.logical_not(is_stop)
+            live_rank = jnp.cumsum(non_stop)  # inclusive
+            res_rank = jnp.cumsum(valid_stop)
+            live_sel = non_stop & (live_rank <= K) & (s.n_res + res_rank < K)
+            res_sel = valid_stop & (s.n_res + res_rank <= K) & (live_rank < K)
 
-        # --- rebuild the live beam ---
-        sel = jnp.argsort(jnp.logical_not(live_sel))[:K]  # first K selected
-        ok = live_sel[sel]  # all True unless results filled first
-        par = parent[sel]
-        new_latest = srt_tok[sel]
-        new_sum_lp = jnp.where(ok, srt_lp[sel], NEG)
+            # --- rebuild the live beam ---
+            # first K selected
+            sel = jnp.argsort(jnp.logical_not(live_sel))[:K]
+            ok = live_sel[sel]  # all True unless results filled first
+            par = parent[sel]
+            new_latest = srt_tok[sel]
+            new_sum_lp = jnp.where(ok, srt_lp[sel], NEG)
 
-        # --- append ONE backpointer column (no history gathers) ---
-        # s.t == T only on masked post-horizon iterations; column T is
-        # the scratch column those writes land in (never read back)
-        parent_hist = s.parent_hist.at[:, s.t].set(par)
-        tok_hist = s.tok_hist.at[:, s.t].set(new_latest)
-        attn_col = s.t if attn_col_fn is None else attn_col_fn(s.t)
-        attn_steps = s.attn_steps.at[:, attn_col].set(step.attn_dist)
-        pgen_steps = s.pgen_steps.at[:, s.t].set(step.p_gen)
+            # --- append ONE backpointer column (no history gathers) ---
+            # s.t == T only on masked post-horizon iterations; column T is
+            # the scratch column those writes land in (never read back)
+            parent_hist = s.parent_hist.at[:, s.t].set(par)
+            tok_hist = s.tok_hist.at[:, s.t].set(new_latest)
+            attn_col = s.t if attn_col_fn is None else attn_col_fn(s.t)
+            attn_steps = s.attn_steps.at[:, attn_col].set(step.attn_dist)
+            pgen_steps = s.pgen_steps.at[:, s.t].set(step.p_gen)
 
-        # --- record finished hypotheses as scalar backpointers ---
-        slot = jnp.where(res_sel, s.n_res + res_rank - 1, K)  # K = scratch
-        res_lp = s.res_lp.at[slot].set(jnp.where(res_sel, srt_lp, NEG))
-        res_len = s.res_len.at[slot].set(s.t + 2)  # START + t+1 generated
-        res_t = s.res_t.at[slot].set(s.t)
-        res_par = s.res_par.at[slot].set(parent)
-        # scratch row K may hold garbage; restore invariants there
-        res_lp = res_lp.at[K].set(NEG)
+            # --- record finished hypotheses as scalar backpointers ---
+            slot = jnp.where(res_sel, s.n_res + res_rank - 1, K)  # K = scratch
+            res_lp = s.res_lp.at[slot].set(jnp.where(res_sel, srt_lp, NEG))
+            res_len = s.res_len.at[slot].set(s.t + 2)  # START + t+1 generated
+            res_t = s.res_t.at[slot].set(s.t)
+            res_par = s.res_par.at[slot].set(parent)
+            # scratch row K may hold garbage; restore invariants there
+            res_lp = res_lp.at[K].set(NEG)
 
-        return _BeamState(
-            t=s.t + 1,
-            latest=new_latest,
-            sum_lp=new_sum_lp,
-            dec_state=jax.tree_util.tree_map(lambda x: x[par], step.state),
-            n_res=s.n_res + jnp.sum(res_sel).astype(jnp.int32),
-            parent_hist=parent_hist,
-            tok_hist=tok_hist,
-            attn_steps=attn_steps,
-            pgen_steps=pgen_steps,
-            res_lp=res_lp,
-            res_len=res_len,
-            res_t=res_t,
-            res_par=res_par,
-        )
+            return _BeamState(
+                t=s.t + 1,
+                latest=new_latest,
+                sum_lp=new_sum_lp,
+                dec_state=jax.tree_util.tree_map(lambda x: x[par], step.state),
+                n_res=s.n_res + jnp.sum(res_sel).astype(jnp.int32),
+                parent_hist=parent_hist,
+                tok_hist=tok_hist,
+                attn_steps=attn_steps,
+                pgen_steps=pgen_steps,
+                res_lp=res_lp,
+                res_len=res_len,
+                res_t=res_t,
+                res_par=res_par,
+            )
 
     return body
 
@@ -266,13 +269,14 @@ def _masked_scan_body(cond, body):
 
     def scan_body(s, _):
         s2 = body(s)
-        keep = cond(s)
-        kept = {
-            f: jax.tree_util.tree_map(
-                lambda old, new: jnp.where(keep, new, old),
-                getattr(s, f), getattr(s2, f))
-            for f in _SELECT_FIELDS
-        }
+        with jax.named_scope("beam_select"):
+            keep = cond(s)
+            kept = {
+                f: jax.tree_util.tree_map(
+                    lambda old, new: jnp.where(keep, new, old),
+                    getattr(s, f), getattr(s2, f))
+                for f in _SELECT_FIELDS
+            }
         return s2._replace(**kept), None
 
     return scan_body
@@ -555,8 +559,9 @@ def prefill_jit(params, hps: HParams,
     encode is bitwise the valid prefix of a full-width encode — parity
     with the batch search is by construction, not by tolerance."""
     family = get_family(hps.model_family)
-    enc_view = family.pad_enc_view(family.beam_encode(params, hps, arrays),
-                                   hps.max_enc_steps)
+    with jax.named_scope("encoder"):
+        enc_view = family.pad_enc_view(
+            family.beam_encode(params, hps, arrays), hps.max_enc_steps)
     T = hps.max_enc_steps
 
     def pad_t(x):
@@ -950,14 +955,15 @@ def step_slots_paged_jit(params, hps: HParams, state: PagedSlotState,
     rest_leaves, treedef = jax.tree_util.tree_flatten(state.enc_rest)
     dense_leaves = []
     pool_it = iter(state.enc_pages)
-    for leaf, ta in zip(rest_leaves, axes):
-        if ta is None:
-            dense_leaves.append(leaf)
-            continue
-        dense_leaves.append(_pages_to_leaf(next(pool_it), pages, ta,
-                                           T_enc))
+    with jax.named_scope("page_io"):
+        for leaf, ta in zip(rest_leaves, axes):
+            if ta is None:
+                dense_leaves.append(leaf)
+                continue
+            dense_leaves.append(_pages_to_leaf(next(pool_it), pages, ta,
+                                               T_enc))
+        ext = state.ext_pool[pages].reshape(slots, t_pad)[:, :T_enc]
     enc_view = jax.tree_util.tree_unflatten(treedef, dense_leaves)
-    ext = state.ext_pool[pages].reshape(slots, t_pad)[:, :T_enc]
 
     def one_step(beam, act, enc_one, mask, ext_one):
         def step_nb(p, e, m, x, t, latest, s):
@@ -979,14 +985,16 @@ def step_slots_paged_jit(params, hps: HParams, state: PagedSlotState,
         t_old = beams.t  # [slots] pre-step write column (t <= T always)
         beams2 = jax.vmap(one_step)(beams, active, enc_view,
                                     state.enc_mask, ext)
-        attn = beams2.attn_steps[:, :, 0, :]  # [slots, K, T_enc]
-        pad = t_pad - T_enc
-        if pad:
-            attn = jnp.pad(attn, [(0, 0), (0, 0), (0, pad)])
-        vals = attn.reshape(slots, K, b_max, block).transpose(0, 2, 1, 3)
-        attn_pool = attn_pool.at[flat_pages, :,
-                                 jnp.repeat(t_old, b_max)].set(
-            vals.reshape(slots * b_max, K, block))
+        with jax.named_scope("page_io"):
+            attn = beams2.attn_steps[:, :, 0, :]  # [slots, K, T_enc]
+            pad = t_pad - T_enc
+            if pad:
+                attn = jnp.pad(attn, [(0, 0), (0, 0), (0, pad)])
+            vals = attn.reshape(slots, K, b_max, block).transpose(
+                0, 2, 1, 3)
+            attn_pool = attn_pool.at[flat_pages, :,
+                                     jnp.repeat(t_old, b_max)].set(
+                vals.reshape(slots * b_max, K, block))
         return (beams2, attn_pool), None
 
     (beam, attn_pool), _ = jax.lax.scan(

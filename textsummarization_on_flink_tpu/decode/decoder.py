@@ -974,6 +974,31 @@ class SlotDecodeEngine:
                              self._hps, self._state, idx, item.state))
         self._active[idx] = True
 
+    def _step_call(self, params):
+        """(jitted slot step, its arguments) at the engine's current
+        state: what step() runs and compiled_step() lowers."""
+        if self.paged:
+            return beam_search.step_slots_paged_jit, (
+                params, self._hps, self._state, self._active, self._table,
+                self.chunk)
+        return beam_search.step_slots_jit, (
+            params, self._hps, self._state, self._active, self.chunk)
+
+    def compiled_step(self):
+        """The ``jax.stages.Compiled`` of the slot step at the engine's
+        current shapes and shardings — for ``memory_analysis()`` and for
+        ``as_text()``, whose ``op_name=`` metadata maps an instruction
+        of a device trace to its named scope.  Lowering the very
+        arguments step() passes finds the executable step() runs in the
+        compile cache; nothing runs and the compile ledger is not
+        touched.  Off the hot path: call it after a measured window,
+        never inside one."""
+        if self._state is None:
+            raise RuntimeError("the slot step has no shapes yet: nothing "
+                               "was packed into this engine")
+        fn, args = self._step_call(self._params())
+        return fn.lower(*args).compile()
+
     def step(self) -> List[int]:
         """One chunk for every resident slot; returns the slot indices
         whose search finished (ready to unpack)."""
@@ -984,22 +1009,19 @@ class SlotDecodeEngine:
         # serves every resident at once, so there is no single parent
         # trace) — a request's timeline correlates with these spans by
         # timestamp via its slot/tick lifecycle events, not by trace_id
-        with obs.spans.span(self._obs, "decode/slot_chunk",
-                            active=int(self._active.sum())):
-            if self.paged:
-                self._state, finished = self._jitted(
-                    "decode/step_slots_jit",
-                    beam_search.step_slots_paged_jit, params, self._hps,
-                    self._state, self._active, self._table, self.chunk)
-            else:
-                self._state, finished = self._jitted(
-                    "decode/step_slots_jit", beam_search.step_slots_jit,
-                    params, self._hps, self._state, self._active,
-                    self.chunk)
+        with self._prof.phase("decode/slot_chunk",
+                              active=int(self._active.sum())):
+            fn, args = self._step_call(params)
+            self._state, finished = self._jitted("decode/step_slots_jit",
+                                                 fn, *args)
             self._state = self._pin_state(self._state)
             # the one sanctioned chunk-boundary sync: the host scheduler
-            # needs the finished mask to retire and refill slots
-            return [int(i) for i in np.nonzero(np.asarray(finished))[0]]
+            # needs the finished mask to retire and refill slots.  Its
+            # own child phase: dispatching the chunk is host work, this
+            # is the wait for the device
+            with self._prof.phase("serve/dispatch/wait_mask"):
+                mask = np.asarray(finished)
+            return [int(i) for i in np.nonzero(mask)[0]]
 
     def unpack(self, idx: int, example) -> DecodedResult:
         """Retire slot `idx`: finalize its hypothesis and free the slot.

@@ -419,12 +419,13 @@ def decode_onestep(params: Params, hps: HParams,
     new_sums = []
     attn_dist = None
     for li, layer in enumerate(params["decoder"]["layers"]):
-        x_norm = tf._ln(layer["ln1"], y)
-        s = aan_sum[:, li] + x_norm.astype(jnp.float32)  # running sum
-        new_sums.append(s)
-        avg = (s / (t.astype(jnp.float32) + 1.0)).astype(dt)
-        g = tf._ffn_block(layer["aan_ffn"], avg)
-        y = y + _aan_gate(layer, x_norm, g)
+        with jax.named_scope("attention"):  # the average stands in for it
+            x_norm = tf._ln(layer["ln1"], y)
+            s = aan_sum[:, li] + x_norm.astype(jnp.float32)  # running sum
+            new_sums.append(s)
+            avg = (s / (t.astype(jnp.float32) + 1.0)).astype(dt)
+            g = tf._ffn_block(layer["aan_ffn"], avg)
+            y = y + _aan_gate(layer, x_norm, g)
         # cross attention + output head are the transformer family's
         # shared decode blocks — one numerics source for all three
         # decode paths (beam step / spec verify / this); dhps carries
@@ -462,7 +463,9 @@ def beam_adapter(hps: HParams):
         final_dist, attn_dist, p_gen, _, new_sum = decode_onestep(
             params, hps, enc_one, enc_mask, ext_ids, t, latest,
             state["aan_sum"], nb=nb)
-        topk_probs, topk_ids = jax.lax.top_k(final_dist, 2 * hps.beam_size)
+        with jax.named_scope("topk"):
+            topk_probs, topk_ids = jax.lax.top_k(final_dist,
+                                                 2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,

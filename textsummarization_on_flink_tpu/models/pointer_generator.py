@@ -337,6 +337,26 @@ def final_distribution(hps: HParams, vocab_dist: Array, attn_dist: Array,
     return base.at[b_idx, enc_batch_extend_vocab].add(weighted_attn)
 
 
+@jax.named_scope("vocab_dist")
+def _vocab_dist(params: Params, hps: HParams, cell_out: Array,
+                context: Array, new_state: Tuple[Array, Array], x: Array,
+                attn_dist: Array, ext_ids: Array) -> Tuple[Array, Array]:
+    """The decode step's output head, shared by decode_onestep and
+    decode_onestep_shared: p_gen, output projection, softmax and the
+    pointer mixture.  ext_ids: [B, T_enc].  Returns (final_dist, p_gen)."""
+    dp = params["decoder"]
+    p_gen = jax.nn.sigmoid(
+        _linear(dp["pgen_linear"], context, new_state[0], new_state[1], x))[:, 0]
+    output = _linear(dp["output_linear"], cell_out, context)
+    vocab_scores = _proj(hps, output, params["output_projection"]["w"]) + \
+        params["output_projection"]["v"]
+    vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
+    if not hps.pointer_gen:
+        return vocab_dist, p_gen
+    return final_distribution(hps, vocab_dist, attn_dist, p_gen,
+                              ext_ids), p_gen
+
+
 def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
                    enc_padding_mask: Array, enc_batch_extend_vocab: Array,
                    latest_tokens: Array, state: Tuple[Array, Array],
@@ -356,30 +376,28 @@ def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
     """
     dp = params["decoder"]
     use_cov = hps.coverage
-    ctx_prev, _, cov = attn_ops.attend(
-        dp["attention"], enc.enc_states, enc.enc_features, enc_padding_mask,
-        state, prev_coverage if use_cov else None, use_cov)
+    # the named scopes below (here and in decode_onestep_shared) are
+    # what a device trace splits the step by: OBSERVABILITY.md "Scopes"
+    with jax.named_scope("attention"):
+        ctx_prev, _, cov = attn_ops.attend(
+            dp["attention"], enc.enc_states, enc.enc_features,
+            enc_padding_mask, state, prev_coverage if use_cov else None,
+            use_cov)
     if cov is None:
         cov = prev_coverage
-    inp_emb = params["embedding"][latest_tokens]
-    x = _linear(dp["input_linear"], inp_emb, ctx_prev)
-    cell_out, new_state = lstm_ops.lstm_cell(dp["cell"], x, state)
-    context, attn_dist, _ = attn_ops.attend(
-        dp["attention"], enc.enc_states, enc.enc_features, enc_padding_mask,
-        new_state, cov if use_cov else None, use_cov)
-    p_gen = jax.nn.sigmoid(
-        _linear(dp["pgen_linear"], context, new_state[0], new_state[1], x))[:, 0]
-    output = _linear(dp["output_linear"], cell_out, context)
-    vocab_scores = _proj(hps, output, params["output_projection"]["w"]) + \
-        params["output_projection"]["v"]
-    vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
-    if hps.pointer_gen:
-        final_dist = final_distribution(hps, vocab_dist, attn_dist, p_gen,
-                                        enc_batch_extend_vocab)
-    else:
-        final_dist = vocab_dist
+    with jax.named_scope("lstm_cell"):
+        inp_emb = params["embedding"][latest_tokens]
+        x = _linear(dp["input_linear"], inp_emb, ctx_prev)
+        cell_out, new_state = lstm_ops.lstm_cell(dp["cell"], x, state)
+    with jax.named_scope("attention"):
+        context, attn_dist, _ = attn_ops.attend(
+            dp["attention"], enc.enc_states, enc.enc_features,
+            enc_padding_mask, new_state, cov if use_cov else None, use_cov)
+    final_dist, p_gen = _vocab_dist(params, hps, cell_out, context, new_state,
+                                    x, attn_dist, enc_batch_extend_vocab)
     k = 2 * hps.beam_size  # model.py:284 (batch_size==beam_size there)
-    topk_probs, topk_ids = jax.lax.top_k(final_dist, k)
+    with jax.named_scope("topk"):
+        topk_probs, topk_ids = jax.lax.top_k(final_dist, k)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,
@@ -408,35 +426,30 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
     dp = params["decoder"]
     use_cov = hps.coverage
     block = config_lib.resolve_enc_block(hps) if nb is not None else 0
-    ctx_prev, _, cov = attn_ops.attend_shared(
-        dp["attention"], enc_one.enc_states, enc_one.enc_features, enc_mask,
-        state, prev_coverage if use_cov else None, use_cov,
-        nb=nb, block=block)
+    with jax.named_scope("attention"):
+        ctx_prev, _, cov = attn_ops.attend_shared(
+            dp["attention"], enc_one.enc_states, enc_one.enc_features,
+            enc_mask, state, prev_coverage if use_cov else None, use_cov,
+            nb=nb, block=block)
     if cov is None:
         cov = prev_coverage
-    inp_emb = params["embedding"][latest_tokens]
-    x = _linear(dp["input_linear"], inp_emb, ctx_prev)
-    cell_out, new_state = lstm_ops.lstm_cell(dp["cell"], x, state)
-    context, attn_dist, _ = attn_ops.attend_shared(
-        dp["attention"], enc_one.enc_states, enc_one.enc_features, enc_mask,
-        new_state, cov if use_cov else None, use_cov,
-        nb=nb, block=block)
-    p_gen = jax.nn.sigmoid(
-        _linear(dp["pgen_linear"], context, new_state[0], new_state[1], x))[:, 0]
-    output = _linear(dp["output_linear"], cell_out, context)
-    vocab_scores = _proj(hps, output, params["output_projection"]["w"]) + \
-        params["output_projection"]["v"]
-    vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
+    with jax.named_scope("lstm_cell"):
+        inp_emb = params["embedding"][latest_tokens]
+        x = _linear(dp["input_linear"], inp_emb, ctx_prev)
+        cell_out, new_state = lstm_ops.lstm_cell(dp["cell"], x, state)
+    with jax.named_scope("attention"):
+        context, attn_dist, _ = attn_ops.attend_shared(
+            dp["attention"], enc_one.enc_states, enc_one.enc_features,
+            enc_mask, new_state, cov if use_cov else None, use_cov,
+            nb=nb, block=block)
+    # the mixture scatter is genuinely per-hypothesis; the broadcast
+    # ext ids are an int32 index operand, not a streamed tensor
     K = latest_tokens.shape[0]
-    if hps.pointer_gen:
-        # the mixture scatter is genuinely per-hypothesis; the broadcast
-        # ext ids are an int32 index operand, not a streamed tensor
-        ext_k = jnp.broadcast_to(ext_ids[None], (K,) + ext_ids.shape)
-        final_dist = final_distribution(hps, vocab_dist, attn_dist, p_gen,
-                                        ext_k)
-    else:
-        final_dist = vocab_dist
-    topk_probs, topk_ids = jax.lax.top_k(final_dist, 2 * hps.beam_size)
+    final_dist, p_gen = _vocab_dist(
+        params, hps, cell_out, context, new_state, x, attn_dist,
+        jnp.broadcast_to(ext_ids[None], (K,) + ext_ids.shape))
+    with jax.named_scope("topk"):
+        topk_probs, topk_ids = jax.lax.top_k(final_dist, 2 * hps.beam_size)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,

@@ -514,6 +514,7 @@ def beam_encode(params: Params, hps: HParams, arrays: Dict[str, Array],
 BeamStepOut = pg.BeamStepOut  # shared beam protocol output type
 
 
+@jax.named_scope("attention")
 def cross_attend_layer(hps: HParams, layer: Dict[str, Any], y: Array,
                        ck: Array, cv: Array, enc_mask: Array,
                        nb: Optional[Array] = None,
@@ -582,6 +583,7 @@ def cross_attend_layer(hps: HParams, layer: Dict[str, Any], y: Array,
     return cross_out, jnp.mean(cprobs, axis=1)
 
 
+@jax.named_scope("vocab_dist")
 def decode_output_tail(params: Params, hps: HParams, y: Array,
                        cross_ctx: Array, attn_dist: Array, ext_ids: Array,
                        ) -> Tuple[Array, Array, Array]:
@@ -646,22 +648,24 @@ def beam_adapter(hps: HParams):
         # the cache and softmaxes below deliberately stay f32
         for li, layer in enumerate(params["decoder"]["layers"]):
             p = layer["self_attn"]
-            h_norm = _ln(layer["ln1"], y)
-            q = _split_heads(hps, h_norm @ p["wq"].astype(dt))  # [K, nh, hd]
-            k_new = _split_heads(hps, h_norm @ p["wk"].astype(dt))
-            v_new = _split_heads(hps, h_norm @ p["wv"].astype(dt))
-            cache_k = cache_k.at[:, li, t].set(k_new.astype(cache_dtype))
-            cache_v = cache_v.at[:, li, t].set(v_new.astype(cache_dtype))
-            # widen the (possibly bf16) cache at the point of use: the
-            # logits einsum and softmax stay f32 whatever the storage
-            kk = cache_k[:, li].astype(jnp.float32)  # [K, T, nh, hd]
-            vv = cache_v[:, li].astype(jnp.float32)
-            logits = jnp.einsum("knd,ktnd->knt", q.astype(jnp.float32), kk)
-            logits = logits * (hd ** -0.5)
-            logits = jnp.where(pos_ok[None, None, :] > 0, logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1)
-            ctx = jnp.einsum("knt,ktnd->knd", probs, vv)
-            y = y + _merge_heads(ctx).astype(dt) @ p["wo"].astype(dt)
+            with jax.named_scope("attention"):
+                h_norm = _ln(layer["ln1"], y)
+                q = _split_heads(hps, h_norm @ p["wq"].astype(dt))  # [K, nh, hd]
+                k_new = _split_heads(hps, h_norm @ p["wk"].astype(dt))
+                v_new = _split_heads(hps, h_norm @ p["wv"].astype(dt))
+                cache_k = cache_k.at[:, li, t].set(k_new.astype(cache_dtype))
+                cache_v = cache_v.at[:, li, t].set(v_new.astype(cache_dtype))
+                # widen the (possibly bf16) cache at the point of use: the
+                # logits einsum and softmax stay f32 whatever the storage
+                kk = cache_k[:, li].astype(jnp.float32)  # [K, T, nh, hd]
+                vv = cache_v[:, li].astype(jnp.float32)
+                logits = jnp.einsum("knd,ktnd->knt", q.astype(jnp.float32),
+                                    kk)
+                logits = logits * (hd ** -0.5)
+                logits = jnp.where(pos_ok[None, None, :] > 0, logits, -1e30)
+                probs = jax.nn.softmax(logits, axis=-1)
+                ctx = jnp.einsum("knt,ktnd->knd", probs, vv)
+                y = y + _merge_heads(ctx).astype(dt) @ p["wo"].astype(dt)
             # cross attention against the precomputed per-layer K/V
             cross_out, attn_dist = cross_attend_layer(
                 hps, layer, y, enc_one.cross_k[li], enc_one.cross_v[li],
@@ -672,7 +676,9 @@ def beam_adapter(hps: HParams):
         final_dist, p_gen, _ = decode_output_tail(params, hps, y,
                                                   cross_ctx, attn_dist,
                                                   ext_ids)
-        topk_probs, topk_ids = jax.lax.top_k(final_dist, 2 * hps.beam_size)
+        with jax.named_scope("topk"):
+            topk_probs, topk_ids = jax.lax.top_k(final_dist,
+                                                 2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,

@@ -11,7 +11,13 @@ Three ledgers behind one per-registry ``Profiler`` (attached at
     sub-phases), aggregated into the labeled ``profile/phase_seconds``
     histogram plus a phases-sum-to-wall accounting check
     (``profile/phase_coverage_ratio``).  The clock is injectable so the
-    tier-1 gate drives it in virtual time.
+    tier-1 gate drives it in virtual time.  ``Profiler.phase(name)`` is
+    the ONE bracket the hot paths use: it books this ledger, records
+    the ``obs.spans`` span and opens a ``jax.profiler.TraceAnnotation``
+    for the same interval, so a profiler capture holds the host phases
+    on the device trace's own clock.  A phase opened inside another on
+    the same thread is a CHILD: it gets its own label, span and
+    annotation and is left out of the coverage sum and the recent ring.
   * **compile ledger** — the ONE shared jit-cache-diff helper
     (``compiled_call``) the decode paths route through, recording every
     compile event (site, shape/bucket key, wall duration, warm-set
@@ -43,11 +49,13 @@ allocation (pinned in tests/test_profile.py).
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from textsummarization_on_flink_tpu.obs import flightrec
+from textsummarization_on_flink_tpu.obs import spans as spans_lib
 from textsummarization_on_flink_tpu.obs.registry import Registry
 
 #: bounded ring of recent phase records — feeds the /profile top-k
@@ -67,6 +75,103 @@ BASELINE_SAMPLES = 3
 DEFAULT_DIVERGENCE_FACTOR = 5.0
 
 
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where jax is ALREADY loaded in
+    this process, else None: obs/ imports no jax, and a jax-free process
+    (the virtual-time gates, the fleet router) has no profiler whose
+    host plane an annotation could land in."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
+class _NullBracket:
+    """The dark registry's phase()/wall(): one shared do-nothing
+    context."""
+
+    __slots__ = ()
+
+    dt = 0.0
+
+    def __enter__(self) -> "_NullBracket":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        pass
+
+    def cancel(self) -> None:
+        pass
+
+
+NULL_BRACKET = _NullBracket()
+
+
+class _Phase:
+    """One open ``Profiler.phase``: ledger + span + annotation over the
+    same interval.  ``dt`` holds the booked duration after exit."""
+
+    __slots__ = ("_prof", "name", "_trace_id", "_span", "_ann", "_t0", "dt")
+
+    def __init__(self, prof: "Profiler", name: str,
+                 trace_id: Optional[str], parent, attrs: Dict[str, Any]):
+        self._prof = prof
+        self.name = name
+        self._trace_id = trace_id
+        self._span = spans_lib.span(prof._reg, name, parent=parent, **attrs)
+        ann = _trace_annotation()
+        self._ann = ann(name, **attrs) if ann is not None else None
+        self._t0 = 0.0
+        self.dt = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._prof._local.depth = self._prof._depth() + 1
+        self._span.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = self._prof._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        prof = self._prof
+        prof._local.depth = prof._depth() - 1
+        # booked on a raising exit too: the failed work took the time
+        self.dt = prof.end(self.name, self._t0, trace_id=self._trace_id)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._span.__exit__(exc_type, exc, tb)
+
+
+class _Wall:
+    """One open ``Profiler.wall``: the wall ledger plus an annotation
+    (no span: a wall is the denominator, not an attributable phase)."""
+
+    __slots__ = ("_prof", "name", "_ann", "_t0", "_keep")
+
+    def __init__(self, prof: "Profiler", name: str):
+        self._prof = prof
+        self.name = name
+        ann = _trace_annotation()
+        self._ann = ann(name) if ann is not None else None
+        self._t0 = 0.0
+        self._keep = True
+
+    def cancel(self) -> None:
+        """Leave this unit out of the wall ledger (an idle serve tick:
+        its queue poll is idleness, not a phase to fix)."""
+        self._keep = False
+
+    def __enter__(self) -> "_Wall":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = self._prof._clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._keep:
+            self._prof.end_wall(self.name, self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+
+
 class _NullProfiler:
     """Shared do-nothing profiler for dark registries: every method
     returns a preexisting constant, so the ``obs=False`` path adds no
@@ -74,6 +179,13 @@ class _NullProfiler:
     NULL_COUNTER/NULL_GAUGE — pinned by test_profile)."""
 
     __slots__ = ()
+
+    def phase(self, name, trace_id=None, parent=None,
+              **attrs) -> _NullBracket:
+        return NULL_BRACKET
+
+    def wall(self, name) -> _NullBracket:
+        return NULL_BRACKET
 
     def start(self) -> float:
         return 0.0
@@ -134,6 +246,10 @@ class Profiler:
         # phase ledger: name -> [count, total_s, max_s]; walls likewise
         self._phases: Dict[str, List[float]] = {}
         self._walls: Dict[str, List[float]] = {}
+        # seconds booked by CHILD phases (a phase() open on the thread
+        # when they closed): in the table above, out of the coverage sum
+        self._nested_s = 0.0
+        self._local = threading.local()  # .depth: phase()s open here
         self._recent: List[Tuple[int, str, float, Optional[str]]] = []
         # compile ledger: site -> {compiles, hits, keys, last_dur_s}
         self._sites: Dict[str, Dict[str, Any]] = {}
@@ -158,14 +274,43 @@ class Profiler:
         self._c_div = registry.counter("profile/divergence_dumps_total")
 
     # -- phase ledger ---------------------------------------------------
+    def phase(self, name: str, trace_id: Optional[str] = None,
+              parent: Optional[spans_lib.TraceContext] = None,
+              **attrs: Any) -> _Phase:
+        """The one bracket around a hot-path interval::
+
+            with prof.phase("serve/dispatch", fill=n) as ph:
+                ...
+            prof.observe_dispatch(site, key, ph.dt)
+
+        books the phase ledger (`trace_id` is the histogram exemplar),
+        records the ``obs.spans`` span (`parent` links it into a
+        request's trace; `attrs` ride on it) and, where jax is loaded,
+        opens a ``jax.profiler.TraceAnnotation(name, **attrs)`` — so a
+        capture shows the phase on the thread that ran it, on the device
+        trace's clock."""
+        return _Phase(self, name, trace_id, parent, attrs)
+
+    def wall(self, name: str) -> _Wall:
+        """The bracket around one WALL unit (a serve tick, a train
+        round) — the denominator of the phases-sum-to-wall check."""
+        return _Wall(self, name)
+
+    def _depth(self) -> int:
+        return getattr(self._local, "depth", 0)
+
     def start(self) -> float:
         """A phase/wall start token (the injected clock's now)."""
         return self._clock()
 
     def end(self, phase: str, t0: float,
             trace_id: Optional[str] = None) -> float:
-        """Close one phase opened by start(); returns its duration."""
+        """Close one phase opened by start(); returns its duration.  A
+        phase closed while a phase() is open on this thread is a child:
+        same table and histogram, no share of coverage or of the ring
+        (its parent already accounts for the interval)."""
         dt = self._clock() - t0
+        child = self._depth() > 0
         ts_us = int(time.time() * 1e6)  # serialized epoch stamp only
         with self._lock:
             agg = self._phases.get(phase)
@@ -175,9 +320,12 @@ class Profiler:
             agg[1] += dt
             if dt > agg[2]:
                 agg[2] = dt
-            self._recent.append((ts_us, phase, dt, trace_id))
-            if len(self._recent) > RECENT_PHASES_CAP:
-                del self._recent[:len(self._recent) - RECENT_PHASES_CAP]
+            if child:
+                self._nested_s += dt
+            else:
+                self._recent.append((ts_us, phase, dt, trace_id))
+                if len(self._recent) > RECENT_PHASES_CAP:
+                    del self._recent[:len(self._recent) - RECENT_PHASES_CAP]
         self._h_phase.labels(phase=phase).observe(dt, trace_id=trace_id)
         return dt
 
@@ -202,7 +350,8 @@ class Profiler:
         wall = sum(w[1] for w in self._walls.values())
         if wall <= 0.0:
             return 0.0
-        return sum(p[1] for p in self._phases.values()) / wall
+        return (sum(p[1] for p in self._phases.values())
+                - self._nested_s) / wall
 
     def coverage(self) -> float:
         """sum(phase time) / sum(wall time) — the accounting check."""

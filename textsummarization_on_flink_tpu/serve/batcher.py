@@ -50,6 +50,16 @@ from textsummarization_on_flink_tpu.serve.queue import (
     ServeRequest,
 )
 
+#: serve/request_stage_seconds bounds: a quarter-octave wide from 1 ms
+#: to ~131 s, so a percentile read from the buckets is within 19% of
+#: the value wherever a reader's wait falls (the default time buckets
+#: double: a 5 s wait sits in a bucket 2.6 s wide)
+STAGE_BUCKETS = obs.exponential_buckets(1e-3, 2.0 ** 0.25, 69)
+
+
+def _trace_id(req: ServeRequest) -> Optional[str]:
+    return req.trace.trace_id if req.trace is not None else None
+
 
 def resolve_buckets(hps: HParams) -> List[int]:
     """The ascending encoder-length bucket list for this job (the one
@@ -266,6 +276,12 @@ class ContinuousBatcher:
         self._g_prefill_ready = reg.gauge("serve/prefill_ready")
         self._h_queue_time = reg.histogram("serve/time_in_queue_seconds")
         self._h_e2e = reg.histogram("serve/e2e_latency_seconds")
+        # the request's stage clock: queue -> prefill -> slot_wait ->
+        # resident -> harvest, each observed from the previous mark on
+        # the request (ServeRequest.stage_t), so a completed request's
+        # stages sum to its enqueue -> resolve time by construction
+        self._h_stage = reg.histogram("serve/request_stage_seconds",
+                                      buckets=STAGE_BUCKETS)
         self._c_done = reg.counter("serve/completed_total")
         self._c_errors = reg.counter("serve/errors_total")
         # per-tenant cost accounting (ISSUE 15): decoded tokens charged
@@ -283,6 +299,11 @@ class ContinuousBatcher:
         self._h_arena_fill = reg.histogram(
             "serve/arena_fill",
             buckets=[0.1, 0.25, 0.5, 0.75, 0.9, 0.95, 1.0])
+
+    @property
+    def engine(self) -> Any:
+        """The slot engine this scheduler drives."""
+        return self._engine
 
     def busy(self) -> bool:
         return any(r is not None for r in self._resident)
@@ -306,6 +327,18 @@ class ContinuousBatcher:
         must keep ticking for (they pack on the next refill)."""
         return bool(self._prefilled)
 
+    def _close_stage(self, req: ServeRequest, stage: str,
+                     now: Optional[float] = None) -> float:
+        """Close `stage` of `req` at `now`: observe the seconds since the
+        request's previous mark and move the mark.  Returns the stage's
+        milliseconds, for the lifecycle event fired at the same site."""
+        if now is None:
+            now = time.monotonic()
+        dt = now - req.stage_t
+        req.stage_t = now
+        self._h_stage.labels(stage=stage).observe(dt)
+        return round(dt * 1e3, 3)
+
     def _set_active_gauge(self) -> None:
         n = sum(r is not None for r in self._resident)
         self._g_active.set(n)
@@ -326,7 +359,8 @@ class ContinuousBatcher:
             evicted += 1
             obs.spans.request_event(
                 self._reg, "evict", req.trace, req.uuid, where="resident",
-                slot=idx, chunks=self._chunks[idx])
+                slot=idx, chunks=self._chunks[idx],
+                resident_ms=self._close_stage(req, "resident"))
             req.future._reject(DeadlineExceededError(
                 f"request {req.uuid!r} deadline expired after "
                 f"{self._chunks[idx]} resident chunk(s)"))
@@ -360,14 +394,14 @@ class ContinuousBatcher:
             may_block = False
             if req is None:
                 return None
-            queue_s = time.monotonic() - req.enqueue_t
-            self._h_queue_time.observe(queue_s)
+            queue_ms = self._close_stage(req, "queue")
+            self._h_queue_time.observe(req.stage_t - req.enqueue_t)
             if req.deadline.expired():  # died waiting in the queue
                 self._c_evictions.inc()
                 self._tick_evictions += 1
                 obs.spans.request_event(
                     self._reg, "evict", req.trace, req.uuid,
-                    where="queue")
+                    where="queue", queue_ms=queue_ms)
                 req.future._reject(DeadlineExceededError(
                     f"request {req.uuid!r} deadline expired while "
                     f"queued"))
@@ -377,7 +411,7 @@ class ContinuousBatcher:
             # per uuid from the same stream bench's queue split reads
             obs.spans.request_event(
                 self._reg, "admit", req.trace, req.uuid,
-                queue_ms=round(queue_s * 1e3, 3),
+                queue_ms=queue_ms,
                 **({"tenant": req.tenant} if req.tenant else {}))
             return req
 
@@ -401,9 +435,10 @@ class ContinuousBatcher:
             may_block = False
             if req is None:
                 break
-            t0 = self._prof.start()
+            trace_id = _trace_id(req)
             try:
-                with obs.spans.span(self._reg, "serve/prefill"):
+                with self._prof.phase("serve/prefill",
+                                      trace_id=trace_id) as ph:
                     pre = self._engine.prefill(req.example)
             except Exception as e:
                 # the request left the queue but never became resident:
@@ -411,17 +446,17 @@ class ContinuousBatcher:
                 # failure handling deal with the engine state
                 self._c_prefill_errors.inc()
                 self._c_errors.inc()
+                self._close_stage(req, "prefill")
                 req.future._reject(e)
                 raise
-            trace_id = req.trace.trace_id if req.trace is not None else None
-            dt = self._prof.end("serve/prefill", t0, trace_id=trace_id)
             bucket = int(getattr(pre, "bucket", req.example.enc_len))
-            self._prof.observe_dispatch("serve/prefill", bucket, dt,
+            self._prof.observe_dispatch("serve/prefill", bucket, ph.dt,
                                         trace_id=trace_id)
             self._c_prefills.inc()
             self._h_prefill_bucket.observe(bucket)
             obs.spans.request_event(
-                self._reg, "prefill", req.trace, req.uuid, bucket=bucket)
+                self._reg, "prefill", req.trace, req.uuid, bucket=bucket,
+                prefill_ms=self._close_stage(req, "prefill"))
             self._prefilled.append((req, pre))
         self._g_prefill_ready.set(len(self._prefilled))
 
@@ -448,7 +483,9 @@ class ContinuousBatcher:
                         self._tick_evictions += 1
                         obs.spans.request_event(
                             self._reg, "evict", req.trace, req.uuid,
-                            where="prefilled")
+                            where="prefilled",
+                            slot_wait_ms=self._close_stage(req,
+                                                           "slot_wait"))
                         req.future._reject(DeadlineExceededError(
                             f"request {req.uuid!r} deadline expired "
                             f"awaiting a free slot (prefilled)"))
@@ -471,15 +508,17 @@ class ContinuousBatcher:
                         self._prefilled.appendleft((req, payload))
                         self._arena_backpressure(need, free_pages)
                         return
-                t0 = self._prof.start()
                 try:
-                    if (self._supports_arena and self._faults is not None
-                            and self._faults.fire("serve.arena_full")):
-                        raise ArenaExhaustedError(
-                            "injected serve.arena_full fault",
-                            needed=self._engine.pages_needed(payload),
-                            free=0)
-                    self._engine.pack(idx, payload)
+                    with self._prof.phase("serve/pack",
+                                          trace_id=_trace_id(req)):
+                        if (self._supports_arena
+                                and self._faults is not None
+                                and self._faults.fire("serve.arena_full")):
+                            raise ArenaExhaustedError(
+                                "injected serve.arena_full fault",
+                                needed=self._engine.pages_needed(payload),
+                                free=0)
+                        self._engine.pack(idx, payload)
                 except ArenaExhaustedError as e:
                     # typed backpressure from the engine's own alloc
                     # (belt to the proactive check's suspenders, and the
@@ -490,6 +529,7 @@ class ContinuousBatcher:
                     # guarantees prefill support whenever paged.
                     if not self._supports_prefill:
                         self._c_errors.inc()
+                        self._close_stage(req, "slot_wait")
                         req.future._reject(e)
                         raise
                     self._prefilled.appendleft((req, payload))
@@ -500,13 +540,11 @@ class ContinuousBatcher:
                     # resident: resolve it HERE, then let the server's
                     # dispatch-failure handling deal with the engine
                     self._c_errors.inc()
+                    self._close_stage(req, "slot_wait")
                     req.future._reject(e)
                     raise
                 if self._supports_arena and self._arena_blocked:
                     self._arena_blocked = False  # pages freed; edge re-arms
-                self._prof.end("serve/pack", t0,
-                               trace_id=req.trace.trace_id
-                               if req.trace is not None else None)
                 self._resident[idx] = req
                 self._chunks[idx] = 0
                 self._c_refills.inc()
@@ -516,35 +554,45 @@ class ContinuousBatcher:
                 # answer ("why was uuid X slow?")
                 obs.spans.request_event(
                     self._reg, "slot", req.trace, req.uuid, slot=idx,
-                    tick=self._tick)
+                    tick=self._tick,
+                    slot_wait_ms=self._close_stage(req, "slot_wait"))
                 break
         if self._supports_prefill:
             self._g_prefill_ready.set(len(self._prefilled))
         self._set_active_gauge()
 
     def _harvest(self, finished: List[int]) -> None:
-        done_t = time.monotonic()
+        # every finished request stops being resident at ONE instant,
+        # the read of the mask that retires it; what follows is its
+        # wait behind the slots unpacked before it (stage `harvest`)
+        mask_t = time.monotonic()
+        resident_ms = {idx: self._close_stage(self._resident[idx],
+                                              "resident", mask_t)
+                       for idx in finished
+                       if self._resident[idx] is not None}
         for idx in finished:
             req = self._resident[idx]
             if req is None:  # pragma: no cover - defensive
                 continue
-            res = self._engine.unpack(idx, req.example)
+            with self._prof.phase("serve/harvest/unpack", slot=idx):
+                res = self._engine.unpack(idx, req.example)
             self._resident[idx] = None
             self._h_resident.observe(self._chunks[idx])
-            # exemplar (ISSUE 15): the landing latency bucket remembers
-            # THIS request's trace_id, so a fat p99 bucket on /metrics
-            # names a concrete uuid to chase
-            self._h_e2e.observe(
-                done_t - req.enqueue_t,
-                trace_id=req.trace.trace_id if req.trace is not None
-                else None)
             self._c_tenant_tokens.labels(
                 tenant=req.tenant or "default").inc(
                 len(getattr(res, "decoded_words", ()) or ()))
             self._c_done.inc()
+            harvest_ms = self._close_stage(req, "harvest")
+            # stamped per request where its future resolves, so the
+            # harvest is inside the latency.  Exemplar (ISSUE 15): the
+            # landing latency bucket remembers THIS request's trace_id,
+            # so a fat p99 bucket on /metrics names a uuid to chase
+            e2e_s = req.stage_t - req.enqueue_t
+            self._h_e2e.observe(e2e_s, trace_id=_trace_id(req))
             obs.spans.request_event(
                 self._reg, "finish", req.trace, req.uuid, slot=idx,
-                chunks=self._chunks[idx])
+                chunks=self._chunks[idx], resident_ms=resident_ms[idx],
+                harvest_ms=harvest_ms, e2e_ms=round(e2e_s * 1e3, 3))
             req.future._resolve(res)
         self._set_active_gauge()
 
@@ -600,41 +648,38 @@ class ContinuousBatcher:
         # inside the queue poll, and that wait is idleness, not an
         # attributable phase — counting it would sink the coverage
         # ratio without naming a phase to fix
-        w0 = self._prof.start()
-        t0 = self._prof.start()
-        self._evict_expired()
-        self._prof.end("serve/evict", t0)
-        self._prefill_stage(poll)
-        self._refill(poll)
-        if not self.busy():
-            return False
-        # the frame lands BEFORE the chunk dispatch, so a failing tick
-        # contributes its own pre-failure frame (refill/evict state) and
-        # the dump holds everything strictly preceding the trigger
-        n_active = sum(r is not None for r in self._resident)
-        self._observe_arena()
-        self._record_frame(n_active / self.slots)
-        t0 = self._prof.start()
-        with obs.spans.span(
-                self._reg, "serve/dispatch",
-                fill=n_active, tick=self._tick):
-            if self._faults is not None and self._faults.fire(
-                    "serve.dispatch"):
-                raise RuntimeError("injected serve.dispatch fault")
-            finished = self._engine.step()
-        dt = self._prof.end("serve/dispatch", t0)
-        # divergence sentinel: the slot-chunk program is the one
-        # dispatch shape continuous mode executes — price once, then
-        # compare every chunk's achieved bytes/s against it
-        self._prof.observe_dispatch("serve/dispatch", self._dispatch_key, dt)
-        self._h_occupancy.observe(n_active / self.slots)
-        for idx, req in enumerate(self._resident):
-            if req is not None:
-                self._chunks[idx] += 1
-        t0 = self._prof.start()
-        self._harvest(finished)
-        self._prof.end("serve/harvest", t0)
-        self._prof.end_wall("serve/tick", w0)
+        with self._prof.wall("serve/tick") as wall:
+            with self._prof.phase("serve/evict"):
+                self._evict_expired()
+            self._prefill_stage(poll)
+            self._refill(poll)
+            if not self.busy():
+                wall.cancel()
+                return False
+            # the frame lands BEFORE the chunk dispatch, so a failing
+            # tick contributes its own pre-failure frame (refill/evict
+            # state) and the dump holds everything strictly preceding
+            # the trigger
+            n_active = sum(r is not None for r in self._resident)
+            self._observe_arena()
+            self._record_frame(n_active / self.slots)
+            with self._prof.phase("serve/dispatch", fill=n_active,
+                                  tick=self._tick) as ph:
+                if self._faults is not None and self._faults.fire(
+                        "serve.dispatch"):
+                    raise RuntimeError("injected serve.dispatch fault")
+                finished = self._engine.step()
+            # divergence sentinel: the slot-chunk program is the one
+            # dispatch shape continuous mode executes — price once, then
+            # compare every chunk's achieved bytes/s against it
+            self._prof.observe_dispatch("serve/dispatch",
+                                        self._dispatch_key, ph.dt)
+            self._h_occupancy.observe(n_active / self.slots)
+            for idx, req in enumerate(self._resident):
+                if req is not None:
+                    self._chunks[idx] += 1
+            with self._prof.phase("serve/harvest"):
+                self._harvest(finished)
         return True
 
     def fail_resident(self, error: BaseException) -> int:
@@ -650,6 +695,7 @@ class ContinuousBatcher:
                 continue
             self._engine.release(idx)
             self._resident[idx] = None
+            self._close_stage(req, "resident")
             req.future._reject(error)
             n += 1
         self._c_errors.inc(n)
@@ -666,6 +712,7 @@ class ContinuousBatcher:
         n = 0
         while self._prefilled:
             req, _ = self._prefilled.popleft()
+            self._close_stage(req, "slot_wait")
             req.future._reject(error)
             n += 1
         if n:

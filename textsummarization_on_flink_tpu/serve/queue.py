@@ -208,7 +208,8 @@ class ServeRequest:
     """One admitted (or about-to-be-admitted) summarization request."""
 
     __slots__ = ("uuid", "article", "reference", "example", "future",
-                 "deadline", "enqueue_t", "trace", "tier", "tenant")
+                 "deadline", "enqueue_t", "stage_t", "trace", "tier",
+                 "tenant")
 
     def __init__(self, uuid: str, article: str, reference: str,
                  example: Any, deadline: Optional[Deadline] = None,
@@ -251,6 +252,10 @@ class ServeRequest:
         # less room and degrades (or at worst expires) honestly
         self.deadline = deadline if deadline is not None else Deadline.never()
         self.enqueue_t = time.monotonic()
+        # the stage clock's previous mark (serve/request_stage_seconds):
+        # each lifecycle site on the dispatch thread observes the time
+        # since this mark and moves it, on the same monotonic clock
+        self.stage_t = self.enqueue_t
 
 
 class RequestQueue:
@@ -343,7 +348,7 @@ class RequestQueue:
                                     cause="breaker_open")
             raise ServeOverloadError(
                 "request shed: admission breaker open (sustained overload)")
-        req.enqueue_t = time.monotonic()
+        req.enqueue_t = req.stage_t = time.monotonic()
         # lifecycle root event BEFORE the queue put: the instant the
         # request becomes visible to the dispatch thread it may emit
         # admit/slot/resolve, and those must never precede enqueue in

@@ -486,6 +486,16 @@ class ServingServer:
     def serve_mode(self) -> str:
         return self._mode
 
+    def compiled_slot_step(self):
+        """The continuous engine's compiled slot step
+        (``SlotDecodeEngine.compiled_step``): memory analysis and the
+        instruction -> named-scope map come from the program through
+        here, not from the engine's private state."""
+        if self._cont is None:
+            raise RuntimeError(
+                f"serve_mode={self._mode!r} has no slot step")
+        return self._cont.engine.compiled_step()
+
     def __enter__(self) -> "ServingServer":
         return self.start()
 
@@ -906,10 +916,9 @@ class ServingServer:
         # the effective tier so the /profile phase table splits beam
         # from greedy from spec wall time
         prof = profile_lib.profiler_for(self._reg)
-        t0 = prof.start()
         try:
-            with obs.spans.span(self._reg, "serve/dispatch",
-                                fill=len(group), tier=tier or "legacy"):
+            with prof.phase("serve/dispatch", fill=len(group),
+                            tier=tier or "legacy") as ph:
                 if self._faults.fire("serve.dispatch"):
                     raise RuntimeError("injected serve.dispatch fault")
                 batch = self._batcher.build(group)
@@ -920,9 +929,8 @@ class ServingServer:
                 else:
                     results = self._decoder.decode_batch(
                         batch, deadline=deadline, tier=tier)
-            dt = prof.end("serve/dispatch", t0)
             prof.observe_dispatch(
-                "serve/dispatch", f"tier_{tier or 'legacy'}", dt)
+                "serve/dispatch", f"tier_{tier or 'legacy'}", ph.dt)
             if len(results) != len(group):
                 raise RuntimeError(
                     f"decoder returned {len(results)} results for "

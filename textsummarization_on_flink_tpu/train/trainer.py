@@ -754,63 +754,61 @@ class Trainer:
         multi-step scan otherwise."""
         if not pending:
             return
-        p0 = self._prof.start()
-        # the fetch is a blocking D2H sync — its cost is exactly the
-        # dispatch-serialization price the windowing amortizes, so it is
-        # measured (train/metrics_fetch_seconds) rather than guessed
-        t_fetch = time.perf_counter()
-        with obs.spans.span(self._obs, "train/metrics_flush",
-                            parent=self._trace, step=pending[0][0]):
+        with self._prof.phase("train/metrics_flush", parent=self._trace,
+                              step=pending[0][0]):
+            # the fetch is a blocking D2H sync — its cost is exactly the
+            # dispatch-serialization price the windowing amortizes, so it is
+            # measured (train/metrics_fetch_seconds) rather than guessed
+            t_fetch = time.perf_counter()
             fetched = jax.device_get([m for _, _, m, _ in pending])
-        self._m_fetch.observe(time.perf_counter() - t_fetch)
-        total = sum(n for _, n, _, _ in pending)
-        step_time = window_dt / max(total, 1)
-        for _ in range(total):  # window average, one sample per step
-            self._m_step_time.observe(step_time)
-        log.info("seconds for training step: %.3f (avg over %d)",
-                 step_time, total)
-        prefetch_depth = self._g_prefetch.value
-        for (step0, n, _, arrays), m in zip(pending, fetched):
-            for i in range(n):
-                step = step0 + i
-                pick = (lambda x: x) if n == 1 else (lambda x: x[i])
-                loss = float(pick(m.loss))
-                log.info("loss: %f", loss)
-                scalars = dict(loss=loss,
-                               total_loss=float(pick(m.total_loss)),
-                               global_norm=float(pick(m.global_norm)),
-                               step_time=step_time)
-                if self.hps.coverage:
-                    cl = float(pick(m.coverage_loss))
-                    log.info("coverage_loss: %f", cl)
-                    scalars["coverage_loss"] = cl
-                # per-step flight frame: what the NaN post-mortem reads
-                # (a finite-or-not loss ships either way — the LAST
-                # frames before a blowup are the interesting ones)
-                flightrec.record(
-                    self._obs, "train_step", step=step, loss=loss,
-                    global_norm=float(pick(m.global_norm)),
-                    step_time=round(step_time, 6),
-                    prefetch_depth=prefetch_depth)
-                if not np.isfinite(loss):
-                    self._c_nan.inc()
-                    self._dump_nan_batch(step, arrays)
-                    flightrec.trigger(self._obs, "train_nan", step=step)
-                    # worst case: the bad step opens a window that only
-                    # flushes at >= metrics_every steps, reached in whole
-                    # k-step dispatches — so up to metrics_every + k - 2
-                    # steps can run past it (ADVICE r3)
-                    lag = max(max(self.metrics_every, 1)
-                              + self.steps_per_dispatch - 2, 0)
-                    raise NonFiniteLossError(
-                        f"Loss is not finite. Stopping. "
-                        f"(step {step}, loss {loss}; detection is "
-                        f"windowed — up to {lag} "
-                        f"optimizer steps may have run past the first "
-                        f"bad one; --debug pins the window to 1 for "
-                        f"step-exact detection)")
-                self.writer.scalars(step + 1, **scalars)
-        self._prof.end("train/metrics_flush", p0)
+            self._m_fetch.observe(time.perf_counter() - t_fetch)
+            total = sum(n for _, n, _, _ in pending)
+            step_time = window_dt / max(total, 1)
+            for _ in range(total):  # window average, one sample per step
+                self._m_step_time.observe(step_time)
+            log.info("seconds for training step: %.3f (avg over %d)",
+                     step_time, total)
+            prefetch_depth = self._g_prefetch.value
+            for (step0, n, _, arrays), m in zip(pending, fetched):
+                for i in range(n):
+                    step = step0 + i
+                    pick = (lambda x: x) if n == 1 else (lambda x: x[i])
+                    loss = float(pick(m.loss))
+                    log.info("loss: %f", loss)
+                    scalars = dict(loss=loss,
+                                   total_loss=float(pick(m.total_loss)),
+                                   global_norm=float(pick(m.global_norm)),
+                                   step_time=step_time)
+                    if self.hps.coverage:
+                        cl = float(pick(m.coverage_loss))
+                        log.info("coverage_loss: %f", cl)
+                        scalars["coverage_loss"] = cl
+                    # per-step flight frame: what the NaN post-mortem reads
+                    # (a finite-or-not loss ships either way — the LAST
+                    # frames before a blowup are the interesting ones)
+                    flightrec.record(
+                        self._obs, "train_step", step=step, loss=loss,
+                        global_norm=float(pick(m.global_norm)),
+                        step_time=round(step_time, 6),
+                        prefetch_depth=prefetch_depth)
+                    if not np.isfinite(loss):
+                        self._c_nan.inc()
+                        self._dump_nan_batch(step, arrays)
+                        flightrec.trigger(self._obs, "train_nan", step=step)
+                        # worst case: the bad step opens a window that only
+                        # flushes at >= metrics_every steps, reached in whole
+                        # k-step dispatches — so up to metrics_every + k - 2
+                        # steps can run past it (ADVICE r3)
+                        lag = max(max(self.metrics_every, 1)
+                                  + self.steps_per_dispatch - 2, 0)
+                        raise NonFiniteLossError(
+                            f"Loss is not finite. Stopping. "
+                            f"(step {step}, loss {loss}; detection is "
+                            f"windowed — up to {lag} "
+                            f"optimizer steps may have run past the first "
+                            f"bad one; --debug pins the window to 1 for "
+                            f"step-exact detection)")
+                    self.writer.scalars(step + 1, **scalars)
 
     def _dump_nan_batch(self, step: int, arrays) -> None:
         """--debug: persist the batch that produced a non-finite loss
@@ -899,177 +897,174 @@ class Trainer:
             # per-round wall bracket (obs/profile.py, ISSUE 16): the
             # sub-phases below sum toward it, and the gap is the loop's
             # unattributed overhead (stacking, bookkeeping)
-            w0 = self._prof.start()
-            # k batches per dispatch (steps_per_dispatch), clipped to the
-            # remaining step budget so the limit stays exact
-            k = self.steps_per_dispatch
-            if limit:
-                k = min(k, limit - step)
-            items = []
-            t_wait = time.perf_counter()
-            p0 = self._prof.start()
-            while len(items) < k:
-                item = prefetcher.next_batch()
-                if item is None:
-                    exhausted = True
+            with self._prof.wall("train/round"):
+                # k batches per dispatch (steps_per_dispatch), clipped to the
+                # remaining step budget so the limit stays exact
+                k = self.steps_per_dispatch
+                if limit:
+                    k = min(k, limit - step)
+                items = []
+                t_wait = time.perf_counter()
+                with self._prof.phase("train/host_wait"):
+                    while len(items) < k:
+                        item = prefetcher.next_batch()
+                        if item is None:
+                            exhausted = True
+                            break
+                        items.append(item)
+                # host-wait: time the loop spent blocked on the input side
+                # while the device sat idle (dispatch itself is async)
+                self._m_host_wait.observe(time.perf_counter() - t_wait)
+                if exhausted and (multihost and (limit == 0 or step + len(items)
+                                                 < limit)):
+                    raise RuntimeError(
+                        f"batcher exhausted at step {step + len(items)} before "
+                        f"the num_steps={limit} limit on a multi-host run; "
+                        f"other hosts may still be issuing collectives — "
+                        f"aborting instead of desyncing")
+                if not items:
+                    log.info("batcher exhausted; stopping training at step %d",
+                             step)
                     break
-                items.append(item)
-            # host-wait: time the loop spent blocked on the input side
-            # while the device sat idle (dispatch itself is async)
-            self._m_host_wait.observe(time.perf_counter() - t_wait)
-            self._prof.end("train/host_wait", p0)
-            if exhausted and (multihost and (limit == 0 or step + len(items)
-                                             < limit)):
-                raise RuntimeError(
-                    f"batcher exhausted at step {step + len(items)} before "
-                    f"the num_steps={limit} limit on a multi-host run; "
-                    f"other hosts may still be issuing collectives — "
-                    f"aborting instead of desyncing")
-            if not items:
-                log.info("batcher exhausted; stopping training at step %d",
-                         step)
-                break
-            if profile_dir and not profiling and not profile_done \
-                    and step >= profile_start:
-                self._flush_metrics(pending, time.monotonic() - window_t0)
-                pending = []
-                pending_steps = 0
-                jax.profiler.start_trace(profile_dir)
-                profiling = True
-                window_t0 = time.monotonic()
-                # the capture's opening edge in the profiler ledger
-                # (ISSUE 16): /profile names the step range a trace
-                # covers without grepping logs
-                self._prof.note("profiler_capture", dir=str(profile_dir),
-                                start_step=profile_start,
-                                stop_step=profile_stop)
-                log.info("profiler trace started -> %s", profile_dir)
-            n = len(items)
-            p0 = self._prof.start()
-            try:
-                if n == 1:
-                    _, arrays = items[0]
-                    new_state, metrics = self._step_fn(self.state, arrays)
-                else:
-                    # stack on device: k tiny int/float batch arrays gain
-                    # a leading scan axis (bytes ~ k x the batch, trivial
-                    # next to one dispatch round trip)
-                    arrays = jax.tree_util.tree_map(
-                        lambda *xs: jnp.stack(xs),
-                        *[a for _, a in items])
-                    new_state, metrics = self._multi_step(n)(
-                        self.state, arrays)
-                    arrays = None
-            except FloatingPointError as e:
-                # jax_debug_nans (--debug, which pins n=1) raises inside
-                # the step with the op-level location; still dump the
-                # offending batch and surface the watchdog error type
-                self._c_nan.inc()
-                self._dump_nan_batch(step, arrays)
-                flightrec.trigger(self._obs, "train_nan", step=step)
-                if self._recovery is not None:
-                    # the step never completed, so self.state is still
-                    # the pre-dispatch state — skip/rollback from it
-                    if self._recover(step):
-                        # recovery path, not the per-step path: one sync
-                        # to learn the resume step
-                        step = int(np.asarray(self.state.step))  # tslint: disable=TS002
-                        continue
-                    raise NanLossError(
-                        f"Loss is not finite and divergence recovery is "
-                        f"exhausted. Stopping. (step {step}; "
-                        f"jax_debug_nans trace above)") from e
-                raise NonFiniteLossError(
-                    f"Loss is not finite. Stopping. (step {step}; "
-                    f"jax_debug_nans trace above)") from e
-            # dispatch-submit time (async under jax: device compute
-            # overlaps with the host loop; the blocking D2H fetches are
-            # the metrics-flush phase, not this one)
-            dt = self._prof.end("train/step_dispatch", p0)
-            self._prof.observe_dispatch("train/step_dispatch", "step", dt)
-            injected = self._faults.fire("train.step_nan")
-            if self._recovery is not None:
-                # armed: one D2H metrics sync per dispatch — poisoned
-                # state must never outlive the dispatch that made it (the
-                # documented cost of arming, config.py nan_skip_steps)
-                fetched = jax.device_get(metrics)  # tslint: disable=TS002
-                finite = bool(np.all(np.isfinite(np.asarray(fetched.loss))))  # tslint: disable=TS002 — host data
-                if injected or not finite:
-                    self._c_nan.inc()
-                    self._dump_nan_batch(step, arrays)
-                    flightrec.trigger(self._obs, "train_nan", step=step,
-                                      injected=bool(injected))
-                    # new_state is discarded; self.state (pre-dispatch,
-                    # never donated when armed) remains the live params
-                    if self._recover(step):
-                        step = int(np.asarray(self.state.step))  # tslint: disable=TS002
-                        continue
-                    raise NanLossError(
-                        f"Loss is not finite and divergence recovery is "
-                        f"exhausted. Stopping. (step {step}"
-                        f"{'; injected train.step_nan' if injected else ''})")
-                self.state = new_state
-                self._recovery.note_good(new_state)
-                metrics = fetched  # flush below reuses the fetched copy
-            else:
-                # the dispatch itself completed: publish its state BEFORE
-                # any injected raise, so self.state never points at
-                # buffers the donated step already consumed (an on-error
-                # handler may still save it)
-                self.state = new_state
-                if injected:
-                    self._c_nan.inc()
-                    flightrec.trigger(self._obs, "train_nan", step=step,
-                                      injected=True)
-                    raise NonFiniteLossError(
-                        f"injected train.step_nan fault at step {step} "
-                        f"(divergence recovery unarmed: nan_skip_steps and "
-                        f"nan_max_rollbacks are 0)")
-            pending.append((step, n, metrics,
-                            arrays if self.hps.debug else None))
-            prev_step = step
-            step += n
-            pending_steps += n
-            self._c_steps.inc(n)
-            self._c_examples.inc(n * self.hps.batch_size)
-            if pending_steps >= flush_every or self._recovery is not None:
-                self._flush_metrics(pending, time.monotonic() - window_t0)
-                pending = []
-                pending_steps = 0
-                window_t0 = time.monotonic()
-            if profiling and step > profile_stop:
-                # the finalize edge gets its own span so one capture is
-                # one linkable event in events.jsonl (trace_summary.py
-                # lanes show the trace window next to the step spans)
-                with obs.spans.span(self._obs, "train/profiler_capture",
-                                    parent=self._trace,
-                                    start_step=profile_start,
-                                    stop_step=profile_stop):
-                    jax.profiler.stop_trace()
-                profiling = False
-                profile_done = True
-                log.info("profiler trace written to %s", profile_dir)
-            if self.checkpointer is not None:
-                if checkpoint_steps > 0:
-                    # crossed a cadence boundary this dispatch — identical
-                    # arithmetic on every host, so saves stay collective
-                    # even when k does not divide checkpoint_steps
-                    due = (step // checkpoint_steps
-                           ) != (prev_step // checkpoint_steps)
-                else:
-                    due = time.monotonic() - last_ckpt >= self.checkpoint_secs
-                if due:
-                    # the save fetches state anyway; fold the metrics
-                    # flush into the same sync point
+                if profile_dir and not profiling and not profile_done \
+                        and step >= profile_start:
                     self._flush_metrics(pending, time.monotonic() - window_t0)
                     pending = []
                     pending_steps = 0
-                    p0 = self._prof.start()
-                    self.checkpointer.save(self.state)
-                    self._prof.end("train/checkpoint", p0)
-                    last_ckpt = time.monotonic()
+                    jax.profiler.start_trace(profile_dir)
+                    profiling = True
                     window_t0 = time.monotonic()
-            self._prof.end_wall("train/round", w0)
+                    # the capture's opening edge in the profiler ledger
+                    # (ISSUE 16): /profile names the step range a trace
+                    # covers without grepping logs
+                    self._prof.note("profiler_capture", dir=str(profile_dir),
+                                    start_step=profile_start,
+                                    stop_step=profile_stop)
+                    log.info("profiler trace started -> %s", profile_dir)
+                n = len(items)
+                try:
+                    with self._prof.phase("train/step_dispatch") as ph:
+                        if n == 1:
+                            _, arrays = items[0]
+                            new_state, metrics = self._step_fn(self.state, arrays)
+                        else:
+                            # stack on device: k tiny int/float batch arrays gain
+                            # a leading scan axis (bytes ~ k x the batch, trivial
+                            # next to one dispatch round trip)
+                            arrays = jax.tree_util.tree_map(
+                                lambda *xs: jnp.stack(xs),
+                                *[a for _, a in items])
+                            new_state, metrics = self._multi_step(n)(
+                                self.state, arrays)
+                            arrays = None
+                except FloatingPointError as e:
+                    # jax_debug_nans (--debug, which pins n=1) raises inside
+                    # the step with the op-level location; still dump the
+                    # offending batch and surface the watchdog error type
+                    self._c_nan.inc()
+                    self._dump_nan_batch(step, arrays)
+                    flightrec.trigger(self._obs, "train_nan", step=step)
+                    if self._recovery is not None:
+                        # the step never completed, so self.state is still
+                        # the pre-dispatch state — skip/rollback from it
+                        if self._recover(step):
+                            # recovery path, not the per-step path: one sync
+                            # to learn the resume step
+                            step = int(np.asarray(self.state.step))  # tslint: disable=TS002
+                            continue
+                        raise NanLossError(
+                            f"Loss is not finite and divergence recovery is "
+                            f"exhausted. Stopping. (step {step}; "
+                            f"jax_debug_nans trace above)") from e
+                    raise NonFiniteLossError(
+                        f"Loss is not finite. Stopping. (step {step}; "
+                        f"jax_debug_nans trace above)") from e
+                # dispatch-submit time (async under jax: device compute
+                # overlaps with the host loop; the blocking D2H fetches are
+                # the metrics-flush phase, not this one)
+                self._prof.observe_dispatch("train/step_dispatch", "step",
+                                            ph.dt)
+                injected = self._faults.fire("train.step_nan")
+                if self._recovery is not None:
+                    # armed: one D2H metrics sync per dispatch — poisoned
+                    # state must never outlive the dispatch that made it (the
+                    # documented cost of arming, config.py nan_skip_steps)
+                    fetched = jax.device_get(metrics)  # tslint: disable=TS002
+                    finite = bool(np.all(np.isfinite(np.asarray(fetched.loss))))  # tslint: disable=TS002 — host data
+                    if injected or not finite:
+                        self._c_nan.inc()
+                        self._dump_nan_batch(step, arrays)
+                        flightrec.trigger(self._obs, "train_nan", step=step,
+                                          injected=bool(injected))
+                        # new_state is discarded; self.state (pre-dispatch,
+                        # never donated when armed) remains the live params
+                        if self._recover(step):
+                            step = int(np.asarray(self.state.step))  # tslint: disable=TS002
+                            continue
+                        raise NanLossError(
+                            f"Loss is not finite and divergence recovery is "
+                            f"exhausted. Stopping. (step {step}"
+                            f"{'; injected train.step_nan' if injected else ''})")
+                    self.state = new_state
+                    self._recovery.note_good(new_state)
+                    metrics = fetched  # flush below reuses the fetched copy
+                else:
+                    # the dispatch itself completed: publish its state BEFORE
+                    # any injected raise, so self.state never points at
+                    # buffers the donated step already consumed (an on-error
+                    # handler may still save it)
+                    self.state = new_state
+                    if injected:
+                        self._c_nan.inc()
+                        flightrec.trigger(self._obs, "train_nan", step=step,
+                                          injected=True)
+                        raise NonFiniteLossError(
+                            f"injected train.step_nan fault at step {step} "
+                            f"(divergence recovery unarmed: nan_skip_steps and "
+                            f"nan_max_rollbacks are 0)")
+                pending.append((step, n, metrics,
+                                arrays if self.hps.debug else None))
+                prev_step = step
+                step += n
+                pending_steps += n
+                self._c_steps.inc(n)
+                self._c_examples.inc(n * self.hps.batch_size)
+                if pending_steps >= flush_every or self._recovery is not None:
+                    self._flush_metrics(pending, time.monotonic() - window_t0)
+                    pending = []
+                    pending_steps = 0
+                    window_t0 = time.monotonic()
+                if profiling and step > profile_stop:
+                    # the finalize edge gets its own span so one capture is
+                    # one linkable event in events.jsonl (trace_summary.py
+                    # lanes show the trace window next to the step spans)
+                    with obs.spans.span(self._obs, "train/profiler_capture",
+                                        parent=self._trace,
+                                        start_step=profile_start,
+                                        stop_step=profile_stop):
+                        jax.profiler.stop_trace()
+                    profiling = False
+                    profile_done = True
+                    log.info("profiler trace written to %s", profile_dir)
+                if self.checkpointer is not None:
+                    if checkpoint_steps > 0:
+                        # crossed a cadence boundary this dispatch — identical
+                        # arithmetic on every host, so saves stay collective
+                        # even when k does not divide checkpoint_steps
+                        due = (step // checkpoint_steps
+                               ) != (prev_step // checkpoint_steps)
+                    else:
+                        due = time.monotonic() - last_ckpt >= self.checkpoint_secs
+                    if due:
+                        # the save fetches state anyway; fold the metrics
+                        # flush into the same sync point
+                        self._flush_metrics(pending, time.monotonic() - window_t0)
+                        pending = []
+                        pending_steps = 0
+                        with self._prof.phase("train/checkpoint"):
+                            self.checkpointer.save(self.state)
+                        last_ckpt = time.monotonic()
+                        window_t0 = time.monotonic()
         self._flush_metrics(pending, time.monotonic() - window_t0)
         if profiling:
             jax.profiler.stop_trace()
