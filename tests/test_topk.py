@@ -1,0 +1,179 @@
+"""ops.topk.top_k against jax.lax.top_k: values bit-equal, ids equal —
+the contract the beam step's selection stands on (ISSUE 26) — and that
+the selection ENGAGES where the benchmark's cell runs it: the slot step
+lowered at pg_see2017's vocabulary holds no sort and no top-k over a
+50 128-wide operand.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import __graft_entry__ as ge
+from _hlo import wide_row_orderings
+from textsummarization_on_flink_tpu.config import HParams
+from textsummarization_on_flink_tpu.decode import beam_search
+from textsummarization_on_flink_tpu.models import get_family
+from textsummarization_on_flink_tpu.ops import topk
+
+LENGTHS = (7, 64, 1000, 50000, 50128, 152064)
+LEAD = (3, 2)
+
+
+def _softmax(rng, n):
+    z = rng.normal(size=LEAD + (n,)).astype(np.float32) * 3
+    e = np.exp(z - z.max(-1, keepdims=True))
+    return e / e.sum(-1, keepdims=True)
+
+
+def _zero_tail(rng, n):
+    """The extended vocabulary's OOV buckets: 128 exact zeros at the
+    end of a softmax (an article with no OOV word)."""
+    x = _softmax(rng, n)
+    x[..., -min(128, n - 1):] = 0.0
+    return x
+
+
+def _many_ties(rng, n):
+    return rng.integers(0, 5, size=LEAD + (n,)).astype(np.float32)
+
+
+def _tie_on_kth(rng, n):
+    """k - 1 clear winners, then MORE equal values than places are
+    left, far apart: the k-th place goes to the lowest index."""
+    del rng
+    x = np.zeros(LEAD + (n,), np.float32)
+    x[..., n // 2] = 3.0  # the one clear winner at k = 2
+    x[..., [n - 1, 3, n // 3, n - 2, 1, n // 2 + 1]] = 2.0
+    x[..., [n - 3, 5, 2 * n // 3]] = 1.0
+    return x
+
+
+def _all_equal(rng, n):
+    del rng
+    return np.full(LEAD + (n,), 0.25, np.float32)
+
+
+def _neg_inf(rng, n):
+    """-inf entries, and rows with fewer finite values than k."""
+    x = _softmax(rng, n)
+    x[..., ::3] = -np.inf
+    x[0] = -np.inf
+    x[0, :, n // 2] = 1.0
+    return x
+
+
+ROWS = {"softmax": _softmax, "zero_tail": _zero_tail,
+        "many_ties": _many_ties, "tie_on_kth": _tie_on_kth,
+        "all_equal": _all_equal, "neg_inf": _neg_inf}
+
+
+def _bits(a):
+    return np.asarray(a.astype(jnp.float32)).view(np.uint32)
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["plain", "vmap"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("n,k", [(n, k) for n in LENGTHS for k in (2, 8)
+                                 if k <= n])
+def test_top_k_is_lax_top_k(n, k, dtype, vmapped):
+    rng = np.random.default_rng(n + k)
+    fn = lambda r: topk.top_k(r, k)  # noqa: E731
+    if vmapped:  # two leading axes, as the slot step (slots, then beam)
+        fn = jax.vmap(jax.vmap(fn))
+    fn = jax.jit(fn)
+    for name, make in ROWS.items():
+        x = jnp.asarray(make(rng, n)).astype(dtype)
+        want_v, want_i = jax.lax.top_k(x, k)
+        got_v, got_i = fn(x)
+        assert got_v.dtype == want_v.dtype and got_i.dtype == want_i.dtype
+        np.testing.assert_array_equal(_bits(got_v), _bits(want_v),
+                                      err_msg=name)
+        np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i),
+                                      err_msg=name)
+
+
+def test_plan_is_pinned():
+    """The one choice, from the row's length and k alone: selection at
+    the cell's width, lax.top_k at a test vocabulary's."""
+    assert topk._plan(50128, 8) == "select"
+    assert topk._plan(152064, 8) == "select"
+    assert topk._plan(50128, 2) == "select"
+    assert topk._plan(64, 8) == "lax"
+    assert topk._plan(50128, 64) == "lax"
+
+
+def test_short_rows_and_integers_are_lax_top_k_itself():
+    x = jnp.arange(24.0).reshape(2, 12)
+    assert "top_k" in str(jax.make_jaxpr(lambda r: topk.top_k(r, 4))(x))
+    big = jnp.zeros((2, 1024), jnp.int32)
+    assert "top_k" in str(jax.make_jaxpr(lambda r: topk.top_k(r, 4))(big))
+    assert "top_k" not in str(jax.make_jaxpr(
+        lambda r: topk.top_k(r, 4))(big.astype(jnp.float32)))
+
+
+# -- the mechanism engages where the cell runs it ---------------------------
+
+#: pg_see2017's vocabulary (50 000 + 128 OOV buckets) and beam; every
+#: other width is small: the selection is chosen from the row alone
+PG_WIDE = HParams(batch_size=2, hidden_dim=8, emb_dim=6, vocab_size=50000,
+                  max_oov_buckets=128, beam_size=4, max_enc_steps=12,
+                  max_dec_steps=8, min_dec_steps=2, mode="decode",
+                  decode_enc_block=4)
+TF_WIDE = PG_WIDE.replace(model_family="transformer", emb_dim=8, num_heads=2,
+                          enc_layers=1, dec_layers=1)
+WIDE = PG_WIDE.vocab_size + PG_WIDE.max_oov_buckets  # 50 128
+
+
+def test_the_detector_sees_a_stock_top_k():
+    x = jnp.zeros((2, 4, WIDE), jnp.float32)
+    stock = jax.jit(lambda r: jax.lax.top_k(r, 8)).lower(x).compile()
+    assert wide_row_orderings(stock.as_text(), WIDE)
+    ours = jax.jit(lambda r: topk.top_k(r, 8)).lower(x).compile()
+    assert not wide_row_orderings(ours.as_text(), WIDE)
+
+
+def test_paged_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
+    """The engine's own executable (SlotDecodeEngine.compiled_step(),
+    behind ServingServer.compiled_slot_step()): two slots over the
+    paged arena at pg_see2017's vocabulary and beam."""
+    from textsummarization_on_flink_tpu.data.vocab import Vocab
+    from textsummarization_on_flink_tpu.obs import Registry
+    from textsummarization_on_flink_tpu.serve.server import ServingServer
+    from textsummarization_on_flink_tpu.train import trainer as trainer_lib
+
+    words = ["the", "cat", "sat", "dog", "ran", "."]
+    vocab = Vocab(words=words + [f"w{i}" for i in range(50000 - 4 - len(
+        words))])
+    assert vocab.size() == 50000
+    hps = PG_WIDE.replace(
+        max_enc_steps=16, max_dec_steps=4, min_dec_steps=1,
+        serve_buckets="16", serve_mode="continuous", serve_slots=2,
+        serve_refill_chunk=2, serve_arena_pages=8)
+    params = trainer_lib.init_train_state(hps, vocab.size(), seed=0).params
+    server = ServingServer(hps, vocab, params=params,
+                           decode_root=str(tmp_path / "d"),
+                           registry=Registry())
+    with server:
+        server.submit("the cat sat .", uuid="a").result(timeout=600)
+        assert server._cont.engine.paged
+        text = server.compiled_slot_step().as_text()
+    assert str(WIDE) in text  # the step does hold the wide row
+    assert not wide_row_orderings(text, WIDE)
+
+
+def test_transformer_slot_step_at_the_cells_vocabulary_orders_no_wide_row():
+    hps = TF_WIDE
+    family = get_family(hps.model_family)
+    params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(0))
+    B = hps.batch_size
+    arrays = ge._decode_arrays(hps, np.random.RandomState(1), B)
+    pages = 6
+    paged = beam_search.init_slots_paged_jit(params, hps, arrays, pages)
+    table = np.full((B, 3), pages, np.int32)
+    text = beam_search.step_slots_paged_jit.lower(
+        params, hps, paged, np.ones(B, bool), table, 2).compile().as_text()
+    assert str(WIDE) in text
+    assert not wide_row_orderings(text, WIDE)

@@ -22,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import __graft_entry__ as ge
+from _hlo import wide_row_orderings
 from textsummarization_on_flink_tpu.config import HParams, resolve_enc_block
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -165,6 +166,18 @@ def test_prefill_compiles_for_v5e(bucket, one_chip):
         _enc_arrays(hps, 1, one_chip, width=bucket)).compile()
 
 
+def _orders_no_vocabulary_row(compiled, hps):
+    """The beam step keeps 2 x beam of the extended vocabulary by
+    selection (ops/topk.py): XLA:TPU turns a `vmap`ped ``lax.top_k``
+    into a full sort of every [.., 50 128] row, 86% of the slot step's
+    device time before ISSUE 26 — in the chip's own compiler's output,
+    no sort and no top-k may have an operand that wide."""
+    text = compiled.as_text()
+    width = hps.vocab_size + hps.max_oov_buckets
+    assert str(width) in text
+    assert not wide_row_orderings(text, width)
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 @pytest.mark.parametrize("family", ["pointer_generator", "transformer"])
 def test_slot_step_compiles_for_v5e(family, paged, one_chip):
@@ -175,8 +188,9 @@ def test_slot_step_compiles_for_v5e(family, paged, one_chip):
     if not paged:
         state = _on(one_chip, jax.eval_shape(
             lambda: beam_search.init_slots_jit(params, hps, arrays)))
-        beam_search.step_slots_jit.lower(params, hps, state, active,
-                                         CHUNK).compile()
+        compiled = beam_search.step_slots_jit.lower(
+            params, hps, state, active, CHUNK).compile()
+        _orders_no_vocabulary_row(compiled, hps)
         return
     b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
     pages = SLOTS * b_max // 2
@@ -185,8 +199,9 @@ def test_slot_step_compiles_for_v5e(family, paged, one_chip):
                                                  pages)))
     table = jax.ShapeDtypeStruct((SLOTS, b_max), np.int32,
                                  sharding=one_chip)
-    beam_search.step_slots_paged_jit.lower(params, hps, state, active,
-                                           table, CHUNK).compile()
+    compiled = beam_search.step_slots_paged_jit.lower(
+        params, hps, state, active, table, CHUNK).compile()
+    _orders_no_vocabulary_row(compiled, hps)
 
 
 # -- one program across the four chips -------------------------------------

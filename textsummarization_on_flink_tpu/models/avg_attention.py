@@ -66,6 +66,7 @@ from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import pointer_generator as pg
 from textsummarization_on_flink_tpu import models as models_lib
 from textsummarization_on_flink_tpu.models import transformer as tf
+from textsummarization_on_flink_tpu.ops import topk as topk_ops
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -464,8 +465,8 @@ def beam_adapter(hps: HParams):
             params, hps, enc_one, enc_mask, ext_ids, t, latest,
             state["aan_sum"], nb=nb)
         with jax.named_scope("topk"):
-            topk_probs, topk_ids = jax.lax.top_k(final_dist,
-                                                 2 * hps.beam_size)
+            topk_probs, topk_ids = topk_ops.top_k(final_dist,
+                                                  2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,

@@ -36,6 +36,7 @@ from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.ops import attention as attn_ops
 from textsummarization_on_flink_tpu.ops import losses as loss_ops
 from textsummarization_on_flink_tpu.ops import lstm as lstm_ops
+from textsummarization_on_flink_tpu.ops import topk as topk_ops
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -397,7 +398,7 @@ def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
                                     x, attn_dist, enc_batch_extend_vocab)
     k = 2 * hps.beam_size  # model.py:284 (batch_size==beam_size there)
     with jax.named_scope("topk"):
-        topk_probs, topk_ids = jax.lax.top_k(final_dist, k)
+        topk_probs, topk_ids = topk_ops.top_k(final_dist, k)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,
@@ -449,7 +450,7 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
         params, hps, cell_out, context, new_state, x, attn_dist,
         jnp.broadcast_to(ext_ids[None], (K,) + ext_ids.shape))
     with jax.named_scope("topk"):
-        topk_probs, topk_ids = jax.lax.top_k(final_dist, 2 * hps.beam_size)
+        topk_probs, topk_ids = topk_ops.top_k(final_dist, 2 * hps.beam_size)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,

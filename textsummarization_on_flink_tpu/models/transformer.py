@@ -55,6 +55,7 @@ from textsummarization_on_flink_tpu import models as models_lib
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import pointer_generator as pg
 from textsummarization_on_flink_tpu.ops import losses as loss_ops
+from textsummarization_on_flink_tpu.ops import topk as topk_ops
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -677,8 +678,8 @@ def beam_adapter(hps: HParams):
                                                   cross_ctx, attn_dist,
                                                   ext_ids)
         with jax.named_scope("topk"):
-            topk_probs, topk_ids = jax.lax.top_k(final_dist,
-                                                 2 * hps.beam_size)
+            topk_probs, topk_ids = topk_ops.top_k(final_dist,
+                                                  2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,
@@ -787,6 +788,6 @@ def spec_verify(params: Params, hps: HParams, enc_one: TransformerEncView,
         cross_ctx = cross_out
     final_dist, p_gen, _ = decode_output_tail(params, hps, y, cross_ctx,
                                               attn_dist, ext_ids)
-    topk_probs, topk_ids = jax.lax.top_k(final_dist, 2)
+    topk_probs, topk_ids = topk_ops.top_k(final_dist, 2)
     return (topk_ids, jnp.log(topk_probs + 1e-10), attn_dist, p_gen,
             {"cache_k": cache_k, "cache_v": cache_v})
