@@ -154,6 +154,7 @@ def run_serve(cfg, mix, seed: int, seconds: float, work: str, meter,
     at the first measured instant."""
     import jax
 
+    from textsummarization_on_flink_tpu.config import resolve_refill_chunk
     from textsummarization_on_flink_tpu.obs import profile as profile_lib
     from textsummarization_on_flink_tpu.pipeline.io import CollectionSink
     from textsummarization_on_flink_tpu.serve.errors import ServeOverloadError
@@ -167,7 +168,7 @@ def run_serve(cfg, mix, seed: int, seconds: float, work: str, meter,
     n_req = max(1, int(round(float(mix["rate_per_s"]) * seconds)))
     offsets = traffic_lib.arrival_offsets(mix, n_req, seed)
     articles = traffic_lib.make_articles(
-        mix, V, n_req, seed, clock=cfg["init"].get("summary_clock"))
+        mix, V, n_req, seed, clock=weights.summary_clock(cfg))
     words = traffic_lib.Words(V, mix["article"])
     warm_rng = traffic_lib.rng_for(seed, 9)
     server = ServingServer(hps, vocab, params=params,
@@ -253,16 +254,14 @@ def run_serve(cfg, mix, seed: int, seconds: float, work: str, meter,
         snap1 = registry_snapshot(reg)
         if tracer is not None:
             tracer.finish()
-        engine = server._cont._engine
-        from textsummarization_on_flink_tpu.decode import beam_search
-        if engine.paged:
-            temp = program_temp_bytes(
-                beam_search.step_slots_paged_jit, engine._params(), hps,
-                engine._state, engine._active, engine._table, engine.chunk)
-        else:
-            temp = program_temp_bytes(
-                beam_search.step_slots_jit, engine._params(), hps,
-                engine._state, engine._active, engine.chunk)
+        # the slot step as the server ran it, through the program's own
+        # accessor: XLA's figure for its scratch and, in a traced run,
+        # its text (the instruction -> named scope map of the capture)
+        compiled = server.compiled_slot_step()
+        temp = int(getattr(compiled.memory_analysis(),
+                           "temp_size_in_bytes", 0) or 0)
+        slot_step_hlo = compiled.as_text() if tracer is not None else None
+        del compiled
     finally:
         server.stop(timeout=30.0)
     run.attempted = len(sent)
@@ -278,17 +277,29 @@ def run_serve(cfg, mix, seed: int, seconds: float, work: str, meter,
             run.failed += 1
             run.latencies_ms.append(1e3 * (t_drained - due[art.uuid]))
     mean_len = float(np.mean([len(a.ids) for a in sent])) if sent else 0.0
+    # the longest stretch in which no summary came back, and when it
+    # began: a tick is under half a second, so a stall of the server (or
+    # of the whole host: the generator's own lateness shows that) stands
+    # out of an untraced run too (PERF.md section 7)
+    done = np.sort([resolved[a.uuid] for a in sent if a.uuid in results])
+    silence, silence_at = 0.0, 0.0
+    if len(done) > 1:
+        i = int(np.argmax(np.diff(done)))
+        silence, silence_at = float(done[i + 1] - done[i]), float(done[i] - t0)
     ctx = {"registry0": snap0, "registry1": snap1,
            "compiles_in_window": compiles1 - compiles0,
            "tracer": tracer, "window_s": run.window_s,
-           "program_temp_bytes": temp,
-           "hparams": cfg["hparams"],
+           "program_temp_bytes": temp, "slot_step_hlo": slot_step_hlo,
+           "family": cfg["family"], "hparams": cfg["hparams"],
            "deployment": dict(cfg["deployment"]["serve"],
-                              chunk=engine.chunk,
-                              slots=int(hps.serve_slots)),
+                              chunk=resolve_refill_chunk(hps),
+                              slots=int(hps.serve_slots),
+                              param_bytes=weights.param_dtype(cfg).itemsize),
            "harness": {"mean_article_len": mean_len,
                        "summary_tokens_mean":
-                           run.tokens_out / max(1, len(run.finished))},
+                           run.tokens_out / max(1, len(run.finished)),
+                       "longest_silence_ms": 1e3 * silence,
+                       "longest_silence_at_s": silence_at},
            "errors": {u: f"{type(e).__name__}: {e}"
                       for u, e in list(errors.items())[:5]}}
     del server, params
@@ -465,8 +476,9 @@ def run_train(cfg, mix, seed: int, seconds: float, work: str, meter,
            "compiles_in_window": compiles1 - compiles0,
            "tracer": tracer, "window_s": run.window_s,
            "program_temp_bytes": temp,
-           "hparams": cfg["hparams"],
-           "deployment": dict(cfg["deployment"]["train"]),
+           "family": cfg["family"], "hparams": cfg["hparams"],
+           "deployment": dict(cfg["deployment"]["train"],
+                              param_bytes=weights.param_dtype(cfg).itemsize),
            "harness": {"steps": run.steps}}
     trainer.writer.close()
     del trainer, state

@@ -1,11 +1,13 @@
-"""What the two plain references share: the pointer mixture, the loss,
+"""What the plain references share: the pointer mixture, the loss,
 gradients in blocks of rows, TF1 Adagrad with global-norm clipping, the
 scoring of served tokens, and See et al.'s beam search in plain Python.
 Imports nothing of the program.
 
-`family(name)` returns the module holding `encode` / `decode` for a
-configuration's "family".  All functions take `hp`, the config file's
-"hparams" dict.
+`family(name)` returns the module harness/families/<name>.py of a
+configuration's "family": its `encode` / `decode` / `LOG_EPS` feed the
+pointer mixture here, unless the family gives `token_logprobs` /
+`next_dist` of its own (no copy distribution, a head over a slice of the
+vocabulary).  All functions take `hp`, the config file's "hparams" dict.
 """
 
 from __future__ import annotations
@@ -13,6 +15,7 @@ from __future__ import annotations
 import functools
 import importlib
 import json
+import os
 from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
@@ -23,17 +26,28 @@ UNK_ID, PAD_ID, START_ID, STOP_ID = 0, 1, 2, 3
 
 
 def family(name: str):
-    return importlib.import_module(
-        {"pointer_generator": "harness.reference_pg",
-         "transformer": "harness.reference_tf"}[name])
+    """The module of a configuration's "family"; a name with no file
+    under harness/families/ is an error that lists the files there."""
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "families")
+    have = sorted(f[:-3] for f in os.listdir(here)
+                  if f.endswith(".py") and not f.startswith("_"))
+    if name not in have:
+        raise ValueError(f"no model family {name!r}: benchmark/harness/"
+                       f"families/ holds {have}")
+    return importlib.import_module("harness.families." + name)
 
 
 # ---------------------------------------------------------------- mixture
 
 def _token_logprobs(fam, p, hp, ids, ext_ids, n, dec_inputs, targets,
                     decode_mode):
-    """log P(targets[t]) under the pointer mixture at every position,
-    for one article.  ids/ext_ids [T], dec_inputs/targets [Td]."""
+    """log P(targets[t]) at every position, for one article: the
+    family's own, else the pointer mixture.  ids/ext_ids [T],
+    dec_inputs/targets [Td]."""
+    if hasattr(fam, "token_logprobs"):
+        return fam.token_logprobs(p, hp, ids, ext_ids, n, dec_inputs,
+                                  targets, decode_mode)
     V = int(hp["vocab_size"])
     enc = fam.encode(p, hp, ids, n)
     proj_in, W, b, att, pgen = fam.decode(p, hp, enc, dec_inputs, decode_mode)
@@ -54,7 +68,11 @@ def _token_logprobs(fam, p, hp, ids, ext_ids, n, dec_inputs, targets,
 def final_dist_at(fam, p, hp, ids, ext_ids, n, dec_inputs, t):
     """The extended-vocabulary distribution [V + oov] for the token that
     follows dec_inputs[:t+1] (decode semantics), one article, K rows:
-    dec_inputs [K, Td]."""
+    dec_inputs [K, Td]: each row the family's own `next_dist`, else the
+    pointer mixture."""
+    if hasattr(fam, "next_dist"):
+        return jax.vmap(lambda row: fam.next_dist(
+            p, hp, ids, ext_ids, n, row, t))(dec_inputs)
     V, n_oov = int(hp["vocab_size"]), int(hp["max_oov_buckets"])
     enc = fam.encode(p, hp, ids, n)
     valid = jnp.arange(ids.shape[0]) < n
@@ -210,11 +228,13 @@ def _topk_fn(family_module: str, hp_json: str):
 
 
 def beam_search(fam, p, hp, art_ids: np.ndarray, ext_ids: np.ndarray,
-                ) -> Tuple[List[int], float]:
+                on_step=None) -> Tuple[List[int], float]:
     """See et al.'s beam search (abisee beam_search.py) for one article,
     in plain Python over the reference's distributions.  Returns (the
     best hypothesis' generated tokens, its length-normalised log
-    probability: total over len(tokens) + 1 for START)."""
+    probability: total over len(tokens) + 1 for START).  `on_step(step,
+    ranked, kept, results)` sees every pruning (a builder's look at where
+    two searches part: tools/probe.py); it changes nothing."""
     K, V = int(hp["beam_size"]), int(hp["vocab_size"])
     Te, Td = int(hp["max_enc_steps"]), int(hp["max_dec_steps"])
     min_steps = int(hp["min_dec_steps"])
@@ -244,7 +264,8 @@ def beam_search(fam, p, hp, art_ids: np.ndarray, ext_ids: np.ndarray,
                 all_h.append((hyps[i][0] + [int(cand_toks[i, j])],
                               hyps[i][1] + float(cand_lp[i, j])))
         hyps = []
-        for h in sorted(all_h, key=avg, reverse=True):
+        ranked = sorted(all_h, key=avg, reverse=True)
+        for h in ranked:
             if h[0][-1] == STOP_ID:
                 if steps >= min_steps:
                     results.append(h)
@@ -252,6 +273,8 @@ def beam_search(fam, p, hp, art_ids: np.ndarray, ext_ids: np.ndarray,
                 hyps.append(h)
             if len(hyps) == K or len(results) == K:
                 break
+        if on_step is not None:
+            on_step(steps, ranked, hyps, results)
         steps += 1
     if not results:
         results = hyps

@@ -1,4 +1,4 @@
-"""Two reader kinds that split what the accepted metrics only total, both
+"""The reader kinds that split what the other metrics only total, both
 over ONE profiler capture and on its own clock: the program's host phases
 (`Profiler.phase` opens a `jax.profiler.TraceAnnotation`, so they lie in
 the capture's host plane, on the thread that ran them) and the device
@@ -22,6 +22,8 @@ operations' named scopes (`jax.named_scope` in the decode programs).
                innermost claims it, and `"scope": null` reads what none
                of them claims — so the metrics of one partition add up
                to the program's device time.
+  scope_roofline  (readers.py) the same self time under the least time
+               for a named count: `scope_reading` is what it shares.
 
 `load(log_dir)` reads a capture into plain lists; `idle_by_phase`,
 `self_times` and `read` are pure Python over them and are what the tests
@@ -60,17 +62,15 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 def load(log_dir: str) -> Dict[str, Any]:
     """{"planes": what `trace.load` gives (device lines),
     "threads": one list of phase-named events per host line}."""
-    return {"planes": trace_lib.load(log_dir),
-            "threads": host_threads(log_dir)}
+    data = trace_lib.parse(log_dir)  # one parse of the file for both
+    return {"planes": trace_lib.load(log_dir, data),
+            "threads": host_threads(data)}
 
 
-def host_threads(log_dir: str) -> List[List[Event]]:
-    """The phase-named events of the newest capture under `log_dir`, one
-    list per host line (a line is a thread)."""
-    from jax.profiler import ProfileData
-
+def host_threads(data) -> List[List[Event]]:
+    """The phase-named events of a parsed capture, one list per host
+    line (a line is a thread)."""
     threads: List[List[Event]] = []
-    data = ProfileData.from_file(trace_lib.newest_xplane(log_dir))
     for plane in data.planes:
         if not plane.name.startswith("/host:CPU"):
             continue
@@ -232,6 +232,33 @@ def scope_seconds(capture: Dict[str, Any], scopes_of: Dict[str, List[str]],
     return out
 
 
+def _memo(ctx: Dict[str, Any], key, make):
+    """One reading of the capture for all the metrics that share it."""
+    memo = ctx.setdefault("_splits", {})
+    if key not in memo:
+        memo[key] = make()
+    return memo[key]
+
+
+def scope_reading(src: Dict[str, Any], ctx: Dict[str, Any],
+                  ) -> Optional[Dict[Any, float]]:
+    """`scope_seconds` for a source that names "program", "scope" and
+    optionally the whole partition "scopes", over ctx["capture"] and
+    ctx["slot_step_hlo"] — or None where there is no capture, no text,
+    no run of the program, or none of the partition's scopes in it."""
+    capture, hlo = ctx.get("capture"), ctx.get("slot_step_hlo")
+    if not capture or not hlo:
+        return None
+    partition = tuple(src.get("scopes") or [src["scope"]])
+    scopes_of = _memo(ctx, "scope_map", lambda: scope_map(hlo))
+    r = _memo(ctx, ("scope", src["program"], partition),
+              lambda: scope_seconds(capture, scopes_of, src["program"],
+                                    partition))
+    if r is None or not any(r[s] > 0 for s in partition):
+        return None  # the program carries none of these scopes
+    return r
+
+
 def read(src: Dict[str, Any], ctx: Dict[str, Any]) -> Optional[float]:
     """A metric file's "source" of kind trace_phase or trace_scope over
     ctx["capture"] (`load`) and ctx["slot_step_hlo"] (the compiled slot
@@ -247,15 +274,6 @@ def read(src: Dict[str, Any], ctx: Dict[str, Any]) -> Optional[float]:
             return None
         return r[src.get("phase")] / r["window_s"] * 100.0
     if kind == "trace_scope":
-        hlo = ctx.get("slot_step_hlo")
-        if not hlo:
-            return None
-        partition = src.get("scopes") or [src["scope"]]
-        scopes_of = ctx.get("_scope_map")
-        if scopes_of is None:  # one parse of the text for all metrics
-            scopes_of = ctx["_scope_map"] = scope_map(hlo)
-        r = scope_seconds(capture, scopes_of, src["program"], partition)
-        if r is None or not any(r[s] > 0 for s in partition):
-            return None  # the program carries none of these scopes
-        return r[src.get("scope")] / r["calls"] * 1e3
+        r = scope_reading(src, ctx)
+        return None if r is None else r[src.get("scope")] / r["calls"] * 1e3
     raise KeyError(f"unknown reader kind {kind!r}")

@@ -36,16 +36,22 @@ def newest_xplane(log_dir: str) -> Optional[str]:
     return max(paths, key=os.path.getmtime) if paths else None
 
 
-def load(log_dir: str) -> Dict[str, Dict[str, List[Event]]]:
-    """{plane name: {line name: [(event name, start_ns, duration_ns)]}}
-    for the device planes, plus under the key "host" the one line that
-    holds the harness's own sync annotation (if any)."""
+def parse(log_dir: str):
+    """The newest capture under `log_dir` as `jax.profiler.ProfileData`."""
     from jax.profiler import ProfileData
 
     path = newest_xplane(log_dir)
     if path is None:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-    data = ProfileData.from_file(path)
+    return ProfileData.from_file(path)
+
+
+def load(log_dir: str, data=None) -> Dict[str, Dict[str, List[Event]]]:
+    """{plane name: {line name: [(event name, start_ns, duration_ns)]}}
+    for the device planes, plus under the key "host" the one line that
+    holds the harness's own sync annotation (if any).  `data`: the
+    capture where the caller has parsed it already."""
+    data = data if data is not None else parse(log_dir)
     out: Dict[str, Dict[str, List[Event]]] = {}
     for plane in data.planes:
         is_dev = DEVICE_PLANE.match(plane.name) is not None
@@ -69,9 +75,7 @@ def load(log_dir: str) -> Dict[str, Dict[str, List[Event]]]:
 def describe(log_dir: str, limit: int = 6) -> Dict[str, Any]:
     """Every plane and line of the newest trace with its event count and
     first few event names: what a builder reads before trusting `load`."""
-    from jax.profiler import ProfileData
-
-    data = ProfileData.from_file(newest_xplane(log_dir))
+    data = parse(log_dir)
     out = {}
     for plane in data.planes:
         lines = {}

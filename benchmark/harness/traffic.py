@@ -132,22 +132,23 @@ class Article:
 
 
 def make_articles(mix: Dict[str, Any], vocab_size: int, n: int, seed: int,
-                  prefix: str = "r", clock: Dict[str, Any] = None,
+                  prefix: str = "r", clock=(None, None),
                   ) -> List[Article]:
     """n articles for a serving mix: lengths are the mix's quantiles in a
     seed-drawn order, words drawn from the seed.
 
     Where the mix asks for summary lengths (`summary.length`) and the
-    configuration's weights carry a summary clock (`clock`: its
-    init.summary_clock, see weights.py), each article's FIRST word is
-    moved to the nearest word of the same Zipf neighbourhood whose id
-    codes for the length wanted: the summary lengths are the mix's
-    quantiles too, in an order of their own."""
+    configuration's weights carry a summary clock (`clock`: the pair
+    `weights.summary_clock(cfg)` gives), each article's FIRST word is
+    moved to the word of the same Zipf neighbourhood that codes for the
+    length wanted (the family's `word_for_length`): the summary lengths
+    are the mix's quantiles too, in an order of their own."""
     words = Words(vocab_size, mix["article"])
     order_rng, word_rng = rng_for(seed, 1), rng_for(seed, 2)
     lengths = quantile_lengths(mix["article"]["length"], n)
     order_rng.shuffle(lengths)
     wanted = None
+    fam, clock = clock
     if mix.get("summary") and clock:
         wanted = quantile_lengths(mix["summary"]["length"], n)
         rng_for(seed, 4).shuffle(wanted)
@@ -155,14 +156,10 @@ def make_articles(mix: Dict[str, Any], vocab_size: int, n: int, seed: int,
     for i, L in enumerate(lengths):
         w, ids, ext = words.draw(word_rng, int(L))
         if wanted is not None:
-            codes, lo = int(clock["codes"]), int(clock["min_tokens"])
-            if not lo <= wanted[i] < lo + codes:
-                raise ValueError(f"no code for a summary of {wanted[i]}")
             # a Zipf rank whatever the draw put first (never an OOV word)
             rank = int(np.searchsorted(words.cdf, word_rng.random()))
-            rank = rank - rank % codes + int(wanted[i]) - lo
-            if rank >= words.n_words:
-                rank -= codes
+            rank = fam.word_for_length(clock, int(wanted[i]), rank,
+                                       words.n_words)
             w[0], ids[0], ext[0] = f"w{rank}", rank + N_SPECIAL, \
                 rank + N_SPECIAL
             if any(x.startswith("oov") for x in w[1:]):

@@ -15,7 +15,9 @@ line marked as a rehearsal with no device metric in it.
 Everything a cell is made of is data: BENCHMARK.json names the cell, its
 configuration (benchmark/configs/), its traffic mix (benchmark/traffic/),
 its own file (benchmark/workloads/: the limits of `correct`) and its
-per-layer metrics (benchmark/metrics/).
+per-layer metrics (benchmark/metrics/); what the harness knows of a
+model family is one module under benchmark/harness/families/, found by
+the configuration's "family".
 """
 
 from __future__ import annotations
@@ -85,6 +87,13 @@ def apply_rehearsal(cfg, mix, cell_file):
     if "summary_clock" in cfg["init"]:
         cfg["init"].update(tiny["init"])
     cell_file["check"].update(tiny["check"])
+    # then the configuration's own rehearsal sizes: a family with widths
+    # of its own shrinks them in its own file
+    own = cfg.get("rehearse", {})
+    cfg["hparams"].update(own.get("hparams", {}))
+    for role, over in own.get("deployment", {}).items():
+        cfg["deployment"].setdefault(role, {}).update(over)
+    cfg["init"].update(own.get("init", {}))
 
 
 def build_native_bridge() -> None:
@@ -112,7 +121,7 @@ def run_cell(bench, cell, cfg, mix, cell_file, seed: int, seconds: float,
     run cannot measure."""
     import jax
 
-    from harness import cells, correct, readers, traffic
+    from harness import cells, correct, readers, splits, traffic
     from harness import trace as trace_lib
     from harness.compile_meter import CompileMeter
     from harness.peaks import peaks_for
@@ -168,8 +177,11 @@ def run_cell(bench, cell, cfg, mix, cell_file, seed: int, seconds: float,
         ctx["trace"] = None
         tracer = ctx.pop("tracer")
         if tracer is not None:
+            # the capture itself (device lines and the host's phases) for
+            # the readers that split it, and its reduction for the rest
+            ctx["capture"] = splits.load(tracer.log_dir)
             ctx["trace"] = trace_lib.reduce(
-                trace_lib.load(tracer.log_dir), tracer.window_s, chips,
+                ctx["capture"]["planes"], tracer.window_s, chips,
                 phases=tracer.phases, sync_epoch_ns=tracer.sync_epoch_ns)
         # ---- correct: the timed path's output against the reference ----
         check = cell_file["check"]
@@ -197,7 +209,8 @@ def run_cell(bench, cell, cfg, mix, cell_file, seed: int, seconds: float,
     short = {k: [v["value"], v["limit"]] for k, v in compared.items()}
     out = {"e2e": e2e, "harness": ctx["harness"], "run": run,
            "read_numbers": read_numbers, "detail": detail,
-           "errors": ctx.get("errors"), "programs": None}
+           "words": None if kind == "train_job" else words,
+           "errors": ctx.get("errors"), "programs": None, "ctx": ctx}
     if rehearse:
         out["line"] = {"rehearsal": True, "workload": name,
                        "platform": dev.platform, "correct": ok,
@@ -232,16 +245,25 @@ def run_cell(bench, cell, cfg, mix, cell_file, seed: int, seconds: float,
                              "idle_gaps": tr["idle_gaps"]}
         out["programs"] = {k: v for k, v in sorted(
             tr["programs"].items(), key=lambda kv: -kv[1]["total_s"])[:12]}
+    # what the harness itself saw of the run (the driver ignores the key;
+    # the next builder reads it where a run reads far off)
+    line["harness"] = ctx["harness"]
     line["compared"] = short  # each number beside its limit, last
     out["line"] = line
     return out
 
 
-def prepare_process(rehearse: int = 0) -> None:
+def prepare_process(rehearse: int = 0, trace: int = 0) -> None:
     """What has to be settled before jax is imported: the platform of a
     rehearsal, the compile cache, the program and its native bridge."""
     if rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    if trace:
+        # named scopes are metadata, which the compile cache leaves out of
+        # its key: without this a cached executable carries the op_names
+        # of whatever build compiled it (PERF.md section 6, PR 25)
+        os.environ.setdefault(
+            "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "1")
     # the compile cache: where the machine says, else one fixed path in
     # the checkout (the program's own helper leaves a set variable alone)
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
@@ -274,7 +296,7 @@ def main(argv=None) -> int:
     if args.rehearse:
         apply_rehearsal(cfg, mix, cell_file)
     try:
-        prepare_process(args.rehearse)
+        prepare_process(args.rehearse, args.trace)
         out = run_cell(bench, cell, cfg, mix, cell_file, args.seed,
                        args.seconds, args.trace, args.rehearse)
     except SystemExit as e:

@@ -87,10 +87,11 @@ def test_training_control_fails_the_limits(cell, name, held):
     assert not correct.judge(fault, _limits(cell))[0]
 
 
-@pytest.mark.parametrize("cell,name", _cells("open_loop"))
-def test_serving_control_fails_the_limits(cell, name):
+def _served_by_the_reference(name, n):
+    """(cfg, words, clock, finished, tokens): n articles at the middle
+    size, each "served" by the reference's own beam search."""
     cfg = _mid(name)
-    # the summary clock at this size: lengths 5..24 (weights.py)
+    # the summary clock at this size: lengths 5..24 (families/pointer_generator.py)
     cfg["init"]["stop_bias"] = -12.6
     clock = cfg["init"]["summary_clock"] = {
         "units": 4, "gain": 24.0, "step": 0.03, "phase": 0.002,
@@ -109,12 +110,11 @@ def test_serving_control_fails_the_limits(cell, name):
     class Res:
         pass
 
-    finished, off = [], []
-    for art in traffic.make_articles(mix, hp["vocab_size"], 6, 3,
-                                     clock=clock):
+    finished, tokens = [], []
+    for art in traffic.make_articles(mix, hp["vocab_size"], n, 3,
+                                     clock=weights.summary_clock(cfg)):
         toks, avg = ref.beam_search(fam, params, hp, art.ids, art.ext)
-        off.append(len(toks) - int(weights.length_code(clock,
-                                                       int(art.ids[0]))))
+        tokens.append(toks)
         r = Res()
         out = [t for t in toks if t != ref.STOP_ID]
         r.decoded_words = [f"w{t - 4}" if 4 <= t < hp["vocab_size"]
@@ -124,6 +124,15 @@ def test_serving_control_fails_the_limits(cell, name):
                            for t in out]
         r.avg_log_prob = avg
         finished.append((art, r))
+    return cfg, words, clock, finished, tokens
+
+
+@pytest.mark.parametrize("cell,name", _cells("open_loop"))
+def test_serving_control_fails_the_limits(cell, name):
+    cfg, words, clock, finished, tokens = _served_by_the_reference(name, 6)
+    fam = ref.family(cfg["family"])
+    off = [len(toks) - int(fam.length_code(clock, int(art.ids[0])))
+           for (art, _), toks in zip(finished, tokens)]
     # the clock works: each summary ends near the length its article's
     # first word codes for
     assert max(abs(x) for x in off) <= 3, off
@@ -135,3 +144,22 @@ def test_serving_control_fails_the_limits(cell, name):
                                     control=True)
     control["compiles_in_window"] = 0
     assert not correct.judge(control, _limits(cell))[0], control
+
+
+@pytest.mark.parametrize("altered,fails", [(1, ("beam_gap",)),
+                                           (5, ("beam_gap",
+                                                "beam_gap_median"))])
+@pytest.mark.parametrize("cell,name", _cells("open_loop"))
+def test_an_altered_answer_fails_a_beam_number(cell, name, altered, fails):
+    """One answer of five that is not the search's best (three of its
+    words altered) fails the widest gap and leaves the median where it
+    was; all five fail both."""
+    cfg, words, _, finished, _ = _served_by_the_reference(name, 5)
+    for _, r in finished[:altered]:
+        r.decoded_words[1:4] = ["w7", "w8", "w9"]
+    sample = {"score": 5, "beam": 5}
+    numbers = correct.serve_numbers(cfg, 3, finished, words, sample)
+    limits = _limits(cell)
+    over = tuple(k for k in ("beam_gap", "beam_gap_median")
+                 if numbers[k] > limits[k])
+    assert over == fails, numbers

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from harness import counts
+from harness import counts, reference
 from tests.tiny import BENCH
 
 
@@ -20,7 +20,8 @@ def test_train_flops_equal_bench_py(name, fn):
         cfg = json.load(f)
     dep = cfg["deployment"]["train"]
     hps = HParams(batch_size=dep["batch_size"], **cfg["hparams"])
-    mine = counts.train_step(cfg["hparams"], dep)
+    mine = counts.train_step(reference.family(cfg["family"]),
+                             cfg["hparams"], dep)
     assert mine["flops"] == pytest.approx(getattr(bench, fn)(hps), rel=1e-12)
     assert mine["bytes"] > 6 * 4 * 20e6
 
@@ -30,14 +31,15 @@ def test_parameter_counts_and_decode_counts():
         pg = json.load(f)
     with open(os.path.join(BENCH, "configs", "tf_cnndm.json")) as f:
         tf = json.load(f)
-    assert 21e6 < counts.n_params(pg["hparams"]) < 22e6
-    assert 50e6 < counts.n_params(tf["hparams"]) < 60e6
-    for cfg in (pg, tf):
+    fams = [reference.family(c["family"]) for c in (pg, tf)]
+    assert 21e6 < counts.n_params(fams[0], pg["hparams"]) < 22e6
+    assert 50e6 < counts.n_params(fams[1], tf["hparams"]) < 60e6
+    for fam, cfg in zip(fams, (pg, tf)):
         dep = dict(chunk=25, slots=64)
-        one = counts.slot_chunk(cfg["hparams"], dep, 1.0, 400.0)
-        two = counts.slot_chunk(cfg["hparams"], dep, 2.0, 400.0)
+        one = counts.slot_chunk(fam, cfg["hparams"], dep, 1.0, 400.0)
+        two = counts.slot_chunk(fam, cfg["hparams"], dep, 2.0, 400.0)
         assert two["flops"] == pytest.approx(2 * one["flops"])
         assert one["bytes"] < two["bytes"] < 2 * one["bytes"]
-        short = counts.prefill(cfg["hparams"], dep, 100.0)
-        long = counts.prefill(cfg["hparams"], dep, 400.0)
+        short = counts.prefill(fam, cfg["hparams"], dep, 100.0)
+        long = counts.prefill(fam, cfg["hparams"], dep, 400.0)
         assert 0 < short["flops"] < long["flops"]

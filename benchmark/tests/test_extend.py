@@ -1,6 +1,8 @@
 """A new configuration, traffic mix, cell and per-layer metric over an
-existing source kind are added by new files and new entries alone: done
-here in a temporary copy, and the added cell rehearses."""
+existing source kind, and a new MODEL FAMILY with a parameter type, a
+rehearsal block and a count of its own, are added by new files and new
+entries alone: done here in a temporary copy, and the added cells
+rehearse."""
 import json
 import os
 import shutil
@@ -12,7 +14,9 @@ from tests.tiny import BENCH, benchmark_with_held
 ROOT = os.path.dirname(BENCH)
 
 
-def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
+def _copy(tmp_path):
+    """A temporary checkout: benchmark/ copied, the program linked.
+    Returns every file of benchmark/ as it is, for the walk at the end."""
     before = {}
     for dirpath, _, files in os.walk(BENCH):
         for f in files:
@@ -23,6 +27,22 @@ def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(ROOT, "textsummarization_on_flink_tpu"),
                tmp_path / "textsummarization_on_flink_tpu")
+    return before
+
+
+def _rehearse(tmp_path, cell):
+    p = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", cell,
+         "--seed", "4", "--seconds", "2", "--trace", "0", "--rehearse",
+         "1"], cwd=str(tmp_path), env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["attempted"] > 0, cell
+
+
+def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
+    before = _copy(tmp_path)
     # the held cells' entries (benchmark/held/) go back in the same way:
     # entries alone, their files are still there
     b = json.load(open(benchmark_with_held(tmp_path)))
@@ -67,15 +87,7 @@ def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
                            "workloads": ["pg_other_even"]})
     json.dump(b, open(tmp_path / "BENCHMARK.json", "w"))
     for cell in ["pg_other_even"] + held:
-        p = subprocess.run(
-            [sys.executable, "benchmark/run.py", "--workload", cell,
-             "--seed", "4", "--seconds", "2", "--trace", "0", "--rehearse",
-             "1"], cwd=str(tmp_path), env=dict(os.environ,
-                                               JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-2000:]
-        line = json.loads(p.stdout.strip().splitlines()[-1])
-        assert line["correct"] is True and line["attempted"] > 0, cell
+        _rehearse(tmp_path, cell)
     # the new metric's reader finds its counter in a run's registry
     sys.path.insert(0, str(nb))
     from harness import readers
@@ -85,5 +97,108 @@ def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
            "harness": {}, "trace": None}
     assert readers.read(metric, ctx) == 5.0
     # and no file the benchmark already had was edited
+    for rel, data in before.items():
+        assert open(nb / rel, "rb").read() == data, rel
+
+
+READ_THE_NEW_METRIC = """
+import json, sys
+sys.path[:0] = ["benchmark", "."]
+import run as bench_run
+from harness import readers
+from tests.test_splits import HLO, capture
+bench, cell, cfg, mix, cell_file = bench_run.load_cell("pg_third_even")
+bench_run.apply_rehearsal(cfg, mix, cell_file)
+occ = {"count": 4, "sum": 2.0, "buckets": [1.0], "counts": [4, 0],
+       "min": 0.5, "max": 0.5}
+ctx = {"capture": capture(), "slot_step_hlo": HLO, "family": cfg["family"],
+       "hparams": cfg["hparams"], "harness": {},
+       "deployment": {"slots": 4, "chunk": 5}, "registry0": {},
+       "registry1": {"serve/slot_occupancy": occ},
+       "peaks": {"flops_per_s": 1e12, "bytes_per_s": 1e6}}
+spec = bench_run._load("metrics", "third_rows_roofline.even.json")
+print(json.dumps({"value": readers.read(spec, ctx),
+                  "hidden_dim": cfg["hparams"]["hidden_dim"],
+                  "slots": cfg["deployment"]["serve"]["serve_slots"],
+                  "units": cfg["init"]["summary_clock"]["units"],
+                  "dtype": str(__import__("harness.weights", fromlist=["w"])
+                               .param_dtype(cfg))}))
+"""
+
+
+def test_add_a_model_family_by_files_alone(tmp_path):
+    """The case the family modules exist for: a THIRD family (its module
+    a copy of the pointer-generator's under another name, with a count of
+    its own), a configuration of it that states its parameter type and
+    its own rehearsal sizes, a cell, and a roofline-by-scope metric over
+    the new count: files and entries only, and the cell rehearses."""
+    before = _copy(tmp_path)
+    b = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    nb = tmp_path / "benchmark"
+    fam = open(nb / "harness" / "families" / "pointer_generator.py").read()
+    fam += '''
+
+def count_vocab_rows(hp, dep, ctx):
+    """One read of the occupied residents' vocabulary rows a step."""
+    from harness import readers
+
+    rows = readers.occupied_slots(ctx) * int(hp["beam_size"])
+    return {"flops": 0.0, "bytes": float(
+        rows * int(hp["vocab_size"]) * 4 * int(dep["chunk"]))}
+'''
+    with open(nb / "harness" / "families" / "pg_third.py", "w") as f:
+        f.write(fam)
+    cfg = json.load(open(nb / "configs" / "pg_see2017.json"))
+    cfg.update(name="pg_third", family="pg_third", param_dtype="float32",
+               rehearse={"hparams": {"hidden_dim": 24, "emb_dim": 12},
+                         "deployment": {"serve": {"serve_slots": 3}},
+                         "init": {"summary_clock": dict(
+                             cfg["init"]["summary_clock"], units=3,
+                             gain=12.0, step=0.1, phase=0.01, codes=6,
+                             min_tokens=3)}})
+    json.dump(cfg, open(nb / "configs" / "pg_third.json", "w"))
+    mix = json.load(open(nb / "traffic" / "news_open_loop.json"))
+    mix.update(arrivals="uniform", rate_per_s=9.0)
+    json.dump(mix, open(nb / "traffic" / "even_slow.json", "w"))
+    json.dump({"name": "pg_third_even",
+               "check": {"sample": {"score": 4, "beam": 1}},
+               "limits": {"score_gap": 1e-3, "beam_gap": 1e-3,
+                          "compiles_in_window": 0}},
+              open(nb / "workloads" / "pg_third_even.json", "w"))
+    layer = "models and kernels (models/, ops/)"
+    metric = {"name": "third_rows_roofline.even", "unit": "%",
+              "layer": layer, "moves": "summary_p95_ms",
+              "source": {"kind": "scope_roofline", "count": "vocab_rows",
+                         "program": "^jit_step_slots(_paged)?_jit$",
+                         "scope": "topk"}}
+    json.dump(metric,
+              open(nb / "metrics" / "third_rows_roofline.even.json", "w"))
+    b["configs"].append({"name": "pg_third", "source": cfg["source"],
+                         "file": "benchmark/configs/pg_third.json",
+                         "reduced": [], "why": "throw-away"})
+    b["workloads"].append({"name": "pg_third_even", "config": "pg_third",
+                           "traffic": "even_slow", "chips": 1,
+                           "why": "throw-away"})
+    for m in b["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"].append("pg_third_even")
+    b["per_layer"].append({"name": metric["name"], "unit": "%",
+                           "better": "higher", "source": "device_trace",
+                           "layer": layer, "moves": "summary_p95_ms",
+                           "workloads": ["pg_third_even"]})
+    json.dump(b, open(tmp_path / "BENCHMARK.json", "w"))
+    _rehearse(tmp_path, "pg_third_even")
+    # the new metric reads a capture through the new family's count, at
+    # the configuration's own rehearsal sizes and parameter type
+    p = subprocess.run([sys.executable, "-c", READ_THE_NEW_METRIC],
+                       cwd=str(tmp_path), capture_output=True, text=True,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    # 2 occupied x beam 2 x 64 words x 4 B x 5 steps = 5 120 B a run:
+    # 5.12 ms at 1 MB/s, over topk's 37.5 ms a run
+    assert abs(got.pop("value") - 5.12 / 37.5 * 100.0) < 1e-9
+    assert got == {"hidden_dim": 24, "slots": 3, "units": 3,
+                   "dtype": "float32"}
     for rel, data in before.items():
         assert open(nb / rel, "rb").read() == data, rel

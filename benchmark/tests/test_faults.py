@@ -81,7 +81,8 @@ def test_a_token_altered_where_it_is_produced(monkeypatch, capsys, cell):
 
     _, _, cfg, _, _ = bench_run.load_cell(cell)
     model = importlib.import_module(
-        "textsummarization_on_flink_tpu.models." + cfg["family"])
+        "textsummarization_on_flink_tpu.models."
+        + cfg["hparams"]["model_family"])
     real = model.beam_adapter_masked
 
     def broken(hps):
@@ -100,6 +101,6 @@ def test_a_token_altered_where_it_is_produced(monkeypatch, capsys, cell):
     monkeypatch.setattr(model, "beam_adapter_masked", broken)
     line = _rehearse(capsys, cell)
     assert line["correct"] is False
-    for number in ("score_gap", "beam_gap"):
+    for number in ("score_gap", "beam_gap", "beam_gap_median"):
         value, limit = line["compared"][number]
         assert value > limit, number

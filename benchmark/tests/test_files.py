@@ -5,6 +5,8 @@ import json
 import os
 import re
 
+import numpy as np
+
 from tests.tiny import BENCH
 
 ROOT = os.path.dirname(BENCH)
@@ -12,7 +14,8 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 KINDS = {"registry", "registry_ratio", "harness", "trace_program",
-         "trace_device", "roofline", "mfu"}
+         "trace_device", "roofline", "mfu", "trace_phase", "trace_scope",
+         "scope_roofline"}
 
 
 def _bench():
@@ -125,13 +128,17 @@ def test_cross_references():
     assert on_disk("traffic") == {w["traffic"] for w in b["workloads"]} | {
         h["workload"]["traffic"] for h in held}
     # a mix that asks for summary lengths needs a configuration whose
-    # weights carry the clock, with a code for each length
+    # weights carry the clock, and a family whose words code each length
+    from harness import weights
+
     for w in b["workloads"]:
         with open(os.path.join(BENCH, "traffic", w["traffic"] + ".json")) as f:
             mix = json.load(f)
         if "summary" in mix:
             with open(os.path.join(ROOT, configs[w["config"]]["file"])) as f:
-                clock = json.load(f)["init"]["summary_clock"]
+                cfg = json.load(f)
+            fam, clock = weights.summary_clock(cfg)
             spec = mix["summary"]["length"]
-            assert clock["min_tokens"] <= spec["min"]
-            assert spec["max"] < clock["min_tokens"] + clock["codes"]
+            coded = set(fam.length_code(clock, np.arange(
+                4, int(cfg["hparams"]["vocab_size"]))).tolist())
+            assert set(range(spec["min"], spec["max"] + 1)) <= coded

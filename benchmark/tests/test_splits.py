@@ -1,7 +1,8 @@
-"""The two split readers (harness/splits.py) on a hand-built capture
-with known answers, the sums that say a split closes, the loader on a
-capture recorded here, and the proof that a further phase, scope or stage
-metric is a file and an entry only."""
+"""The split readers (harness/splits.py, and `scope_roofline` over them
+in harness/readers.py) on a hand-built capture with known answers, the
+sums that say a split closes, the loader on a capture recorded here, and
+the proof that a further phase, scope or stage metric is a file and an
+entry only."""
 import json
 import os
 import re
@@ -208,25 +209,25 @@ def test_loader_keeps_phase_named_events_per_thread(tmp_path):
     assert splits.read(_phase_spec("serve/dispatch"), {"capture": cap}) is None
 
 
-def test_the_proposed_metrics_are_whole_and_keep_to_the_contract():
-    with open(os.path.join(BENCH, "proposed", "trace_splits.json")) as f:
-        doc = json.load(f)
+def _split_metrics():
+    """{name: (BENCHMARK.json entry, metric file)} of the per-layer
+    metrics whose reader is one of the split kinds."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entries = {e["name"]: e for e in doc["per_layer"]}
-    files = {m["name"]: m for m in doc["metrics"]}
-    assert set(entries) == set(files) and len(entries) == 12
-    assert not set(entries) & {m["name"] for m in bench["per_layer"]}
-    layers = {m["layer"] for m in bench["per_layer"]}
-    for name, e in entries.items():
-        assert set(e) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", name)
-        assert e["layer"] in layers and e["moves"] == "summary_p95_ms"
-        m = files[name]
-        assert (m["unit"], m["layer"], m["moves"]) == (
-            e["unit"], e["layer"], e["moves"])
-        assert m["source"]["kind"] in ("trace_phase", "trace_scope")
+    out = {}
+    for e in bench["per_layer"]:
+        with open(os.path.join(BENCH, "metrics", e["name"] + ".json")) as f:
+            m = json.load(f)
+        if m["source"]["kind"] in ("trace_phase", "trace_scope",
+                                   "scope_roofline"):
+            out[e["name"]] = (e, m)
+    return out
+
+
+def test_the_split_metrics_are_whole_and_read_the_hand_built_capture():
+    both = _split_metrics()
+    assert len(both) == 13
+    files = {n: m for n, (_, m) in both.items()}
     # each partition is whole: one metric per member and the remainder
     scope = [m["source"] for m in files.values()
              if m["source"]["kind"] == "trace_scope"]
@@ -238,12 +239,57 @@ def test_the_proposed_metrics_are_whole_and_keep_to_the_contract():
     rest = [s for s in phase if s["phase"] is None]
     assert len(rest) == 1 and sorted(rest[0]["none_of"]) == sorted(
         s["phase"] for s in phase if s["phase"] is not None)
+    # a scope's roofline is over the same self time as its device time
+    roof = files["topk_roofline.steady"]["source"]
+    same = files["topk_device_ms.steady"]["source"]
+    assert [roof[k] for k in ("program", "scope", "scopes")] == [
+        same[k] for k in ("program", "scope", "scopes")]
     # and they read the hand-built capture without a line of code more
     ctx = {"capture": capture(), "slot_step_hlo": HLO}
-    got = {n: splits.read(m["source"], ctx) for n, m in files.items()}
+    got = {n: readers.read(m, ctx) for n, m in files.items()
+           if m["source"]["kind"] != "scope_roofline"}
     assert got["idle_in_harvest.steady"] == pytest.approx(9.0)
     assert got["topk_device_ms.steady"] == pytest.approx(37.5)
     assert got["lstm_cell_device_ms.steady"] == 0.0
+
+
+def _roofline_ctx(occupancy=True):
+    """The hand-built capture with what a count needs beside it: two
+    slots of four occupied, beam 2, rows of 8 + 2 floats, 5 steps a run."""
+    occ = {"count": 10, "sum": 5.0, "buckets": [1.0], "counts": [10, 0],
+           "min": 0.5, "max": 0.5}
+    return {"capture": capture(), "slot_step_hlo": HLO,
+            "family": "pointer_generator",
+            "hparams": {"beam_size": 2, "vocab_size": 8,
+                        "max_oov_buckets": 2},
+            "deployment": {"slots": 4, "chunk": 5},
+            "harness": {"mean_article_len": 10.0},
+            "registry0": {},
+            "registry1": {"serve/slot_occupancy": occ} if occupancy else {},
+            "peaks": {"flops_per_s": 1e12, "bytes_per_s": 1e6}}
+
+
+def test_scope_roofline_is_the_least_time_over_the_scopes_self_time():
+    spec = {"source": {"kind": "scope_roofline", "count": "topk_rows",
+                       "program": "^jit_step_slots(_paged)?_jit$",
+                       "scope": "topk", "scopes": SCOPES}}
+    # 2 occupied x beam 2 x 10 floats x 4 B x 5 steps = 800 B a run:
+    # 0.8 ms at 1 MB/s, over topk's 37.5 ms a run
+    assert readers.read(spec, _roofline_ctx()) == pytest.approx(
+        0.8 / 37.5 * 100.0)
+    # nothing to read is None, never 0: no occupancy in the window, no
+    # capture, a scope the program does not carry, a scope with no time
+    assert readers.read(spec, _roofline_ctx(occupancy=False)) is None
+    assert readers.read(spec, dict(_roofline_ctx(), capture=None)) is None
+    other = {"source": dict(spec["source"], scope="flash", scopes=None)}
+    assert readers.read(other, _roofline_ctx()) is None
+    empty = {"source": dict(spec["source"], scope="lstm_cell",
+                            scopes=SCOPES + ["lstm_cell"])}
+    assert readers.read(empty, _roofline_ctx()) is None
+    # a count nobody has is an error that names it, not a silent None
+    bad = {"source": dict(spec["source"], count="no_such_rows")}
+    with pytest.raises(ValueError, match="no_such_rows"):
+        readers.read(bad, _roofline_ctx())
 
 
 def test_a_further_phase_scope_or_stage_metric_is_a_file_only():

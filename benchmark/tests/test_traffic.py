@@ -44,12 +44,13 @@ def test_summary_lengths_are_coded_in_the_first_word():
 
     mix = _mix("news_open_loop")
     with open(os.path.join(BENCH, "configs", "pg_see2017.json")) as f:
-        clock = json.load(f)["init"]["summary_clock"]
-    a = traffic.make_articles(mix, 50000, 300, 2 ** 31 + 9, clock=clock)
-    b = traffic.make_articles(mix, 50000, 300, 11, clock=clock)
+        fam, clock = weights.summary_clock(json.load(f))
+    a = traffic.make_articles(mix, 50000, 300, 2 ** 31 + 9,
+                              clock=(fam, clock))
+    b = traffic.make_articles(mix, 50000, 300, 11, clock=(fam, clock))
 
     def coded(arts):
-        return sorted(int(weights.length_code(clock, int(x.ids[0])))
+        return sorted(int(fam.length_code(clock, int(x.ids[0])))
                       for x in arts)
 
     assert coded(a) == coded(b)
@@ -79,7 +80,10 @@ def test_tokenisation_equals_the_programs():
     vocab = Vocab(words=words.vocabulary())
     assert vocab.size() == V
     hps = HParams(vocab_size=V, max_enc_steps=400)
-    clock = {"codes": 65, "min_tokens": 36}
+    from harness import reference
+
+    clock = (reference.family("pointer_generator"),
+             {"codes": 65, "min_tokens": 36})
     for art in (traffic.make_articles(mix, V, 5, 3)
                 + traffic.make_articles(mix, V, 5, 4, clock=clock)):
         ex = SummaryExample.build(art.text, [], vocab, hps)

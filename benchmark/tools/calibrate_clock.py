@@ -1,7 +1,8 @@
 """Builder's tool (CPU, counts only): the summary lengths the plain
 reference's beam search gives on seed-made weights with the summary clock
-(weights.py), against the lengths the articles' first words ask for, for
-a few STOP biases, at the configuration's full widths.  From the root:
+(the family's `wire`), against the lengths the articles' first words ask
+for, for a few STOP biases, at the configuration's full widths.  From the
+root:
 
     JAX_PLATFORMS=cpu python benchmark/tools/calibrate_clock.py <config> <stop_bias> [...]
 
@@ -29,16 +30,16 @@ def main():
         cfg = json.load(f)
     with open(os.path.join(BENCH, "traffic", "news_open_loop.json")) as f:
         mix = json.load(f)
-    clock = cfg["init"]["summary_clock"]
+    fam, clock = weights.summary_clock(cfg)
     clock.update(json.loads(os.environ.get("INIT", "{}")))
-    fam = ref.family(cfg["family"])
     V = cfg["hparams"]["vocab_size"]
     for b in biases:
         cfg["init"]["stop_bias"] = b
         for seed in seeds:
             params = weights.make_params(cfg, seed)
-            arts = traffic.make_articles(mix, V, n_art, seed, clock=clock)
-            want = [int(weights.length_code(clock, int(a.ids[0])))
+            arts = traffic.make_articles(mix, V, n_art, seed,
+                                          clock=(fam, clock))
+            want = [int(fam.length_code(clock, int(a.ids[0])))
                     for a in arts]
             got = [len(ref.beam_search(fam, params, cfg["hparams"], a.ids,
                                        a.ext)[0]) for a in arts]

@@ -20,6 +20,9 @@ the benchmark.
                    where the slot step produces it (a served cell)
 --fault half_batch the reference over half of each batch in the
                    program's place (a training cell)
+--beam-look 1      for each search of a served cell's beam sample: where
+                   the served tokens and the reference's own best part,
+                   and how close the pruning was there (PERF.md section 6)
 """
 import argparse
 import copy
@@ -34,10 +37,13 @@ sys.path[:0] = [BENCH, ROOT]
 import run as bench_run  # noqa: E402
 
 
-def plant_token_swap():
+def plant_token_swap(model_family: str):
+    import importlib
+
     import jax.numpy as jnp
 
-    from textsummarization_on_flink_tpu.models import pointer_generator as m
+    m = importlib.import_module(
+        "textsummarization_on_flink_tpu.models." + model_family)
 
     real = m.beam_adapter_masked
 
@@ -53,6 +59,66 @@ def plant_token_swap():
         return init, bad_step
 
     m.beam_adapter_masked = broken
+
+
+def beam_look(cfg, seed, finished, words, sample):
+    """For each search of the beam sample: the gap, the step at which the
+    served tokens and the reference's best part (`split_at`), the step at
+    which the reference's search drops the served prefix (`left_at`) with
+    the total log probability by which the last kept candidate beat it
+    there, and `closest_call`: up to the parting, the least total log
+    probability by which the candidate that ended a pruning (the one that
+    filled the beam or the results) stood off its neighbours in the
+    ranking whose swap with it would have pruned otherwise, and its step.
+    Totals, not length-normalised: `score_gap` times the length says how
+    far the program's total may lie from the reference's."""
+    from harness import correct, weights
+    from harness import reference as ref
+
+    hp = cfg["hparams"]
+    fam = ref.family(cfg["family"])
+    params = weights.make_params(cfg, seed)
+    picked = correct.pick_sample(finished, int(sample["score"]),
+                                 seed)[:int(sample["beam"])]
+    served = [correct.served_tokens(words, a, r, hp) for a, r in picked]
+    r_avg = ref.score_tokens(fam, params, hp, [(a.ids, a.ext)
+                                               for a, _ in picked],
+                             [t for t, _ in served])
+    looks = []
+    for (a, _), (toks, n), total in zip(picked, served, r_avg):
+        seen = []
+        best, best_avg = ref.beam_search(
+            fam, params, hp, a.ids, a.ext,
+            on_step=lambda t, ranked, kept, results: seen.append(
+                (t, list(ranked), [h[0] for h in kept + results])))
+        split = next((i for i, (x, y) in enumerate(zip(toks, best))
+                      if x != y), None)
+        if split is None and len(toks) != len(best):
+            split = min(len(toks), len(best))
+        look = {"gap": float(best_avg - total / n), "served_len": len(toks),
+                "best_len": len(best), "split_at": split, "left_at": None,
+                "closest_call": None}
+        for t, ranked, kept in seen:
+            cut = max(i for i, h in enumerate(ranked) if h[0] in kept)
+            mine = [h for h in ranked if h[0] == toks[:t + 1]]
+            if look["left_at"] is None and toks[:t + 1] not in kept \
+                    and t < len(toks):
+                look["left_at"] = t
+                look["kept_over_served"] = (
+                    float(ranked[cut][1] - mine[0][1]) if mine else None)
+            if split is not None and t > split:
+                continue
+            stop = [h[0][-1] == ref.STOP_ID for h in ranked]
+            calls = []
+            if cut + 1 < len(ranked):
+                calls.append(ranked[cut][1] - ranked[cut + 1][1])
+            if cut > 0 and stop[cut - 1] != stop[cut]:
+                calls.append(ranked[cut - 1][1] - ranked[cut][1])
+            if calls and (look["closest_call"] is None
+                          or min(calls) < look["closest_call"][0]):
+                look["closest_call"] = (float(min(calls)), t)
+        looks.append(look)
+    return looks
 
 
 def half_p50(run, traffic):
@@ -79,6 +145,7 @@ def main():
     ap.add_argument("--fault", default="")
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--rehearse", type=int, default=0)
+    ap.add_argument("--beam-look", type=int, default=0)
     ap.add_argument("--pair", type=int, default=0,
                     help="1: seed i goes with rate i, not with every rate")
     args = ap.parse_args()
@@ -86,16 +153,16 @@ def main():
     bench, cell, cfg, mix, cell_file = bench_run.load_cell(args.workload)
     if args.rehearse:
         bench_run.apply_rehearsal(cfg, mix, cell_file)
-    bench_run.prepare_process(args.rehearse)
-    from harness import correct, traffic
+    bench_run.prepare_process(args.rehearse, args.trace)
+    from harness import correct, traffic, weights
 
     if args.program_dtype:
         cfg["hparams"]["compute_dtype"] = args.program_dtype
     if args.fault == "token_swap":
-        plant_token_swap()
+        plant_token_swap(cfg["hparams"]["model_family"])
     rates = [float(r) for r in args.rates.split(",") if r] or [None]
     seeds = [int(s) for s in args.seeds.split(",")]
-    clock = cfg["init"].get("summary_clock")
+    fam, clock = weights.summary_clock(cfg)
     for i, seed in enumerate(seeds):
         for rate in ([rates[i % len(rates)]] if args.pair else rates):
             m = copy.deepcopy(mix)
@@ -124,9 +191,7 @@ def main():
                     "p75": lens[3 * len(lens) // 4], "max": lens[-1]} \
                     if lens else None
                 if clock:
-                    from harness import weights
-
-                    off = [len(r.decoded_words) + 1 - int(weights.length_code(
+                    off = [len(r.decoded_words) + 1 - int(fam.length_code(
                         clock, int(a.ids[0]))) for a, r in run.finished
                         if len(r.decoded_words) < cfg["hparams"][
                             "max_dec_steps"]]
@@ -134,6 +199,10 @@ def main():
                         rec["served_minus_coded_tokens"] = {
                             "mean": sum(off) / len(off), "min": min(off),
                             "max": max(off)}
+            if args.beam_look:
+                rec["beam_look"] = beam_look(
+                    cfg, seed, run.finished, out["words"],
+                    cell_file["check"]["sample"])
             extra = []
             if args.control:
                 extra.append(("control_bf16_reference", True))

@@ -1,31 +1,24 @@
 """Builder's tool (chip): ONE traced run of a served cell through the
-harness's own `run_cell`, with the twelve per-layer metrics of
-benchmark/proposed/trace_splits.json read beside the accepted ones
-(harness/splits.py: idle time by host phase, the slot chunk's device time
-by named scope) and the three sums that say the splits close.  They are
-not in BENCHMARK.json because the harness cannot reach a new reader kind
-without an edit to files this PR may not touch (the file says which); until a
-benchmark PR makes those edits this tool is how the splits are read.
+harness's own `run_cell`, with the sums that say the per-layer splits
+close (idle by host phase against the device's idle share, the slot
+chunk's device time by named scope against the chunk), the stage
+histogram against the latency histogram, the capture's heaviest
+instructions and the programs' device time.  Everything is read from what
+`run_cell` hands back (its result line and the readers' context).
 
     python benchmark/tools/splits.py --workload pg_serve_steady --seed 0 \
         --seconds 45 [--out chiprun_out/splits.json] [--dump DIR] \
         [--trace 1] [--rehearse 0]
 
-Prints the run's result line with the further metrics in it and a
-"splits" object: the sums, the stage histogram against the latency
-histogram, the capture's size, the programs' device time.  `--dump DIR`
+Prints the run's result line with a "splits" object in it.  `--dump DIR`
 also writes the capture's host phases and program runs (`capture.json`:
 small, for reading a tick by hand) and the compiled slot step's text
-(`slot_step.hlo.txt`).  Never a measurement of the benchmark:
-metadata is made part of the compile cache's key (so that the compiled
-slot step's text carries THIS build's op_names), which makes set-up cold
-once, and two of the harness's names are wrapped to keep what `run_cell`
-throws away — the capture and the server.  `--trace 0` leaves the capture
-out (the trace metrics with it) and reads the stage clock of an
-undisturbed run: stopping a capture holds the server up for seconds, and
-a traced run's readers wait longer for it.  `--rehearse 1` walks the same
-path at tiny shapes on the CPU (no capture there: the trace metrics stay
-out, the stage sums and the scope map are still read).
+(`slot_step.hlo.txt`).  Never a measurement of the benchmark.  `--trace
+0` leaves the capture out (the trace metrics with it) and reads the stage
+clock of an undisturbed run: stopping a capture holds the server up for
+seconds, and a traced run's readers wait longer for it.  `--rehearse 1`
+walks the same path at tiny shapes on the CPU (no capture there: the
+trace metrics stay out, the stage sums are still read).
 """
 import argparse
 import json
@@ -36,60 +29,39 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [BENCH, ROOT]
 
-# before jax is imported: see harness/splits.py on stale op_names
-os.environ.setdefault("JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "1")
-
 import run as bench_run  # noqa: E402
 
 STAGES = ("queue", "prefill", "slot_wait", "resident", "harvest")
 
 
-def keep_capture_and_server(kept):
-    from harness import splits
-    from harness import trace as trace_lib
-    from textsummarization_on_flink_tpu.serve import server as server_mod
-
-    real_load = trace_lib.load
-
-    def load_and_keep(log_dir):
-        planes = real_load(log_dir)
-        kept["capture"] = {"planes": planes,
-                           "threads": splits.host_threads(log_dir)}
-        kept["xplane_bytes"] = os.path.getsize(
-            trace_lib.newest_xplane(log_dir))
-        return planes
-
-    trace_lib.load = load_and_keep
-
-    class KeptServer(server_mod.ServingServer):
-        def __init__(self, *args, **kw):
-            super().__init__(*args, **kw)
-            kept["server"] = self
-
-    server_mod.ServingServer = KeptServer
-
-
-def stage_sums(reg):
+def stage_sums(snapshot):
     """Whole-run figures (warm-up and drain included: the same requests
-    on both sides) of the stage histogram and of the latency histogram:
-    the sums that must agree, and mean / p50 / p95 of each."""
-    h = reg.get("serve/request_stage_seconds")
-    e2e = reg.get("serve/e2e_latency_seconds")
-    if h is None or e2e is None:
+    on both sides) of the stage histogram and of the latency histogram,
+    from the registry as the run left it: the sums that must agree, and
+    mean / p50 / p95 of each."""
+    from harness import readers
+
+    def hist(name, **labels):
+        return snapshot.get(readers.series_key({"name": name,
+                                                "labels": labels}))
+
+    by = {s: hist("serve/request_stage_seconds", stage=s) for s in STAGES}
+    e2e = hist("serve/e2e_latency_seconds")
+    if e2e is None or not all(by.values()):
         return None
 
     def stats(x):
-        return {"mean_s": x.sum / max(1, x.count),
-                "p50_s": x.percentile(50), "p95_s": x.percentile(95)}
+        return {"mean_s": x["sum"] / max(1, x["count"]),
+                "p50_s": readers.hist_percentile(x, 50),
+                "p95_s": readers.hist_percentile(x, 95)}
 
-    by = {s: h.labels(stage=s) for s in STAGES}
-    chunks = reg.get("serve/request_resident_chunks")
-    return {"stages_s": {s: x.sum for s, x in by.items()},
-            "stages_sum_s": sum(x.sum for x in by.values()),
-            "e2e_sum_s": e2e.sum, "requests": e2e.count,
+    chunks = hist("serve/request_resident_chunks")
+    return {"stages_s": {s: x["sum"] for s, x in by.items()},
+            "stages_sum_s": sum(x["sum"] for x in by.values()),
+            "e2e_sum_s": e2e["sum"], "requests": e2e["count"],
             "stats": dict({s: stats(x) for s, x in by.items()},
                           e2e=stats(e2e)),
-            "resident_chunks_mean": chunks.sum / max(1, chunks.count)
+            "resident_chunks_mean": chunks["sum"] / max(1, chunks["count"])
             if chunks is not None else None}
 
 
@@ -101,9 +73,11 @@ def top_instructions(capture, paths, program, n=16):
     from harness import splits
 
     dev = splits._first_device((capture or {}).get("planes", {}))
-    if dev is None:
+    if dev is None or not program:
         return []
     calls, ops = splits.program_ops(dev, program)
+    if not calls:
+        return []
     total = {}
     for name, _, ns in ops:
         key = splits.instruction(name)
@@ -126,64 +100,49 @@ def main() -> int:
     bench, cell, cfg, mix, cell_file = bench_run.load_cell(args.workload)
     if args.rehearse:
         bench_run.apply_rehearsal(cfg, mix, cell_file)
-    bench_run.prepare_process(args.rehearse)
+    bench_run.prepare_process(args.rehearse, args.trace)
     from harness import splits
 
-    kept = {}
-    keep_capture_and_server(kept)
     out = bench_run.run_cell(bench, cell, cfg, mix, cell_file, args.seed,
                              args.seconds, trace=args.trace,
                              rehearse=args.rehearse)
-    line = out["line"]
-    line.setdefault("metrics", {})
-    server = kept.get("server")
-    compiled = getattr(server, "compiled_slot_step", None)
-    hlo = compiled().as_text() if compiled else None
+    line, ctx = out["line"], out["ctx"]
+    capture, hlo = ctx.get("capture"), ctx.get("slot_step_hlo")
     paths = splits.scope_map(hlo or "")
-    ctx = {"capture": kept.get("capture"), "slot_step_hlo": hlo,
-           "_scope_map": paths}
-    with open(os.path.join(BENCH, "proposed", "trace_splits.json"),
-              encoding="utf-8") as f:
-        proposed = json.load(f)
-    cells = {e["name"]: e["workloads"] for e in proposed["per_layer"]}
-    got = {}
-    for m in proposed["metrics"]:
-        if args.workload not in cells[m["name"]]:
-            continue
-        value = splits.read(m["source"], ctx)
-        if value is not None:
-            got[m["name"]] = value
-            line["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
-
-    def total(kind):
-        vals = [got[m["name"]] for m in proposed["metrics"]
-                if m["source"]["kind"] == kind and m["name"] in got]
-        return sum(vals) if vals else None
+    kinds, program = {}, ""
+    for m in bench_run.metrics_of(bench, args.workload, "per_layer"):
+        src = bench_run._load("metrics", m["name"] + ".json")["source"]
+        kinds[m["name"]] = src["kind"]
+        if src["kind"] == "trace_scope":
+            program = src["program"]
 
     def metric(name):
-        return line["metrics"].get(name, {}).get("value")
+        return line.get("metrics", {}).get(name, {}).get("value")
+
+    def total(kind):
+        vals = [metric(n) for n, k in kinds.items()
+                if k == kind and metric(n) is not None]
+        return sum(vals) if vals else None
 
     line["splits"] = {
         "idle_sum_pct": total("trace_phase"),
         "device_idle_pct": metric("device_idle.steady"),
         "scope_sum_ms": total("trace_scope"),
         "slot_chunk_device_ms": metric("slot_chunk_device_ms.steady"),
-        "stage_sums": stage_sums(server.registry) if server else None,
-        "xplane_bytes": kept.get("xplane_bytes"),
+        "stage_sums": stage_sums(ctx["registry1"]),
         "phase_events": len(splits.dispatch_thread(
-            (kept.get("capture") or {}).get("threads", []))),
+            (capture or {}).get("threads", []))),
         "scoped_instructions": len(paths),
-        "top_instructions": top_instructions(
-            kept.get("capture"), paths, "^jit_step_slots(_paged)?_jit$"),
+        "top_instructions": top_instructions(capture, paths, program),
         "programs": out["programs"],
         "e2e": out["e2e"],
     }
-    if args.dump and kept.get("capture"):
+    if args.dump and capture:
         os.makedirs(args.dump, exist_ok=True)
-        dev = splits._first_device(kept["capture"]["planes"]) or {}
+        dev = splits._first_device(capture["planes"]) or {}
         with open(os.path.join(args.dump, "capture.json"), "w",
                   encoding="utf-8") as f:
-            json.dump({"threads": kept["capture"]["threads"],
+            json.dump({"threads": capture["threads"],
                        "modules": dev.get("XLA Modules", [])}, f)
         with open(os.path.join(args.dump, "slot_step.hlo.txt"), "w",
                   encoding="utf-8") as f:
