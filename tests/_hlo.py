@@ -5,13 +5,43 @@ import re
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
 
 
+def _instructions(text: str) -> list:
+    return [m.groups() for m in map(_INSTR.match, text.splitlines()) if m]
+
+
+def _dims(shape: str) -> list:
+    """Every dimension printed in a shape (a tuple's parts together)."""
+    return [int(d) for part in re.findall(r"\[([\d,]*)\]", shape)
+            for d in part.split(",") if d]
+
+
+def wide_dimensions(text: str, width: int) -> list:
+    """The instructions of ``compiled.as_text()`` whose result has a
+    dimension of exactly ``width``: with the extended vocabulary's
+    width, whatever still builds or passes on the pointer mixture's
+    row."""
+    return [f"{name} = {shape} {opcode}(" for name, shape, opcode, _
+            in _instructions(text) if width in _dims(shape)]
+
+
+def wide_scatters(text: str, at_least: int) -> list:
+    """The scatters whose result (the operand's shape) has a dimension
+    of ``at_least`` or more: a scatter into a vocabulary-wide row.  (The
+    beam step's own writes, one token a step into [.., max_dec_steps +
+    1] histories and pool pages, are scatters too and stay.)"""
+    return [f"{name} = {shape} scatter(" for name, shape, opcode, _
+            in _instructions(text)
+            if opcode == "scatter" and max(_dims(shape), default=0)
+            >= at_least]
+
+
 def wide_row_orderings(text: str, width: int) -> list:
     """The instructions of ``compiled.as_text()`` that sort, or take a
     top-k of, an operand with a dimension of ``width``.  An operand is
     printed by name alone, so its shape is looked up where it is
     defined; a sort's or a TopK custom call's result is checked too."""
     wide = re.compile(rf"\[(?:\d+,)*{width}(?:,\d+)*\]")
-    instrs = [m.groups() for m in map(_INSTR.match, text.splitlines()) if m]
+    instrs = _instructions(text)
     shape_of = {name: shape for name, shape, _, _ in instrs}
     bad = []
     for name, shape, opcode, rest in instrs:

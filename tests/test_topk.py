@@ -1,8 +1,10 @@
 """ops.topk.top_k against jax.lax.top_k: values bit-equal, ids equal —
 the contract the beam step's selection stands on (ISSUE 26) — and that
-the selection ENGAGES where the benchmark's cell runs it: the slot step
-lowered at pg_see2017's vocabulary holds no sort and no top-k over a
-50 128-wide operand.
+the selection and the candidate ranking (ISSUE 31; its contract:
+tests/test_mixture_topk.py) ENGAGE where the benchmark's cell runs
+them: the slot step lowered at pg_see2017's vocabulary holds no sort
+and no top-k over a vocabulary-wide operand, no scatter into one, and
+nothing 50 128 wide at all — the extended row is never built.
 """
 
 import jax
@@ -11,7 +13,7 @@ import numpy as np
 import pytest
 
 import __graft_entry__ as ge
-from _hlo import wide_row_orderings
+from _hlo import wide_dimensions, wide_row_orderings, wide_scatters
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -135,6 +137,32 @@ def test_the_detector_sees_a_stock_top_k():
     assert not wide_row_orderings(ours.as_text(), WIDE)
 
 
+def _builds_no_extended_row(text: str, hps: HParams) -> None:
+    """The slot step ranks candidates (ops/topk.mixture_top_k): the
+    vocabulary's own rows are 50 000 wide, none is sorted, none is
+    scattered into, and no instruction has the extended width."""
+    V = hps.vocab_size
+    assert wide_dimensions(text, V)  # the step does hold the vocabulary
+    assert not wide_row_orderings(text, V)
+    assert not wide_row_orderings(text, V + hps.max_oov_buckets)
+    assert not wide_scatters(text, V)
+    assert not wide_dimensions(text, V + hps.max_oov_buckets)
+
+
+def test_the_detectors_see_the_extended_row():
+    """The row as the parent's step built it: two slots of four rows."""
+    hps = PG_WIDE
+    dense = jax.jit(jax.vmap(lambda vd, attn, p, ids: topk.top_k(
+        topk.extended_mixture(vd, attn, p, ids, WIDE), 8))).lower(
+            jnp.zeros((2, 4, hps.vocab_size)), jnp.zeros((2, 4, 12)),
+            jnp.zeros((2, 4)), jnp.zeros((2, 12), jnp.int32)).compile()
+    text = dense.as_text()
+    assert wide_dimensions(text, WIDE)
+    assert wide_scatters(text, hps.vocab_size)
+    with pytest.raises(AssertionError):
+        _builds_no_extended_row(text, hps)
+
+
 def test_paged_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
     """The engine's own executable (SlotDecodeEngine.compiled_step(),
     behind ServingServer.compiled_slot_step()): two slots over the
@@ -160,8 +188,7 @@ def test_paged_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
         server.submit("the cat sat .", uuid="a").result(timeout=600)
         assert server._cont.engine.paged
         text = server.compiled_slot_step().as_text()
-    assert str(WIDE) in text  # the step does hold the wide row
-    assert not wide_row_orderings(text, WIDE)
+    _builds_no_extended_row(text, hps)
 
 
 def test_transformer_slot_step_at_the_cells_vocabulary_orders_no_wide_row():
@@ -175,5 +202,4 @@ def test_transformer_slot_step_at_the_cells_vocabulary_orders_no_wide_row():
     table = np.full((B, 3), pages, np.int32)
     text = beam_search.step_slots_paged_jit.lower(
         params, hps, paged, np.ones(B, bool), table, 2).compile().as_text()
-    assert str(WIDE) in text
-    assert not wide_row_orderings(text, WIDE)
+    _builds_no_extended_row(text, hps)

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import __graft_entry__ as ge
-from _hlo import wide_row_orderings
+from _hlo import wide_dimensions, wide_row_orderings, wide_scatters
 from textsummarization_on_flink_tpu.config import HParams, resolve_enc_block
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -167,15 +167,21 @@ def test_prefill_compiles_for_v5e(bucket, one_chip):
 
 
 def _orders_no_vocabulary_row(compiled, hps):
-    """The beam step keeps 2 x beam of the extended vocabulary by
-    selection (ops/topk.py): XLA:TPU turns a `vmap`ped ``lax.top_k``
-    into a full sort of every [.., 50 128] row, 86% of the slot step's
-    device time before ISSUE 26 — in the chip's own compiler's output,
-    no sort and no top-k may have an operand that wide."""
+    """The beam step keeps 2 x beam of the pointer mixture by selection
+    over the vocabulary's row and the article's own ids (ops/topk.py):
+    XLA:TPU turns a `vmap`ped ``lax.top_k`` into a full sort of every
+    [.., 50 128] row, 86% of the slot step's device time before ISSUE
+    26, and expands the mixture's scatter-add over that row into half
+    of what was left (ISSUE 31) — in the chip's own compiler's output
+    no sort and no top-k may have an operand as wide as the vocabulary,
+    no scatter a result that wide, and nothing the extended width."""
     text = compiled.as_text()
-    width = hps.vocab_size + hps.max_oov_buckets
-    assert str(width) in text
+    V, width = hps.vocab_size, hps.vocab_size + hps.max_oov_buckets
+    assert wide_dimensions(text, V)  # the step does hold the vocabulary
+    assert not wide_row_orderings(text, V)
     assert not wide_row_orderings(text, width)
+    assert not wide_scatters(text, V)
+    assert not wide_dimensions(text, width)
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])

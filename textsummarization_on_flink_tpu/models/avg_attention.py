@@ -66,7 +66,6 @@ from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import pointer_generator as pg
 from textsummarization_on_flink_tpu import models as models_lib
 from textsummarization_on_flink_tpu.models import transformer as tf
-from textsummarization_on_flink_tpu.ops import topk as topk_ops
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -406,13 +405,13 @@ def decode_onestep(params: Params, hps: HParams,
                    enc_one: TransformerEncView, enc_mask: Array,
                    ext_ids: Array, t: Array, latest: Array,
                    aan_sum: Array, nb=None) -> Tuple[Array, Array, Array,
-                                                     Array, Array]:
+                                                     Array, Array, Array]:
     """One AAN decode step for K hypotheses: O(1) in history — the only
     carried decode state is the [K, L, H] running sum (f32), updated by
     one add; no cache gather, no attention over past positions.
 
-    Returns (final_dist [K, V_ext], attn_dist [K, T_enc], p_gen [K],
-    h [K, H_d], new_sum [K, L, H_d]).
+    Returns (topk_probs [K, 2*beam], topk_ids [K, 2*beam], attn_dist
+    [K, T_enc], p_gen [K], h [K, H_d], new_sum [K, L, H_d]).
     """
     dhps = _decoder_hps(hps)
     y = _embed_dec_draft(params, hps, latest, t)  # [K, H_d]
@@ -437,11 +436,10 @@ def decode_onestep(params: Params, hps: HParams,
         y = y + cross_out
         y = y + tf._ffn_block(layer["ffn"], tf._ln(layer["ln2"], y))
         cross_ctx = cross_out
-    final_dist, p_gen, h = tf.decode_output_tail(params, dhps, y,
-                                                 cross_ctx, attn_dist,
-                                                 ext_ids)
+    topk_probs, topk_ids, p_gen, h = tf.decode_output_tail(
+        params, dhps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size)
     new_sum = jnp.stack(new_sums, axis=1)  # [K, L, H_d]
-    return final_dist, attn_dist, p_gen, h, new_sum
+    return topk_probs, topk_ids, attn_dist, p_gen, h, new_sum
 
 
 def beam_adapter(hps: HParams):
@@ -461,12 +459,9 @@ def beam_adapter(hps: HParams):
     def step(params: Params, enc_one: TransformerEncView, enc_mask: Array,
              ext_ids: Array, t: Array, latest: Array, state,
              nb=None) -> BeamStepOut:
-        final_dist, attn_dist, p_gen, _, new_sum = decode_onestep(
+        topk_probs, topk_ids, attn_dist, p_gen, _, new_sum = decode_onestep(
             params, hps, enc_one, enc_mask, ext_ids, t, latest,
             state["aan_sum"], nb=nb)
-        with jax.named_scope("topk"):
-            topk_probs, topk_ids = topk_ops.top_k(final_dist,
-                                                  2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,

@@ -326,36 +326,40 @@ def run_encoder(params: Params, hps: HParams, arrays: Dict[str, Array],
 def final_distribution(hps: HParams, vocab_dist: Array, attn_dist: Array,
                        p_gen: Array, enc_batch_extend_vocab: Array) -> Array:
     """Extended-vocab mixture distribution [B, V + max_oov_buckets]
-    (model.py:146-183), with the static OOV budget replacing the dynamic
-    max_art_oovs.  Used at decode time only."""
-    B, V = vocab_dist.shape
-    ext_V = V + hps.max_oov_buckets
-    weighted_vocab = p_gen[:, None] * vocab_dist
-    weighted_attn = (1.0 - p_gen)[:, None] * attn_dist  # [B, T_enc]
-    base = jnp.zeros((B, ext_V), vocab_dist.dtype)
-    base = base.at[:, :V].set(weighted_vocab)
-    b_idx = jnp.arange(B)[:, None].repeat(attn_dist.shape[1], axis=1)
-    return base.at[b_idx, enc_batch_extend_vocab].add(weighted_attn)
+    (model.py:146-183; the static OOV budget replaces max_art_oovs): what
+    a decode step ranks — ``step_top_k`` builds it only for a short row."""
+    return topk_ops.extended_mixture(
+        vocab_dist, attn_dist, p_gen, enc_batch_extend_vocab,
+        vocab_dist.shape[-1] + hps.max_oov_buckets)
+
+
+def step_top_k(hps: HParams, vocab_scores: Array, attn_dist: Array,
+               p_gen: Array, ext_ids: Array, k: int) -> Tuple[Array, Array]:
+    """Every family's decode step ends here: the k best (probabilities,
+    extended ids) of each row, ``top_k(final_distribution(hps, softmax(
+    vocab_scores), ...), k)``.  ext_ids: [B, T_enc], or [T_enc] shared."""
+    if not hps.pointer_gen:
+        vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
+        with jax.named_scope("topk"):
+            return topk_ops.top_k(vocab_dist, k)
+    return topk_ops.mixture_top_k(vocab_scores, attn_dist, p_gen, ext_ids, k,
+                                  vocab_scores.shape[-1] + hps.max_oov_buckets)
 
 
 @jax.named_scope("vocab_dist")
-def _vocab_dist(params: Params, hps: HParams, cell_out: Array,
-                context: Array, new_state: Tuple[Array, Array], x: Array,
-                attn_dist: Array, ext_ids: Array) -> Tuple[Array, Array]:
+def _vocab_scores(params: Params, hps: HParams, cell_out: Array,
+                  context: Array, new_state: Tuple[Array, Array], x: Array,
+                  ) -> Tuple[Array, Array]:
     """The decode step's output head, shared by decode_onestep and
-    decode_onestep_shared: p_gen, output projection, softmax and the
-    pointer mixture.  ext_ids: [B, T_enc].  Returns (final_dist, p_gen)."""
+    decode_onestep_shared: p_gen and the output projection.  Returns
+    (vocab_scores, p_gen); ``step_top_k`` normalises and ranks."""
     dp = params["decoder"]
     p_gen = jax.nn.sigmoid(
         _linear(dp["pgen_linear"], context, new_state[0], new_state[1], x))[:, 0]
     output = _linear(dp["output_linear"], cell_out, context)
     vocab_scores = _proj(hps, output, params["output_projection"]["w"]) + \
         params["output_projection"]["v"]
-    vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
-    if not hps.pointer_gen:
-        return vocab_dist, p_gen
-    return final_distribution(hps, vocab_dist, attn_dist, p_gen,
-                              ext_ids), p_gen
+    return vocab_scores, p_gen
 
 
 def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
@@ -394,11 +398,11 @@ def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
         context, attn_dist, _ = attn_ops.attend(
             dp["attention"], enc.enc_states, enc.enc_features,
             enc_padding_mask, new_state, cov if use_cov else None, use_cov)
-    final_dist, p_gen = _vocab_dist(params, hps, cell_out, context, new_state,
-                                    x, attn_dist, enc_batch_extend_vocab)
+    vocab_scores, p_gen = _vocab_scores(params, hps, cell_out, context,
+                                        new_state, x)
     k = 2 * hps.beam_size  # model.py:284 (batch_size==beam_size there)
-    with jax.named_scope("topk"):
-        topk_probs, topk_ids = topk_ops.top_k(final_dist, k)
+    topk_probs, topk_ids = step_top_k(hps, vocab_scores, attn_dist, p_gen,
+                                      enc_batch_extend_vocab, k)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,
@@ -443,14 +447,10 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
             dp["attention"], enc_one.enc_states, enc_one.enc_features,
             enc_mask, new_state, cov if use_cov else None, use_cov,
             nb=nb, block=block)
-    # the mixture scatter is genuinely per-hypothesis; the broadcast
-    # ext ids are an int32 index operand, not a streamed tensor
-    K = latest_tokens.shape[0]
-    final_dist, p_gen = _vocab_dist(
-        params, hps, cell_out, context, new_state, x, attn_dist,
-        jnp.broadcast_to(ext_ids[None], (K,) + ext_ids.shape))
-    with jax.named_scope("topk"):
-        topk_probs, topk_ids = topk_ops.top_k(final_dist, 2 * hps.beam_size)
+    vocab_scores, p_gen = _vocab_scores(params, hps, cell_out, context,
+                                        new_state, x)
+    topk_probs, topk_ids = step_top_k(hps, vocab_scores, attn_dist, p_gen,
+                                      ext_ids, 2 * hps.beam_size)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,

@@ -55,7 +55,6 @@ from textsummarization_on_flink_tpu import models as models_lib
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import pointer_generator as pg
 from textsummarization_on_flink_tpu.ops import losses as loss_ops
-from textsummarization_on_flink_tpu.ops import topk as topk_ops
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -587,27 +586,23 @@ def cross_attend_layer(hps: HParams, layer: Dict[str, Any], y: Array,
 @jax.named_scope("vocab_dist")
 def decode_output_tail(params: Params, hps: HParams, y: Array,
                        cross_ctx: Array, attn_dist: Array, ext_ids: Array,
-                       ) -> Tuple[Array, Array, Array]:
+                       k: int) -> Tuple[Array, Array, Array, Array]:
     """Decoder output head shared by every transformer-shaped decode
     path (beam adapter step, ``spec_verify``, the AAN step): final LN,
     vocab projection via ``vocab_scores_of`` (tied, or the narrow
-    draft's factored head), p_gen, pointer mixture.  Returns
-    (final_dist [R, V_ext], p_gen [R], h [R, H_dec] f32)."""
+    draft's factored head), p_gen, and the k best of the pointer
+    mixture (``pg.step_top_k``; its selection over the vocabulary
+    carries the ``topk`` scope).  Returns (topk_probs [R, k], topk_ids
+    [R, k], p_gen [R], h [R, H_dec] f32)."""
     h = _ln(params["decoder"]["ln_out"], y).astype(jnp.float32)
     vocab_scores = vocab_scores_of(params, hps, h)
-    vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
     p_gen = jax.nn.sigmoid(
         jnp.concatenate([h, cross_ctx.astype(jnp.float32)], axis=-1)
         @ params["pgen_linear"]["kernel"]
         + params["pgen_linear"]["bias"])[:, 0]
-    if hps.pointer_gen:
-        R = y.shape[0]
-        ext_r = jnp.broadcast_to(ext_ids[None], (R,) + ext_ids.shape)
-        final_dist = pg.final_distribution(hps, vocab_dist, attn_dist,
-                                           p_gen, ext_r)
-    else:
-        final_dist = vocab_dist
-    return final_dist, p_gen, h
+    topk_probs, topk_ids = pg.step_top_k(hps, vocab_scores, attn_dist, p_gen,
+                                         ext_ids, k)
+    return topk_probs, topk_ids, p_gen, h
 
 
 def beam_adapter(hps: HParams):
@@ -674,12 +669,8 @@ def beam_adapter(hps: HParams):
             y = y + cross_out
             y = y + _ffn_block(layer["ffn"], _ln(layer["ln2"], y))
             cross_ctx = cross_out
-        final_dist, p_gen, _ = decode_output_tail(params, hps, y,
-                                                  cross_ctx, attn_dist,
-                                                  ext_ids)
-        with jax.named_scope("topk"):
-            topk_probs, topk_ids = topk_ops.top_k(final_dist,
-                                                  2 * hps.beam_size)
+        topk_probs, topk_ids, p_gen, _ = decode_output_tail(
+            params, hps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,
@@ -786,8 +777,7 @@ def spec_verify(params: Params, hps: HParams, enc_one: TransformerEncView,
         y = y + cross_out
         y = y + _ffn_block(layer["ffn"], _ln(layer["ln2"], y))
         cross_ctx = cross_out
-    final_dist, p_gen, _ = decode_output_tail(params, hps, y, cross_ctx,
-                                              attn_dist, ext_ids)
-    topk_probs, topk_ids = topk_ops.top_k(final_dist, 2)
+    topk_probs, topk_ids, p_gen, _ = decode_output_tail(
+        params, hps, y, cross_ctx, attn_dist, ext_ids, 2)
     return (topk_ids, jnp.log(topk_probs + 1e-10), attn_dist, p_gen,
             {"cache_k": cache_k, "cache_v": cache_v})
