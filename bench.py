@@ -1708,13 +1708,10 @@ def bench_serve() -> None:
         slots_n = resolve_serve_slots(hps)
         rb = decode_resident_bytes(hps.replace(batch_size=slots_n),
                                    pages=arena_pages or None)
-        if arena_pages:
-            resident_mean = int(
-                rb["paged_fixed_bytes_per_slot"]
-                + arena_fill_mean * arena_pages * rb["page_bytes"]
-                / slots_n)
-        else:
-            resident_mean = int(rb["dense_bytes_per_slot"])
+        resident_mean = int(
+            rb["fixed_bytes_per_slot"]
+            + arena_fill_mean * rb["arena_pages"] * rb["page_bytes"]
+            / slots_n)
 
         # per-uuid first-occurrence timestamps of each lifecycle stage
         per_req: dict = {}

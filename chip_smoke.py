@@ -480,6 +480,9 @@ def _serve_engine(name: str, ehps, vocab, rows, buckets, meter):
     server = ServingServer(ehps, vocab, decoder=decoder, engine=engine)
     prof = profile_lib.profiler_for(obs.registry_for(ehps))
     ledger0 = prof.warm_set_size()
+    blocked = obs.registry_for(ehps).counter(
+        "serve/arena_alloc_failures_total")
+    blocked0 = blocked.value
     sink = CollectionSink()
     futures = {}
     errors = []
@@ -530,12 +533,13 @@ def _serve_engine(name: str, ehps, vocab, rows, buckets, meter):
     info = {"requests": len(results), "warm_set": ledger1 - ledger0,
             "compiles_after_warmup": ledger_after,
             "xla_compiles_after_warmup": xla_after}
-    if engine is not None and engine.paged:
+    if engine is not None:
         arena = engine.arena_stats()
         check(arena["free"] == arena["capacity"] and arena["in_use"] == 0,
               f"{name}: arena pages not all free at the end: {arena}")
         info["arena_pages"] = arena["capacity"]
         info["arena_pages_free_at_end"] = arena["free"]
+        info["arena_alloc_failures"] = int(blocked.value - blocked0)
     hyps = {u: (r.decoded_words, r.avg_log_prob)
             for u, r in results.items()}
     check(all(len(w) >= 1 for w, _ in hyps.values()),
@@ -545,19 +549,19 @@ def _serve_engine(name: str, ehps, vocab, rows, buckets, meter):
 
 def phase_serve(hps, vocab, meter) -> dict:
     """`ServingServer` over the train phase's checkpoint: micro-batch,
-    continuous dense, continuous over the paged arena — the three must
-    return the same tokens row for row."""
+    continuous with no arena option (every slot at full length),
+    continuous over an arena of two full-length articles (admissions
+    wait for pages and pages are recycled) — the three must return the
+    same tokens row for row, and both arenas must drain."""
     from textsummarization_on_flink_tpu.config import (
         parse_bucket_spec,
         resolve_enc_block,
-        resolve_serve_slots,
     )
 
     shps = hps.replace(mode="decode", serve_max_queue=4 * SERVE_REQUESTS)
     buckets = parse_bucket_spec(shps.serve_buckets, shps.max_enc_steps)
     rows = _serve_rows(shps, buckets, np.random.RandomState(SEED + 1))
-    pages = resolve_serve_slots(shps) * -(
-        -shps.max_enc_steps // resolve_enc_block(shps))
+    pages = 2 * -(-shps.max_enc_steps // resolve_enc_block(shps))
     engines = (
         ("microbatch", shps.replace(serve_mode="microbatch")),
         ("continuous", shps.replace(serve_mode="continuous")),
@@ -573,6 +577,12 @@ def phase_serve(hps, vocab, meter) -> dict:
         name: compare_hyps("microbatch", hyps["microbatch"], name,
                            hyps[name])
         for name in ("continuous", "continuous_paged")}
+    arenas = compare_hyps("continuous", hyps["continuous"],
+                          "continuous_paged", hyps["continuous_paged"])
+    check(arenas["token_equal"] == arenas["rows"],
+          f"the full and the tight arena decoded different tokens: "
+          f"{json.dumps(arenas['near_ties'])}")
+    checked["arena_equality"] = arenas
     return checked
 
 

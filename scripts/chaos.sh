@@ -154,6 +154,7 @@ from textsummarization_on_flink_tpu.data.vocab import Vocab
 from textsummarization_on_flink_tpu.decode.decoder import DecodedResult
 from textsummarization_on_flink_tpu.obs import Registry
 from textsummarization_on_flink_tpu.resilience import faultinject
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu.serve.fleet import FleetRouter
 from textsummarization_on_flink_tpu.serve.server import ServingServer
 
@@ -161,7 +162,7 @@ class NullDecoder:
     def maybe_reload_checkpoint(self, last):
         return last
 
-class SimEngine:
+class SimEngine(NoArena):
     """3-chunk-per-request slot engine (jax-free): enough residency for
     the injected kill to land mid-decode."""
     def __init__(self, slots=2):
@@ -278,7 +279,7 @@ print(f"serve.cache_fault OK: {fires} injected cache faults degraded to "
 PY
 
 echo
-echo "== TS_FAULTS sweep: serve.arena_full (paged admission requeues, never rejects)"
+echo "== TS_FAULTS sweep: serve.arena_full (admission by free pages requeues, never rejects)"
 TS_FAULTS="serve.arena_full:1.0:0:2" python - <<'PY'
 import glob
 import tempfile
@@ -295,11 +296,10 @@ class NullDecoder:
         return last
 
 class PagedSimEngine:
-    """Jax-free paged slot engine (ISSUE 20): a 4-page arena over 2
+    """Jax-free slot engine with a page arena (ISSUE 20): 4 pages over 2
     slots, 2 decode chunks per request — the REAL ContinuousBatcher
     does the page-gated admission; the armed serve.arena_full point
     lands the allocation failure inside pack."""
-    paged = True
     def __init__(self, slots=2, pages=4, page_words=4):
         self.slots, self._cap = slots, pages
         self._free = list(range(pages))

@@ -18,11 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
+from _slots import Slots
 from test_beam_search import make_arrays
 
 from textsummarization_on_flink_tpu import obs
-from textsummarization_on_flink_tpu.config import HParams
+from textsummarization_on_flink_tpu.config import HParams, resolve_arena_pages
 from textsummarization_on_flink_tpu.data.vocab import START_ID, STOP_ID, UNK_ID
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -171,20 +171,6 @@ def test_backtrack_matches_mirror_no_early_exit(family_name, hps):
         assert_matches_mirror(out, b, ref)
 
 
-def _drive_slots(params, hps, state, slots, chunk=3, max_chunks=16):
-    active = np.ones(slots, bool)
-    done = {}
-    for _ in range(max_chunks):
-        state, fin = beam_search.step_slots_jit(params, hps, state,
-                                                active, chunk)
-        for s in np.nonzero(np.asarray(fin))[0]:
-            done[int(s)] = beam_search.unpack_slot_jit(hps, state, int(s))
-            active[s] = False
-        if not active.any():
-            break
-    return done
-
-
 def _assert_slot_matches_mirror(out, ref):
     n = int(out.length)
     assert list(np.asarray(out.tokens)[:n]) == ref.tokens
@@ -204,15 +190,10 @@ def test_slot_kernels_match_materialized_mirror(family_name, hps):
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(3))
     arrays = make_arrays(hps, seed=6)
     slots = hps.batch_size
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
-    state = beam_search.init_slots_jit(params, hps, zero)
+    eng = Slots(params, hps, arrays, slots)
     for slot in range(slots):
-        one = {k: v[slot:slot + 1] for k, v in arrays.items()}
-        state = beam_search.pack_slot_jit(
-            params, hps, state, slot,
-            beam_search.prefill_jit(params, hps, one))
-    done = _drive_slots(params, hps, state, slots)
+        eng.pack(slot, {k: v[slot:slot + 1] for k, v in arrays.items()})
+    done, _ = eng.drive()
     assert sorted(done) == list(range(slots))
     for b in range(slots):
         ref = materialized_search(params, hps, family, arrays, b)
@@ -252,6 +233,13 @@ def _arrays_with_lens(hps, lens, seed=0):
     return arrays
 
 
+def _one_at(arrays, row, bucket):
+    """Article `row` as [1, bucket] arrays (what prefill_jit takes)."""
+    return {k: (v[row:row + 1, :bucket] if v.ndim == 2
+                else v[row:row + 1])
+            for k, v in arrays.items()}
+
+
 @pytest.mark.parametrize("family_name,hps", FAMILY_CASES)
 def test_bucketed_prefill_matches_mirror_at_every_length(family_name, hps):
     """Mixed-length slot occupancy through the DISAGGREGATED path:
@@ -266,22 +254,17 @@ def test_bucketed_prefill_matches_mirror_at_every_length(family_name, hps):
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(3))
     arrays = _arrays_with_lens(hps, _DISAGG_LENS, seed=6)
     slots = len(_DISAGG_LENS)
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
-    state = beam_search.init_slots_jit(params, hps, zero)
+    eng = Slots(params, hps, arrays, slots)
     for slot, true_len in enumerate(_DISAGG_LENS):
         bucket = next(b for b in _DISAGG_BUCKETS if true_len <= b)
-        one = {k: (v[slot:slot + 1, :bucket] if v.ndim == 2
-                   else v[slot:slot + 1])
-               for k, v in arrays.items()}
-        pre = beam_search.prefill_jit(params, hps, one)
+        pre = eng.prefill(_one_at(arrays, slot, bucket))
         assert int(np.asarray(pre.enc_valid_len)[0]) == true_len
-        state = beam_search.pack_slot_jit(params, hps, state, slot, pre)
+        eng.pack(slot, pre)
     # the resident state records every article's TRUE length, not its
     # bucket or the padded width
     np.testing.assert_array_equal(
-        np.asarray(state.enc_valid_len), np.asarray(_DISAGG_LENS))
-    done = _drive_slots(params, hps, state, slots)
+        np.asarray(eng.state.enc_valid_len), np.asarray(_DISAGG_LENS))
+    done, _ = eng.drive()
     assert sorted(done) == list(range(slots))
     for b in range(slots):
         ref = materialized_search(params, hps, family, arrays, b)
@@ -365,6 +348,14 @@ class TestBf16KVCache:
         assert outs["float32"].state["cache_k"].dtype == jnp.float32
 
 
+def _ledger_call(reg):
+    """Slots' kernel runner through the shared compile ledger."""
+    def call(site, fn, *args, key=""):
+        return profile_lib.compiled_call(reg, site, fn, *args, key=key)
+
+    return call
+
+
 def test_finalize_adds_at_most_one_compile_to_warm_set():
     """ISSUE 7 acceptance detail: the backtrack lives INSIDE
     unpack_slot_jit, so a fresh config still warms the slot engine with
@@ -378,25 +369,12 @@ def test_finalize_adds_at_most_one_compile_to_warm_set():
     family = get_family("pointer_generator")
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(1))
     arrays = make_arrays(hps, seed=8)
-    slots = 2
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
     with obs.use_registry(Registry()) as reg:
-        def call(site, fn, *args):
-            return profile_lib.compiled_call(reg, site, fn, *args)
-
-        state = call("decode/init_slots_jit", beam_search.init_slots_jit,
-                     params, hps, zero)
-        one = {k: v[0:1] for k, v in arrays.items()}
-        pre = call("decode/prefill_jit", beam_search.prefill_jit,
-                   params, hps, one)
-        state = call("decode/pack_slot_jit", beam_search.pack_slot_jit,
-                     params, hps, state, 0, pre)
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_jit, params, hps, state,
-                        np.array([True, False]), 2)
-        call("decode/unpack_slot_jit", beam_search.unpack_slot_jit,
-             hps, state, 0)
+        eng = Slots(params, hps, arrays, slots=2,
+                    call=_ledger_call(reg))
+        eng.pack(0, {k: v[0:1] for k, v in arrays.items()})
+        eng.step([True, False], 2)
+        eng.unpack(0)
         stats = profile_lib.profiler_for(reg).compile_stats()
     growth = {site: st["compiles"] for site, st in stats.items()
               if site != "decode/prefill_jit"}
@@ -422,9 +400,6 @@ def test_warm_set_is_four_plus_one_prefill_per_bucket():
     family = get_family("pointer_generator")
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(2))
     arrays = _arrays_with_lens(hps, (2, 7, 12), seed=5)
-    slots = 3
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
     buckets = (4, 8, 12)
     with obs.use_registry(Registry()) as reg:
         prof = profile_lib.install_profiler(reg)
@@ -432,27 +407,15 @@ def test_warm_set_is_four_plus_one_prefill_per_bucket():
                        "decode/step_slots_jit", "decode/unpack_slot_jit"):
             prof.set_compile_budget(kernel, 1)
         prof.set_compile_budget("decode/prefill_jit", len(buckets))
+        eng = Slots(params, hps, arrays, slots=3, call=_ledger_call(reg))
 
-        def call(site, fn, *args, key=""):
-            return profile_lib.compiled_call(reg, site, fn, *args, key=key)
+        def pre_at(row, bucket):
+            return eng.prefill(_one_at(arrays, row, bucket), key=bucket)
 
-        def pre_at(slot, bucket):
-            one = {k: (v[slot:slot + 1, :bucket] if v.ndim == 2
-                       else v[slot:slot + 1])
-                   for k, v in arrays.items()}
-            return call("decode/prefill_jit", beam_search.prefill_jit,
-                        params, hps, one, key=bucket)
-
-        state = call("decode/init_slots_jit", beam_search.init_slots_jit,
-                     params, hps, zero)
         for slot, bucket in enumerate(buckets):  # warm every bucket
-            state = call("decode/pack_slot_jit", beam_search.pack_slot_jit,
-                         params, hps, state, slot, pre_at(slot, bucket))
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_jit, params, hps, state,
-                        np.array([True, True, True]), 2)
-        call("decode/unpack_slot_jit", beam_search.unpack_slot_jit,
-             hps, state, 1)
+            eng.pack(slot, pre_at(slot, bucket))
+        eng.step([True, True, True], 2)
+        eng.unpack(1)
         stats = prof.compile_stats()
         growth = {site: st["compiles"] for site, st in stats.items()}
         assert growth == {"decode/init_slots_jit": 1,
@@ -466,18 +429,11 @@ def test_warm_set_is_four_plus_one_prefill_per_bucket():
             str(b) for b in buckets), stats
         # churn: different slots, buckets, occupancy patterns, length
         # mixes — every call must land as a ledger HIT
-        state = call("decode/pack_slot_jit", beam_search.pack_slot_jit,
-                     params, hps, state, 1, pre_at(0, 4))
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_jit, params, hps, state,
-                        np.array([False, True, True]), 2)
-        state = call("decode/pack_slot_jit", beam_search.pack_slot_jit,
-                     params, hps, state, 0, pre_at(2, 8))
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_jit, params, hps, state,
-                        np.array([True, False, False]), 2)
-        call("decode/unpack_slot_jit", beam_search.unpack_slot_jit,
-             hps, state, 0)
+        eng.pack(1, pre_at(0, 4))
+        eng.step([False, True, True], 2)
+        eng.pack(0, pre_at(2, 8))
+        eng.step([True, False, False], 2)
+        eng.unpack(0)
         after = prof.compile_stats()
         assert prof.warm_set_size() == 4 + len(buckets), after
         churn_hits = sum(st["hits"] for st in after.values()) \
@@ -489,12 +445,13 @@ def test_warm_set_is_four_plus_one_prefill_per_bucket():
 
 # -- paged resident state parity (ISSUE 20) --------------------------------
 #
-# The page arena replaced the slot state's worst-case per-slot leaves
-# with pools of decode_enc_block-row pages addressed through a per-slot
-# page table (data, not shape).  The mirror stays the FULL-WIDTH dense
-# search: exactness across page-boundary article lengths, arena-full
-# backpressure, and harvest-then-reuse page recycling is the claim that
-# paging changed the MEMORY story, not the numerics.
+# The slot state's enc-axis leaves are pools of decode_enc_block-row
+# pages addressed through a per-slot page table (data, not shape); the
+# tests above run them over the default arena, these over a sized one.
+# The mirror stays the FULL-WIDTH materialized search: exactness across
+# page-boundary article lengths, arena-full backpressure, and
+# harvest-then-reuse page recycling is the claim that paging changed
+# the MEMORY story, not the numerics.
 
 from textsummarization_on_flink_tpu.decode.arena import (  # noqa: E402
     ArenaExhaustedError,
@@ -506,28 +463,6 @@ from textsummarization_on_flink_tpu.decode.arena import (  # noqa: E402
 #: page boundary (block+1), the minimal 1-token article, and the full
 #: 3-page grid — packed together (mixed page-count occupancy).
 _PAGED_LENS = (4, 5, 1, 12)
-
-
-def _scratch_row(row_ids, b_max, pages):
-    row = np.full(b_max, pages, np.int32)
-    row[:len(row_ids)] = row_ids
-    return row
-
-
-def _drive_slots_paged(params, hps, state, table, slots, chunk=3,
-                       max_chunks=16):
-    active = np.ones(slots, bool)
-    done = {}
-    for _ in range(max_chunks):
-        state, fin = beam_search.step_slots_paged_jit(
-            params, hps, state, active, np.asarray(table), chunk)
-        for s in np.nonzero(np.asarray(fin))[0]:
-            done[int(s)] = beam_search.unpack_slot_paged_jit(
-                hps, state, int(s), np.asarray(table)[int(s)])
-            active[s] = False
-        if not active.any():
-            break
-    return state, done
 
 
 @pytest.mark.parametrize("family_name,hps", FAMILY_CASES)
@@ -543,28 +478,16 @@ def test_paged_kernels_match_mirror_at_page_boundaries(family_name, hps):
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(3))
     arrays = _arrays_with_lens(hps, _PAGED_LENS, seed=6)
     slots = len(_PAGED_LENS)
-    block, b_max = 4, 3
     arena = PageArena(9)  # 1+2+1+3 pages needed of 9
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
-    state = beam_search.init_slots_paged_jit(params, hps, zero,
-                                             arena.capacity)
-    table = np.full((slots, b_max), arena.capacity, np.int32)
+    eng = Slots(params, hps, arrays, slots, pages=arena.capacity)
     for slot, true_len in enumerate(_PAGED_LENS):
         bucket = next(b for b in _DISAGG_BUCKETS if true_len <= b)
-        one = {k: (v[slot:slot + 1, :bucket] if v.ndim == 2
-                   else v[slot:slot + 1])
-               for k, v in arrays.items()}
-        pre = beam_search.prefill_jit(params, hps, one)
-        ids = arena.alloc(max(1, -(-true_len // block)))
-        row = _scratch_row(ids, b_max, arena.capacity)
-        state = beam_search.pack_slot_paged_jit(params, hps, state, slot,
-                                                pre, row)
-        table[slot] = row
+        eng.pack(slot, _one_at(arrays, slot, bucket),
+                 arena.alloc(max(1, -(-true_len // eng.block))))
     assert arena.pages_in_use == 7
     np.testing.assert_array_equal(
-        np.asarray(state.enc_valid_len), np.asarray(_PAGED_LENS))
-    _, done = _drive_slots_paged(params, hps, state, table, slots)
+        np.asarray(eng.state.enc_valid_len), np.asarray(_PAGED_LENS))
+    done, _ = eng.drive()
     assert sorted(done) == list(range(slots))
     for b in range(slots):
         ref = materialized_search(params, hps, family, arrays, b)
@@ -587,24 +510,10 @@ def test_paged_arena_full_backpressure_then_recycle_exact(family_name,
     family = get_family(family_name)
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(3))
     arrays = _arrays_with_lens(hps, (12, 12), seed=6)
-    block, b_max = 4, 3
     arena = PageArena(3)  # exactly one 3-page resident fits
-    zero = {k: np.zeros((2,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
-    state = beam_search.init_slots_paged_jit(params, hps, zero,
-                                             arena.capacity)
-    table = np.full((2, b_max), arena.capacity, np.int32)
-
-    def pack(slot, src_row, ids):
-        one = {k: v[src_row:src_row + 1] for k, v in arrays.items()}
-        pre = beam_search.prefill_jit(params, hps, one)
-        row = _scratch_row(ids, b_max, arena.capacity)
-        table[slot] = row
-        return beam_search.pack_slot_paged_jit(params, hps, state, slot,
-                                               pre, row)
-
+    eng = Slots(params, hps, arrays, 2, pages=arena.capacity)
     ids_a = arena.alloc(3)
-    state = pack(0, 0, ids_a)
+    eng.pack(0, _one_at(arrays, 0, 12), ids_a)
     # the second full-length admission cannot get pages: typed, carries
     # the shortfall, allocates NOTHING
     with pytest.raises(ArenaExhaustedError) as exc:
@@ -613,38 +522,29 @@ def test_paged_arena_full_backpressure_then_recycle_exact(family_name,
     assert arena.free_pages == 0 and arena.pages_in_use == 3
     # drive the resident alone to completion — the blocked admission
     # never touched it
-    active = np.array([True, False])
-    done0 = None
-    for _ in range(16):
-        state, fin = beam_search.step_slots_paged_jit(
-            params, hps, state, active, table, 3)
-        if np.asarray(fin)[0]:
-            done0 = beam_search.unpack_slot_paged_jit(hps, state, 0,
-                                                      table[0])
-            break
-    assert done0 is not None
+    done, _ = eng.drive([True, False])
     ref0 = materialized_search(params, hps, family, arrays, 0)
-    _assert_slot_matches_mirror(done0, ref0)
-    # harvest frees the pages; the retried admission reuses the SAME ids
+    _assert_slot_matches_mirror(done[0], ref0)
+    # harvest frees the pages (drive pointed the stale row at scratch,
+    # the engine contract); the retried admission reuses the SAME ids
     arena.free(ids_a.tolist())
-    table[0] = arena.capacity  # stale row -> scratch (engine contract)
+    assert (eng.table[0] == arena.capacity).all()
     ids_b = arena.alloc(3)
     assert sorted(ids_b.tolist()) == sorted(ids_a.tolist())
-    state = pack(1, 1, ids_b)
-    _, done = _drive_slots_paged(params, hps, state, table, 2,
-                                 chunk=3)
+    eng.pack(1, _one_at(arrays, 1, 12), ids_b)
+    done, _ = eng.drive([False, True])
     ref1 = materialized_search(params, hps, family, arrays, 1)
     _assert_slot_matches_mirror(done[1], ref1)
 
 
 def test_paged_warm_set_allocation_churn_never_recompiles():
-    """The ISSUE 20 compile pin: the paged engine warms with the SAME
-    four decode compiles (page-table contents, allocation pattern,
+    """The ISSUE 20 compile pin: over a sized arena the engine warms
+    with the SAME four decode compiles (page-table contents, allocation pattern,
     page-count mix, and occupancy are all traced data) plus one prefill
     per bucket — and after the warm set, page recycling, permuted
     allocation orders, different page counts per slot, and table
     rewrites all land as ledger HITS, never compiles."""
-    # max_oov_buckets=5 keeps every aval distinct from the dense
+    # max_oov_buckets=5 keeps every aval distinct from the default-arena
     # warm-set tests above, so the ledger counts FRESH compiles even in
     # a shared-process run (the global jit caches persist across tests)
     hps = PG_HPS.replace(max_oov_buckets=5, beam_size=2,
@@ -652,9 +552,6 @@ def test_paged_warm_set_allocation_churn_never_recompiles():
     family = get_family("pointer_generator")
     params = family.init_params(hps, hps.vocab_size, jax.random.PRNGKey(2))
     arrays = _arrays_with_lens(hps, (2, 7, 12), seed=5)
-    slots, b_max, pages = 3, 3, 7
-    zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-            for k, v in arrays.items()}
     buckets = (4, 8, 12)
     with obs.use_registry(Registry()) as reg:
         prof = profile_lib.install_profiler(reg)
@@ -663,37 +560,19 @@ def test_paged_warm_set_allocation_churn_never_recompiles():
             prof.set_compile_budget(kernel, 1)
         prof.set_compile_budget("decode/prefill_jit", len(buckets))
 
-        def call(site, fn, *args, key=""):
-            return profile_lib.compiled_call(reg, site, fn, *args, key=key)
-
-        def pre_at(slot, bucket):
-            one = {k: (v[slot:slot + 1, :bucket] if v.ndim == 2
-                       else v[slot:slot + 1])
-                   for k, v in arrays.items()}
-            return call("decode/prefill_jit", beam_search.prefill_jit,
-                        params, hps, one, key=bucket)
-
-        table = np.full((slots, b_max), pages, np.int32)
+        eng = Slots(params, hps, arrays, slots=3, pages=7,
+                    call=_ledger_call(reg))
 
         def pack(slot, bucket, ids):
-            row = _scratch_row(np.asarray(ids, np.int32), b_max, pages)
-            table[slot] = row
-            return call("decode/pack_slot_jit",
-                        beam_search.pack_slot_paged_jit, params, hps,
-                        state, slot, pre_at(slot, bucket), row)
+            eng.pack(slot, eng.prefill(_one_at(arrays, slot, bucket),
+                                       key=bucket), ids)
 
-        state = call("decode/init_slots_jit",
-                     beam_search.init_slots_paged_jit, params, hps, zero,
-                     pages)
         # warm: every bucket, differing page counts (1, 2, 3 pages)
-        state = pack(0, 4, [0])
-        state = pack(1, 8, [1, 2])
-        state = pack(2, 12, [3, 4, 5])
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_paged_jit, params, hps,
-                        state, np.array([True, True, True]), table, 2)
-        call("decode/unpack_slot_jit", beam_search.unpack_slot_paged_jit,
-             hps, state, 1, table[1])
+        pack(0, 4, [0])
+        pack(1, 8, [1, 2])
+        pack(2, 12, [3, 4, 5])
+        eng.step([True, True, True], 2)
+        eng.unpack(1)
         stats = prof.compile_stats()
         growth = {site: st["compiles"] for site, st in stats.items()}
         assert growth == {"decode/init_slots_jit": 1,
@@ -705,17 +584,12 @@ def test_paged_warm_set_allocation_churn_never_recompiles():
         # allocation-pattern churn: recycled ids out of order, a
         # different page count in the same slot, a non-contiguous
         # allocation, shifting occupancy — all HITS
-        state = pack(1, 4, [6])                    # fewer pages, new id
-        state = pack(0, 8, [5, 1])                 # recycled, permuted
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_paged_jit, params, hps,
-                        state, np.array([True, False, True]), table, 2)
-        state = pack(2, 12, [2, 0, 4])             # recycled, shuffled
-        state, _ = call("decode/step_slots_jit",
-                        beam_search.step_slots_paged_jit, params, hps,
-                        state, np.array([False, True, True]), table, 2)
-        call("decode/unpack_slot_jit", beam_search.unpack_slot_paged_jit,
-             hps, state, 2, table[2])
+        pack(1, 4, [6])                    # fewer pages, new id
+        pack(0, 8, [5, 1])                 # recycled, permuted
+        eng.step([True, False, True], 2)
+        pack(2, 12, [2, 0, 4])             # recycled, shuffled
+        eng.step([False, True, True], 2)
+        eng.unpack(2)
         after = prof.compile_stats()
         assert prof.warm_set_size() == 4 + len(buckets), after
         churn_hits = sum(st["hits"] for st in after.values()) \
@@ -762,3 +636,112 @@ class TestPageArena:
             a.free([7])
         with pytest.raises(ValueError):
             PageArena(0)
+
+
+# -- the arena an engine gets (ISSUE 32) ------------------------------------
+#
+# config.resolve_arena_pages is the one rule: the pages asked for, else
+# the pages a byte budget buys, else every slot at full length — never
+# zero, never fewer than one full-length article.
+
+_ARENA_HPS = PG_HPS.replace(decode_enc_block=4)  # b_max = 3
+
+
+@pytest.mark.parametrize("options,slots,page_bytes,want", [
+    pytest.param({}, 5, None, 15, id="no-option-is-slots-x-b_max"),
+    pytest.param({"serve_arena_pages": 7}, 5, None, 7, id="explicit-pages"),
+    pytest.param({"serve_arena_mb": 1.0}, 5, 4096, 256, id="byte-budget"),
+    pytest.param({"serve_arena_pages": 2}, 5, None, ValueError,
+                 id="under-one-article-raises"),
+    pytest.param({"serve_arena_mb": 0.01}, 5, 4096, ValueError,
+                 id="budget-under-one-article-raises"),
+    pytest.param({"serve_arena_mb": 1.0}, 5, None, ValueError,
+                 id="budget-needs-page-bytes"),
+])
+def test_resolve_arena_pages(options, slots, page_bytes, want):
+    hps = _ARENA_HPS.replace(**options)
+    if want is ValueError:
+        with pytest.raises(ValueError):
+            resolve_arena_pages(hps, slots, page_bytes)
+    else:
+        assert resolve_arena_pages(hps, slots, page_bytes) == want
+
+
+_WORDS = ("the a cat dog sat ran mat home big small quick brown fox "
+          "jumped over lazy it was day night").split()
+
+
+def _engine_and_examples(hps, params, tmp_path, lens, slots):
+    """A SlotDecodeEngine over `params` and one SummaryExample an entry
+    of `lens` (article lengths in words)."""
+    from textsummarization_on_flink_tpu.data.batching import SummaryExample
+    from textsummarization_on_flink_tpu.data.vocab import Vocab
+    from textsummarization_on_flink_tpu.decode.decoder import (
+        BeamSearchDecoder,
+    )
+
+    vocab = Vocab(words=_WORDS)
+    assert vocab.size() == hps.vocab_size
+    rng = np.random.RandomState(4)
+    exs = [SummaryExample.build(" ".join(rng.choice(_WORDS, n)), [], vocab,
+                                hps, uuid=f"u{i}")
+           for i, n in enumerate(lens)]
+    dec = BeamSearchDecoder(hps, vocab, batcher=None, params=params,
+                            decode_root=str(tmp_path / "d"))
+    return dec, dec.slot_engine(slots=slots, chunk=3), exs, vocab
+
+
+def _drain(eng, exs_by_slot):
+    results = {}
+    for _ in range(16):
+        for idx in eng.step():
+            results[idx] = eng.unpack(idx, exs_by_slot[idx])
+        if len(results) == len(exs_by_slot):
+            return results
+    raise AssertionError("engine never drained")
+
+
+def test_engine_with_no_arena_option_holds_every_slot_at_full_length(
+        tmp_path):
+    """Every slot filled with a full-length article: the default arena
+    has exactly the pages, nothing waits, and it drains to zero."""
+    hps = _ARENA_HPS
+    assert hps.serve_arena_pages == 0 and hps.serve_arena_mb == 0
+    params = get_family(hps.model_family).init_params(
+        hps, hps.vocab_size, jax.random.PRNGKey(3))
+    slots = 3
+    _, eng, exs, _ = _engine_and_examples(
+        hps, params, tmp_path, [hps.max_enc_steps + 5] * slots, slots)
+    assert eng.arena_stats()["capacity"] == slots * 3
+    for i, ex in enumerate(exs):
+        assert eng.pages_needed(ex) == 3
+        eng.pack(i, ex)  # no ArenaExhaustedError
+    assert eng.free_pages() == 0 and eng.arena_stats()["fill"] == 1.0
+    _drain(eng, dict(enumerate(exs)))
+    stats = eng.arena_stats()
+    assert stats["in_use"] == 0 and stats["free"] == stats["capacity"]
+
+
+@pytest.mark.parametrize("family_name,hps", FAMILY_CASES)
+def test_default_arena_engine_matches_the_batch_search(family_name, hps,
+                                                       tmp_path):
+    """Continuous decode with no arena option, mixed lengths and a slot
+    reused, against run_beam_search on the same articles."""
+    from textsummarization_on_flink_tpu.data.batching import Batch
+
+    hps = hps.replace(decode_enc_block=4, batch_size=4)
+    params = get_family(family_name).init_params(hps, hps.vocab_size,
+                                                 jax.random.PRNGKey(3))
+    dec, eng, exs, vocab = _engine_and_examples(
+        hps, params, tmp_path, (1, 4, 9, 12), slots=3)
+    want = dec.decode_batch(Batch(exs, hps, vocab))
+    for i in range(3):
+        eng.pack(i, exs[i])
+    got = _drain(eng, dict(enumerate(exs[:3])))
+    eng.pack(1, exs[3])  # a retired slot's pages again
+    got[3] = _drain(eng, {1: exs[3]})[1]
+    for i, w in enumerate(want):
+        assert got[i].decoded_words == w.decoded_words
+        assert got[i].avg_log_prob == pytest.approx(w.avg_log_prob,
+                                                    rel=2e-5)
+    assert eng.arena_stats()["in_use"] == 0

@@ -12,6 +12,7 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
+from _slots import Slots
 
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.data.vocab import START_ID, STOP_ID, UNK_ID
@@ -221,24 +222,10 @@ def test_chunked_parity_when_no_beam_finishes(params):
 
 class TestSlotSearch:
     """The continuous-batching slot kernels (pack/step/unpack over a
-    persistent [slots, beam, ...] state) against the batch search:
-    identical per-article trajectories, per-slot activity masking, and
-    a jit cache that never grows with slot index or occupancy."""
-
-    def _drive(self, params, hps, state, active, chunk, max_chunks=16):
-        """Step until every active slot finishes; returns {slot: output,
-        ...} plus the number of chunks run."""
-        done = {}
-        active = np.array(active)
-        for n in range(1, max_chunks + 1):
-            state, fin = beam_search.step_slots_jit(params, hps, state,
-                                                    active, chunk)
-            for s in np.nonzero(np.asarray(fin))[0]:
-                done[int(s)] = beam_search.unpack_slot_jit(hps, state, int(s))
-                active[s] = False
-            if not active.any():
-                return state, done, n
-        raise AssertionError("slots never finished")
+    persistent state, driven through tests/_slots.py over the default
+    arena) against the batch search: identical per-article
+    trajectories, per-slot activity masking, and a jit cache that never
+    grows with slot index or occupancy."""
 
     def test_slot_parity_with_batch_search(self, params):
         """Articles packed into arbitrary slots, stepped with a chunk
@@ -246,18 +233,11 @@ class TestSlotSearch:
         one-dispatch batch search."""
         arrays = make_arrays(HPS, seed=0)
         ref = beam_search.run_beam_search(params, HPS, arrays)
-        slots = 3
-        zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-                for k, v in arrays.items()}
-        state = beam_search.init_slots_jit(params, HPS, zero)
+        eng = Slots(params, HPS, arrays, slots=3)
         placement = {2: 0, 0: 1}  # slot -> article
         for slot, art in placement.items():
-            one = {k: v[art:art + 1] for k, v in arrays.items()}
-            state = beam_search.pack_slot_jit(
-                params, HPS, state, slot,
-                beam_search.prefill_jit(params, HPS, one))
-        _, done, _ = self._drive(params, HPS, state,
-                                 [True, False, True], chunk=3)
+            eng.pack(slot, {k: v[art:art + 1] for k, v in arrays.items()})
+        done, _ = eng.drive([True, False, True], chunk=3)
         assert sorted(done) == sorted(placement)
         for slot, art in placement.items():
             out = done[slot]
@@ -278,35 +258,16 @@ class TestSlotSearch:
         continuous scheduler depends on."""
         arrays = make_arrays(HPS, seed=9)
         ref = beam_search.run_beam_search(params, HPS, arrays)
-        slots = 2
-        zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-                for k, v in arrays.items()}
-        state = beam_search.init_slots_jit(params, HPS, zero)
-        one = {k: v[0:1] for k, v in arrays.items()}
-        state = beam_search.pack_slot_jit(
-            params, HPS, state, 1,
-            beam_search.prefill_jit(params, HPS, one))
-        state, fin = beam_search.step_slots_jit(
-            params, HPS, state, np.array([False, True]), 2)
-        assert not bool(np.asarray(fin)[0])  # inactive slot stays silent
+        eng = Slots(params, HPS, arrays, slots=2)
+        eng.pack(1, {k: v[0:1] for k, v in arrays.items()})
+        fin = eng.step([False, True], 2)
+        assert not bool(fin[0])  # inactive slot stays silent
         # retire slot 1 whenever it finishes, then REFILL it with
         # article 1 and check the second tenancy end to end
-        active = np.array([False, True])
-        done = {}
-        for _ in range(16):
-            for s in np.nonzero(np.asarray(fin))[0]:
-                done[int(s)] = beam_search.unpack_slot_jit(HPS, state, int(s))
-                active[s] = False
-            if done:
-                break
-            state, fin = beam_search.step_slots_jit(params, HPS, state,
-                                                    active, 2)
+        done, _ = eng.drive([False, True], chunk=2)
         assert 1 in done
-        two = {k: v[1:2] for k, v in arrays.items()}
-        state = beam_search.pack_slot_jit(
-            params, HPS, state, 1,
-            beam_search.prefill_jit(params, HPS, two))
-        _, done2, _ = self._drive(params, HPS, state, [False, True], chunk=2)
+        eng.pack(1, {k: v[1:2] for k, v in arrays.items()})
+        done2, _ = eng.drive([False, True], chunk=2)
         out = done2[1]
         n = int(out.length)
         assert list(np.asarray(out.tokens)[:n]) == \
@@ -318,29 +279,18 @@ class TestSlotSearch:
         through different slots adds ZERO jit-cache entries (the
         'no per-request recompiles' acceptance claim at kernel level)."""
         arrays = make_arrays(HPS, seed=2)
-        slots = 3
-        zero = {k: np.zeros((slots,) + v.shape[1:], v.dtype)
-                for k, v in arrays.items()}
-        state = beam_search.init_slots_jit(params, HPS, zero)
-        one = {k: v[0:1] for k, v in arrays.items()}
-        state = beam_search.pack_slot_jit(
-            params, HPS, state, 0,
-            beam_search.prefill_jit(params, HPS, one))
-        state, _ = beam_search.step_slots_jit(
-            params, HPS, state, np.array([True, False, False]), 3)
-        beam_search.unpack_slot_jit(HPS, state, 0)
+        eng = Slots(params, HPS, arrays, slots=3)
+        eng.pack(0, {k: v[0:1] for k, v in arrays.items()})
+        eng.step([True, False, False], 3)
+        eng.unpack(0)
         sizes = {f: f._cache_size()
                  for f in (beam_search.pack_slot_jit,
                            beam_search.step_slots_jit,
                            beam_search.unpack_slot_jit)}
         for slot, art in ((1, 1), (2, 0), (0, 1)):
-            nxt = {k: v[art:art + 1] for k, v in arrays.items()}
-            state = beam_search.pack_slot_jit(
-                params, HPS, state, slot,
-                beam_search.prefill_jit(params, HPS, nxt))
-        state, _ = beam_search.step_slots_jit(
-            params, HPS, state, np.array([True, True, True]), 3)
-        beam_search.unpack_slot_jit(HPS, state, 2)
+            eng.pack(slot, {k: v[art:art + 1] for k, v in arrays.items()})
+        eng.step([True, True, True], 3)
+        eng.unpack(2)
         for f, before in sizes.items():
             assert f._cache_size() == before, f
 

@@ -353,16 +353,16 @@ def test_prefill_cost_scales_with_bucket(budget, prefill_measured, family):
 
 
 # --------------------------------------------------------------------------
-# Paged resident-state gate (ISSUE 20; PERF.md "Paged resident state")
+# Paged resident-state gate (ISSUE 20; SERVING.md "Paged resident state")
 # --------------------------------------------------------------------------
 #
 # The committed `decode.resident` section pins what one ADMITTED slot
-# holds in HBM, dense vs paged, via decode_resident_bytes (eval_shape
-# accounting of the REAL init_slots_jit / init_slots_paged_jit states)
-# at the decode gate scale: the dense worst-case-provisioned baseline is
-# re-measured and pinned, the paged per-slot cost at the bimodal mix
-# stays under its ceiling, and the reduction floors — the "HBM holds
-# more residents" claim priced per slot — hold.
+# holds in HBM via decode_resident_bytes (eval_shape accounting of the
+# REAL init_slots_jit state) at the decode gate scale: the full-length
+# reservation (what every slot holds under the default arena) is
+# re-measured and pinned, the per-slot cost at the bimodal mix stays
+# under its ceiling, and the reduction floors — the "HBM holds more
+# residents" claim priced per slot — hold.
 
 
 @pytest.fixture(scope="module")
@@ -378,61 +378,61 @@ def resident_measured(budget):
 
 
 @pytest.mark.parametrize("family", _DISAGG_FAMILIES)
-def test_resident_dense_baseline_pinned(budget, resident_measured, family):
-    """The comparison cannot drift silently: the re-measured dense
-    per-slot bytes must sit within dense_slack of the committed
-    pre-change baseline (eval_shape is deterministic — a move here
-    means the dense slot state itself changed, which requires
-    re-baselining IN THE SAME COMMIT)."""
+def test_resident_full_length_baseline_pinned(budget, resident_measured,
+                                              family):
+    """The comparison cannot drift silently: the re-measured
+    full-length per-slot bytes must sit within full_length_slack of the
+    committed figure (eval_shape is deterministic — a move here means
+    the slot state itself changed, which requires re-baselining IN THE
+    SAME COMMIT)."""
     rs = budget["decode"]["resident"]
-    committed = rs["baseline"][family]["dense_bytes_per_slot"]
-    got = resident_measured[family]["dense_bytes_per_slot"]
-    slack = rs["dense_slack"]
+    committed = rs["baseline"][family]["full_length_bytes_per_slot"]
+    got = resident_measured[family]["full_length_bytes_per_slot"]
+    slack = rs["full_length_slack"]
     assert abs(got - committed) <= slack * committed, (
-        f"{family}: dense resident bytes/slot moved to {got} (committed "
-        f"{committed} ± {slack:.0%}) — the dense SlotState changed under "
-        f"the paged comparison (see BYTE_BUDGET.json "
+        f"{family}: full-length resident bytes/slot moved to {got} "
+        f"(committed {committed} ± {slack:.0%}) — the SlotState changed "
+        f"under the comparison (see BYTE_BUDGET.json "
         f"decode.resident._comment)")
 
 
 @pytest.mark.parametrize("family", _DISAGG_FAMILIES)
-def test_resident_paged_bytes_within_budget(budget, resident_measured,
-                                            family):
+def test_resident_bytes_within_budget(budget, resident_measured, family):
     ceiling = budget["decode"]["resident"]["budgets"][family][
-        "max_paged_bytes_per_slot"]
-    got = resident_measured[family]["paged_bytes_per_slot"]
+        "max_bytes_per_slot"]
+    got = resident_measured[family]["bytes_per_slot"]
     assert got <= ceiling, (
-        f"{family}: paged resident bytes/slot at the bimodal mix rose to "
+        f"{family}: resident bytes/slot at the bimodal mix rose to "
         f"{got} (committed ceiling {ceiling}) — the fixed share or the "
         f"page grew (see BYTE_BUDGET.json decode.resident._comment)")
 
 
 @pytest.mark.parametrize("family", _DISAGG_FAMILIES)
 def test_resident_reduction_floor_holds(budget, resident_measured, family):
-    """The headline claim per slot: at the bimodal mix, a paged resident
-    holds at least the committed fraction less HBM than the dense
-    worst-case slot — the capacity the arena converts into extra
-    residents (the serving-level half is SERVE_SLO.json 'paged')."""
+    """The headline claim per slot: at the bimodal mix, a resident
+    holds at least the committed fraction less HBM than a full-length
+    one — the capacity a tight arena converts into extra residents
+    (the serving-level half is SERVE_SLO.json 'paged')."""
     floor = budget["decode"]["resident"]["budgets"][family][
-        "min_reduction_vs_dense"]
-    dense = resident_measured[family]["dense_bytes_per_slot"]
-    paged = resident_measured[family]["paged_bytes_per_slot"]
-    reduction = 1.0 - paged / dense
+        "min_reduction_vs_full_length"]
+    full = resident_measured[family]["full_length_bytes_per_slot"]
+    at_mix = resident_measured[family]["bytes_per_slot"]
+    reduction = 1.0 - at_mix / full
     assert reduction >= floor, (
-        f"{family}: paged-vs-dense resident reduction fell to "
-        f"{reduction:.1%} (committed floor {floor:.1%}) — paging no "
-        f"longer buys resident capacity at the bimodal mix")
+        f"{family}: resident reduction at the mix against the full "
+        f"length fell to {reduction:.1%} (committed floor {floor:.1%}) "
+        f"— paging no longer buys resident capacity at the bimodal mix")
 
 
 @pytest.mark.parametrize("family", _DISAGG_FAMILIES)
 def test_resident_accounting_is_structural(resident_measured, family):
     """Honesty check on the accounting itself: the pooled leaves of the
-    PagedSlotState must price to exactly (arena_pages + 1 scratch) x
+    SlotState must price to exactly (arena_pages + 1 scratch) x
     page_bytes — i.e. page_bytes really is the marginal HBM cost of one
     admitted page, not a model."""
     rb = resident_measured[family]
-    pools = rb["paged_total_bytes"] \
-        - rb["paged_fixed_bytes_per_slot"] * rb["slots"]
+    pools = rb["total_bytes"] \
+        - rb["fixed_bytes_per_slot"] * rb["slots"]
     assert pools == (rb["arena_pages"] + 1) * rb["page_bytes"], rb
 # --------------------------------------------------------------------------
 #

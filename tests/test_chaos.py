@@ -20,6 +20,7 @@ import time
 import numpy as np
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.checkpoint import checkpointer as ckpt_lib
 from textsummarization_on_flink_tpu.config import HParams
@@ -686,7 +687,7 @@ class TestServeChaos:
             hps, vocab, batcher=None, params=state.params,
             decode_root=str(tmp_path / "cont_chaos"))
 
-        class SlowEngine:
+        class SlowEngine(NoArena):
             """Real slot engine with injected slow chunks: each step
             stalls long enough for the 2-deep queue to overflow."""
 
@@ -867,7 +868,7 @@ class TestFlightRecorderForensics:
             log_root=str(tmp_path), exp_name="s", flight_frames=4,
             faults=f"serve.dispatch:{self.FAULT_PROB}:{self.FAULT_SEED}:1")
 
-        class NeverFinishEngine:
+        class NeverFinishEngine(NoArena):
             """One resident request, resident forever: every tick is a
             busy tick, so fire() call N == busy tick N."""
 

@@ -86,11 +86,18 @@ def test_phase_serve(trained):
     for engine in ("microbatch", "continuous", "continuous_paged"):
         assert checked[engine]["requests"] == chip_smoke.SERVE_REQUESTS
         assert checked[engine]["compiles_after_warmup"] == 0
-    paged = checked["continuous_paged"]
-    assert paged["arena_pages_free_at_end"] == paged["arena_pages"]
     for engine in ("continuous", "continuous_paged"):
+        arena = checked[engine]
+        assert arena["arena_pages_free_at_end"] == arena["arena_pages"]
         eq = checked["engine_equality"][engine]
         assert eq["token_equal"] == eq["rows"]
+    # no option: every slot at full length, nothing waits; two articles'
+    # worth of pages: admissions wait, and the tokens are the same
+    assert checked["continuous"]["arena_pages"] == 4 * (
+        checked["continuous_paged"]["arena_pages"] // 2)
+    assert checked["continuous"]["arena_alloc_failures"] == 0
+    eq = checked["arena_equality"]
+    assert eq["token_equal"] == eq["rows"] == chip_smoke.SERVE_REQUESTS
 
 
 def test_phase_transformer(trained):

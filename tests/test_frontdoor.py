@@ -15,6 +15,7 @@ import threading
 import numpy as np
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.checkpoint.checkpointer import (
     Checkpointer,
@@ -297,7 +298,7 @@ class TestCoalescing:
         hps = tiny_hps(serve_coalesce=True, serve_mode="continuous",
                        serve_slots=2, serve_refill_chunk=2)
 
-        class _Eng:
+        class _Eng(NoArena):
             slots, chunk = 2, 2
 
             def pack(self, idx, ex):

@@ -9,10 +9,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _slots import Slots
 
 import __graft_entry__ as ge
-from textsummarization_on_flink_tpu.config import HParams, resolve_enc_block
-from textsummarization_on_flink_tpu.decode import beam_search
+from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import get_family
 from textsummarization_on_flink_tpu.ops import topk
 
@@ -245,53 +245,22 @@ def _decode_hps(family: str) -> HParams:
     return hps
 
 
-def _decode(hps, params, arrays, paged: bool, chunk: int = 3):
-    """Every article of `arrays` through a slot engine, one slot each:
-    [(tokens, avg_log_prob)]."""
+def _decode(hps, params, arrays, chunk: int = 3):
+    """Every article of `arrays` through the slot kernels, one slot
+    each: [(tokens, avg_log_prob)]."""
     B = arrays["enc_lens"].shape[0]
-    active = np.ones(B, bool)
-    if paged:
-        b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
-        pages = B * b_max
-        state = beam_search.init_slots_paged_jit(params, hps, arrays, pages)
-        table = np.arange(pages, dtype=np.int32).reshape(B, b_max)
-    else:
-        state = beam_search.init_slots_jit(params, hps, arrays)
+    eng = Slots(params, hps, arrays, B)
     for i in range(B):
-        pre = beam_search.prefill_jit(
-            params, hps, {k: v[i:i + 1] for k, v in arrays.items()})
-        if paged:
-            state = beam_search.pack_slot_paged_jit(params, hps, state, i,
-                                                    pre, table[i])
-        else:
-            state = beam_search.pack_slot_jit(params, hps, state, i, pre)
-    done = {}
-    for _ in range(hps.max_dec_steps):
-        if paged:
-            state, fin = beam_search.step_slots_paged_jit(
-                params, hps, state, active, table, chunk)
-        else:
-            state, fin = beam_search.step_slots_jit(params, hps, state,
-                                                    active, chunk)
-        for s in np.nonzero(np.asarray(fin))[0]:
-            out = (beam_search.unpack_slot_paged_jit(hps, state, int(s),
-                                                     table[s]) if paged else
-                   beam_search.unpack_slot_jit(hps, state, int(s)))
-            n = int(out.length)
-            done[int(s)] = (list(np.asarray(out.tokens)[:n]),
-                            float(out.avg_log_prob))
-            active[s] = False
-        if not active.any():
-            break
+        eng.pack(i, {k: v[i:i + 1] for k, v in arrays.items()})
+    done, _ = eng.drive(chunk=chunk, max_chunks=hps.max_dec_steps)
     assert sorted(done) == list(range(B))
-    return [done[i] for i in range(B)]
+    return [(list(np.asarray(done[i].tokens)[:int(done[i].length)]),
+             float(done[i].avg_log_prob)) for i in range(B)]
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 @pytest.mark.parametrize("family", ["pointer_generator", "transformer",
                                     "avg_attention"])
-def test_decodes_are_token_equal_with_the_candidates(family, paged,
-                                                     monkeypatch):
+def test_decodes_are_token_equal_with_the_candidates(family, monkeypatch):
     hps = _decode_hps(family)
     params = get_family(family).init_params(hps, hps.vocab_size,
                                             jax.random.PRNGKey(3))
@@ -304,13 +273,12 @@ def test_decodes_are_token_equal_with_the_candidates(family, paged,
     real = topk._mixture_candidates
     monkeypatch.setattr(topk, "_mixture_candidates",
                         lambda *a: calls.append(1) or real(*a))
-    got = _decode(hps, params, arrays, paged)
+    got = _decode(hps, params, arrays)
     assert calls  # the candidates ranked, not the row
     del calls[:]
     monkeypatch.setattr(topk, "_mixture_plan", lambda *a: "dense")
     # another static argument: nothing traced above is found again
-    want = _decode(hps.replace(exp_name="the-extended-row"), params, arrays,
-                   paged)
+    want = _decode(hps.replace(exp_name="the-extended-row"), params, arrays)
     assert not calls
     for (g_tok, g_lp), (w_tok, w_lp) in zip(got, want):
         assert g_tok == w_tok

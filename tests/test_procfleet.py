@@ -33,6 +33,7 @@ import time
 
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.obs import Registry
 from textsummarization_on_flink_tpu.obs import flightrec
@@ -418,7 +419,7 @@ class TestCrashLoop:
             def maybe_reload_checkpoint(self, last):
                 return last
 
-        class _OkEngine:
+        class _OkEngine(NoArena):
             """2-slot, 2-chunk-per-request sim engine (jax-free)."""
 
             def __init__(self):

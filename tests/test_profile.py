@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.data.vocab import Vocab
@@ -287,7 +288,7 @@ class _NullDecoder:
         return last
 
 
-class _SimEngine:
+class _SimEngine(NoArena):
     """SlotDecodeEngine protocol over the shared virtual clock: pack
     and step are the only operations that cost virtual time, and both
     run inside profiler phase brackets — so whatever fraction the

@@ -2,7 +2,7 @@
 "Scopes"): a device trace is split by them, so a refactor that drops one
 fails here and not in a trace nobody took.  Checked on the CPU in the
 lowered programs' name stacks — what the compiler turns into each
-instruction's ``op_name`` — for the slot step (dense and paged), prefill
+instruction's ``op_name`` — for the slot step, prefill
 and the one-step decode of each model family, and in the COMPILED text of
 the public accessor the benchmark reads the slot step through, which
 returns the executable the engine runs with no second compile.
@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _slots import Slots
 
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.data.vocab import Vocab
@@ -128,18 +129,10 @@ def test_prefill_carries_the_encoder_scope(hps):
 @pytest.mark.parametrize("hps", FAMILIES)
 def test_slot_steps_carry_every_scope(hps):
     _, params, arrays = _setup(hps)
-    slots = hps.batch_size
-    active = np.ones(slots, bool)
-    want = STEP[hps.model_family] | {"beam_select"}
-    dense = beam_search.init_slots_jit(params, hps, arrays)
-    assert want <= scopes_of(beam_search.step_slots_jit.lower(
-        params, hps, dense, active, 2))
-    pages = 6
-    paged = beam_search.init_slots_paged_jit(params, hps, arrays, pages)
-    table = np.full((slots, 3), pages, np.int32)
-    assert want | {"page_io"} <= scopes_of(
-        beam_search.step_slots_paged_jit.lower(
-            params, hps, paged, active, table, 2))
+    eng = Slots(params, hps, arrays, hps.batch_size)
+    assert STEP[hps.model_family] | {"beam_select", "page_io"} <= scopes_of(
+        beam_search.step_slots_jit.lower(
+            params, hps, eng.state, np.ones(eng.slots, bool), eng.table, 2))
 
 
 def test_compiled_slot_step_is_the_engines_own_executable(tmp_path):
@@ -168,7 +161,6 @@ def test_compiled_slot_step_is_the_engines_own_executable(tmp_path):
     with server:
         server.submit("the cat sat .", uuid="a").result(timeout=300)
         engine = server._cont.engine
-        assert engine.paged
         sizes = engine.cache_sizes()
         ledger = profile_lib.profiler_for(reg).compile_stats()
         compiled = server.compiled_slot_step()

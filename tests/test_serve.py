@@ -15,6 +15,7 @@ from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.config import HParams, parse_bucket_spec
 from textsummarization_on_flink_tpu.data.batching import SummaryExample
@@ -68,7 +69,7 @@ def make_request(hps, vocab, uuid="u0", article="the cat sat .", **kw):
     return ServeRequest(uuid, article, "", ex, **kw)
 
 
-class StubEngine:
+class StubEngine(NoArena):
     """SlotDecodeEngine-protocol stub (jax-free): per-request decode
     cost in CHUNKS derived from the example via `chunks_for`, optional
     per-chunk delay — scheduling semantics without a device."""

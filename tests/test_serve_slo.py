@@ -38,6 +38,7 @@ import random
 
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.data.vocab import Vocab
@@ -75,7 +76,7 @@ class _NullDecoder:
         return last
 
 
-class SimEngine:
+class SimEngine(NoArena):
     """SlotDecodeEngine protocol over virtual time: each step() advances
     the shared clock by chunk * step_cost and every active slot by
     `chunk` steps.  Records each request's RESOLVE time on the virtual
@@ -446,15 +447,15 @@ def test_disagg_runs_through_the_real_prefill_queue(slo, disagg_measured):
 # -- paged resident state (ISSUE 20) ---------------------------------------
 #
 # Memory-capped comparison under the same virtual cost model and bimodal
-# mix: a FIXED page budget (paged.workload.arena_pages) either
-# provisions dense worst-case slots (arena_pages // pages_per_long
-# residents — the pre-change rule: every slot permanently holds a
-# full-length article's state) or backs a block-granular arena serving
-# `paged_slots` slots admitted by FREE PAGES (the ISSUE 20 engine,
-# driven through the REAL ContinuousBatcher's arena admission).  The
-# committed claim: at the same memory, the paged run holds >=
-# resident_advantage_min x the dense mean resident count AND resolves
-# the load with LOWER p99 — capacity bought with paging, not latency
+# mix: a FIXED page budget (paged.workload.arena_pages) either holds
+# every slot at full length (arena_pages // pages_per_long slots — what
+# an engine with no arena option reserves, and no admission ever waits
+# for pages) or backs `paged_slots` slots admitted by FREE PAGES (the
+# ISSUE 20 tight arena, driven through the REAL ContinuousBatcher's
+# arena admission).  The committed claim: at the same memory, the
+# tight-arena run ("paged" in SERVE_SLO.json) holds >=
+# resident_advantage_min x the full-length run's ("dense") mean
+# resident count AND resolves the load with LOWER p99 — capacity bought with paging, not latency
 # bought with memory.  The arena is deliberately sized so the mix
 # cannot always fit (paged_slots x pages_per_long > arena_pages), so
 # the run also proves the backpressure contract end-to-end: allocation
@@ -464,15 +465,13 @@ def test_disagg_runs_through_the_real_prefill_queue(slo, disagg_measured):
 
 
 class PagedSimEngine(DisaggSimEngine):
-    """DisaggSimEngine + the ISSUE 20 arena surface (``paged``,
-    ``pages_needed``/``free_pages``/``arena_stats``): pack allocates
+    """DisaggSimEngine + the ISSUE 20 arena surface
+    (``pages_needed``/``free_pages``/``arena_stats``): pack allocates
     ceil(words / page_words) pages, harvest/release frees them.  pack
     raises the typed ArenaExhaustedError on shortfall — the batcher's
     proactive free-page admission should make that unreachable, and the
     SLO run asserts it stays that way (requeues happen at the admission
     check, never as a failed pack)."""
-
-    paged = True
 
     def __init__(self, wl):
         super().__init__(wl)
@@ -523,10 +522,11 @@ class PagedSimEngine(DisaggSimEngine):
 
 
 def _run_paged(slo, paged: bool):
-    """Drive the bimodal load at a fixed page budget: paged=False is
-    the dense memory-equivalent (arena_pages // pages_per_long worst-
-    case slots, no arena surface), paged=True the block-granular arena
-    at paged_slots.  Returns (vresolve, registry, sim, slots)."""
+    """Drive the bimodal load at a fixed page budget: paged=False
+    holds every slot at full length (arena_pages // pages_per_long
+    slots: the arena can never run out), paged=True serves paged_slots
+    slots from the same pages.  Returns (vresolve, registry, sim,
+    slots)."""
     wl = dict(slo["workload"])
     wl.update(slo["disaggregated"]["workload"])
     wl.update(slo["paged"]["workload"])
@@ -545,7 +545,7 @@ def _run_paged(slo, paged: bool):
         serve_prefill_depth=wl["prefill_depth"])
     arts = _articles(wl)
     with obs.use_registry(Registry()) as reg:
-        sim = (PagedSimEngine if paged else DisaggSimEngine)(wl)
+        sim = PagedSimEngine(wl)
         server = ServingServer(hps, vocab, decoder=_NullDecoder(),
                                engine=sim, registry=reg)
         futs = [server.submit(a, uuid=f"u{i}") for i, a in enumerate(arts)]
@@ -579,6 +579,8 @@ def paged_measured(slo):
         "peak_fill": paged_reg.histogram("serve/arena_fill").percentile(100),
         "pack_shortfalls": paged_sim.pack_shortfalls,
         "final_in_use": paged_sim.arena_stats()["in_use"],
+        "dense_alloc_failures":
+            dense_reg.counter("serve/arena_alloc_failures_total").value,
     }
 
 
@@ -629,6 +631,8 @@ def test_paged_backpressure_requeues_and_drains(slo, paged_measured):
     assert paged_measured["peak_fill"] >= \
         slo["paged"]["min_peak_arena_fill"]
     assert paged_measured["final_in_use"] == 0
+    # every slot at full length: no admission ever waits for pages
+    assert paged_measured["dense_alloc_failures"] == 0
 
 
 # -- elastic serving fleet (ISSUE 13) --------------------------------------
@@ -654,7 +658,7 @@ class _VClock:
         return self.ms / 1000.0
 
 
-class FleetSimEngine:
+class FleetSimEngine(NoArena):
     """SlotDecodeEngine-protocol sim over the SHARED fleet clock.
     ``speed`` < 1 models a degraded replica (the hedge scenario's
     straggler source): its residents advance speed * chunk steps per

@@ -35,6 +35,7 @@ import sys
 
 import pytest
 
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu import obs
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.data.vocab import Vocab
@@ -74,7 +75,7 @@ class _NullDecoder:
         return last
 
 
-class GateSimEngine:
+class GateSimEngine(NoArena):
     """SlotDecodeEngine protocol over the SHARED virtual clock: each
     step() advances it by chunk * step_cost_ms and every active slot by
     ``chunk`` steps, so a long article's harvest lands ``long_steps *

@@ -163,10 +163,10 @@ def test_the_detectors_see_the_extended_row():
         _builds_no_extended_row(text, hps)
 
 
-def test_paged_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
+def test_engine_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
     """The engine's own executable (SlotDecodeEngine.compiled_step(),
-    behind ServingServer.compiled_slot_step()): two slots over the
-    paged arena at pg_see2017's vocabulary and beam."""
+    behind ServingServer.compiled_slot_step()): two slots over an
+    8-page arena at pg_see2017's vocabulary and beam."""
     from textsummarization_on_flink_tpu.data.vocab import Vocab
     from textsummarization_on_flink_tpu.obs import Registry
     from textsummarization_on_flink_tpu.serve.server import ServingServer
@@ -186,7 +186,6 @@ def test_paged_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
                            registry=Registry())
     with server:
         server.submit("the cat sat .", uuid="a").result(timeout=600)
-        assert server._cont.engine.paged
         text = server.compiled_slot_step().as_text()
     _builds_no_extended_row(text, hps)
 
@@ -198,8 +197,8 @@ def test_transformer_slot_step_at_the_cells_vocabulary_orders_no_wide_row():
     B = hps.batch_size
     arrays = ge._decode_arrays(hps, np.random.RandomState(1), B)
     pages = 6
-    paged = beam_search.init_slots_paged_jit(params, hps, arrays, pages)
+    state = beam_search.init_slots_jit(params, hps, arrays, pages)
     table = np.full((B, 3), pages, np.int32)
-    text = beam_search.step_slots_paged_jit.lower(
-        params, hps, paged, np.ones(B, bool), table, 2).compile().as_text()
+    text = beam_search.step_slots_jit.lower(
+        params, hps, state, np.ones(B, bool), table, 2).compile().as_text()
     _builds_no_extended_row(text, hps)

@@ -184,28 +184,19 @@ def _orders_no_vocabulary_row(compiled, hps):
     assert not wide_dimensions(text, width)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 @pytest.mark.parametrize("family", ["pointer_generator", "transformer"])
-def test_slot_step_compiles_for_v5e(family, paged, one_chip):
+def test_slot_step_compiles_for_v5e(family, one_chip):
     hps = _family_hps(family)
     params = _params(hps, one_chip)
     arrays = _enc_arrays(hps, SLOTS, one_chip)
     active = jax.ShapeDtypeStruct((SLOTS,), np.bool_, sharding=one_chip)
-    if not paged:
-        state = _on(one_chip, jax.eval_shape(
-            lambda: beam_search.init_slots_jit(params, hps, arrays)))
-        compiled = beam_search.step_slots_jit.lower(
-            params, hps, state, active, CHUNK).compile()
-        _orders_no_vocabulary_row(compiled, hps)
-        return
     b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
     pages = SLOTS * b_max // 2
     state = _on(one_chip, jax.eval_shape(
-        lambda: beam_search.init_slots_paged_jit(params, hps, arrays,
-                                                 pages)))
+        lambda: beam_search.init_slots_jit(params, hps, arrays, pages)))
     table = jax.ShapeDtypeStruct((SLOTS, b_max), np.int32,
                                  sharding=one_chip)
-    compiled = beam_search.step_slots_paged_jit.lower(
+    compiled = beam_search.step_slots_jit.lower(
         params, hps, state, active, table, CHUNK).compile()
     _orders_no_vocabulary_row(compiled, hps)
 

@@ -275,23 +275,20 @@ class HParams:
     # already-encoded article instead of paying prefill latency inline.
     # 0 = prefill exactly the free slots.
     serve_prefill_depth: int = 2
-    # ---- paged resident state (PERF.md "Paged resident state"; ISSUE 20) ----
-    # Arena page count for the block-granular slot arena: the continuous
-    # engine's enc-axis resident leaves (encoder view / cross-attention
-    # KV cache, extended-vocab ids, attention history) become pools of
-    # decode_enc_block-row pages shared by all slots, and each admission
-    # allocates only ceil(true_len / block) pages — short requests stop
-    # reserving long-request memory, so the same HBM holds 2-4x the
-    # residents at the bimodal mix.  0 = paging off (dense SlotState)
-    # unless serve_arena_mb sets a byte budget.  Must be at least
-    # ceil(max_enc_steps / decode_enc_block) (one full-length article
-    # must fit) — enforced by resolve_arena_pages.
+    # ---- the slot arena (SERVING.md "Paged resident state"; ISSUE 20) ----
+    # Page count of the arena the continuous engine's enc-axis resident
+    # leaves (encoder view / cross-attention KV cache, extended-vocab
+    # ids, attention history) are pooled in: decode_enc_block-row pages
+    # shared by all slots, ceil(true_len / block) of them an admission.
+    # 0 = sized to hold every slot at full length, unless serve_arena_mb
+    # sets a byte budget; fewer pages admit by free pages and let short
+    # articles stop reserving long-article memory.  At least
+    # ceil(max_enc_steps / decode_enc_block): one full-length article
+    # must fit (config.resolve_arena_pages, the one resolver).
     serve_arena_pages: int = 0
-    # Arena sizing by HBM byte budget instead of a page count: the page
-    # count becomes floor(serve_arena_mb MiB / page_bytes), where
-    # page_bytes spans one page across ALL pools
-    # (beam_search.paged_page_bytes).  Ignored when serve_arena_pages is
-    # set explicitly.  0 = no byte budget.
+    # The same arena as an HBM byte budget: floor(serve_arena_mb MiB /
+    # beam_search.paged_page_bytes) pages.  Ignored when
+    # serve_arena_pages is set.  0 = no byte budget.
     serve_arena_mb: float = 0.0
     # ---- speculative decode tier (SERVING.md "Quality tiers"; ISSUE 10) ----
     # Draft tokens proposed per verify cycle: the draft model (AAN
@@ -726,8 +723,8 @@ class HParams:
                 f"{self.serve_prefill_depth}")
         if self.serve_arena_pages < 0:
             raise ValueError(
-                f"serve_arena_pages must be >= 0 (0 = paging off), got "
-                f"{self.serve_arena_pages}")
+                f"serve_arena_pages must be >= 0 (0 = every slot at full "
+                f"length), got {self.serve_arena_pages}")
         if self.serve_arena_mb < 0:
             raise ValueError(
                 f"serve_arena_mb must be >= 0 (0 = no byte budget), got "
@@ -1020,19 +1017,20 @@ def resolve_refill_chunk(hps: "HParams") -> int:
     return max(1, min(int(chunk), hps.max_dec_steps))
 
 
-def resolve_arena_pages(hps: "HParams",
+def resolve_arena_pages(hps: "HParams", slots: int,
                         page_bytes: "Optional[int]" = None) -> int:
-    """Effective page count of the paged-resident-state arena (ISSUE
-    20): ``serve_arena_pages`` when set explicitly, else the page count
-    a ``serve_arena_mb`` HBM byte budget buys (page_bytes — one page's
+    """Page count of the slot arena of an engine with ``slots`` slots
+    (ISSUE 20): ``serve_arena_pages`` when set, else the pages a
+    ``serve_arena_mb`` HBM byte budget buys (page_bytes — one page's
     span across all pools, beam_search.paged_page_bytes — is required
-    for budget mode), else 0 = paging off.  The ONE resolver, shared by
-    decode/decoder.SlotDecodeEngine, __graft_entry__'s cost model, and
-    bench.py's fingerprint, so the measured arena is exactly the served
-    one.  A non-zero result is validated to hold at least one
-    full-length article (ceil(max_enc_steps / decode_enc_block) pages)
-    — anything smaller would deadlock the first max-length admission
-    rather than backpressure it."""
+    for budget mode), else ``slots x ceil(max_enc_steps / block)``: the
+    arena that holds every slot at full length, where no admission can
+    be blocked on pages.  The ONE resolver, shared by
+    decode/decoder.SlotDecodeEngine and __graft_entry__'s cost model,
+    so the priced arena is exactly the served one.  The result holds at
+    least one full-length article (ceil(max_enc_steps /
+    decode_enc_block) pages) — anything smaller would deadlock the
+    first max-length admission rather than backpressure it."""
     b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
     if hps.serve_arena_pages > 0:
         pages = int(hps.serve_arena_pages)
@@ -1043,7 +1041,9 @@ def resolve_arena_pages(hps: "HParams",
                 "(beam_search.paged_page_bytes(params, hps))")
         pages = int(hps.serve_arena_mb * (1 << 20) // page_bytes)
     else:
-        return 0
+        if slots < 1:
+            raise ValueError(f"slots must be >= 1, got {slots}")
+        return int(slots) * b_max
     if pages < b_max:
         raise ValueError(
             f"arena of {pages} page(s) cannot hold one full-length "

@@ -4,7 +4,7 @@ The continuous engine's resident HBM used to be provisioned per SLOT at
 the worst-case article shape — PR 11's length masks cut compute, not
 memory.  This module is the HOST half of the fix: a free-list allocator
 over a fixed pool of ``decode_enc_block``-row pages.  The device half
-(decode/beam_search.py's ``*_paged_jit`` kernels) holds the pooled
+(decode/beam_search.py's slot kernels) holds the pooled
 encoder-axis leaves; the engine (decode/decoder.SlotDecodeEngine) calls
 ``alloc`` at pack time with the admitted article's true page count and
 ``free`` at harvest/release, and mirrors the allocation into the

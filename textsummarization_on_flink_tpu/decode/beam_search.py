@@ -121,9 +121,9 @@ def _init_beam_state(hps: HParams, T_enc: int, dec_state: Any,
     family's beam adapter; everything else is shape-only).
 
     attn_cols narrows the attention history to that many columns — the
-    paged slot path (ISSUE 20) keeps a single scratch column per slot
-    and scatters each step's row into the shared page pool instead of
-    carrying the full [K, T+1, T_enc] buffer per resident."""
+    slot path keeps a single scratch column per slot and scatters each
+    step's row into the shared page pool instead of carrying the full
+    [K, T+1, T_enc] buffer per resident."""
     K = hps.beam_size
     T = hps.max_dec_steps
     return _BeamState(
@@ -159,13 +159,13 @@ def _beam_cond(hps: HParams):
 def _make_beam_body(params, hps: HParams, step_fn, enc_one, enc_mask,
                     ext_ids, attn_col_fn=None):
     """One decode step for one article, closed over its encoder view —
-    shared verbatim by the batch search (_search_one) and the slot loops
-    (step_slots_jit / step_slots_paged_jit), so the paths cannot drift.
+    shared verbatim by the batch search (_search_one) and the slot loop
+    (step_slots_jit), so the paths cannot drift.
 
     attn_col_fn(t) overrides the attention-history write column — the
-    paged path (ISSUE 20) writes every step into its width-1 scratch
-    column (index 0) and scatters that row into the page pool OUTSIDE
-    this body; an explicit override, never out-of-bounds index
+    slot loop writes every step into its width-1 scratch column
+    (index 0) and scatters that row into the page pool OUTSIDE this
+    body; an explicit override, never out-of-bounds index
     semantics, keeps the write well-defined."""
     K = hps.beam_size
     V = hps.vocab_size
@@ -413,18 +413,18 @@ def run_beam_search_jit(params, hps: HParams, arrays: Dict[str, Array],
 
 
 # --------------------------------------------------------------------------
-# Slot-state search: the continuous-batching kernel set (ISSUE 6)
-# + prefill/decode disaggregation (ISSUE 11)
+# Slot-state search: the continuous-batching kernel set (ISSUE 6),
+# prefill/decode disaggregation (ISSUE 11), paged resident state (ISSUE 20)
 # --------------------------------------------------------------------------
 #
 # The batch search above is all-or-nothing: one dispatch decodes B
 # articles and returns when the SLOWEST finishes — the straggler barrier
 # FastSeq (PAPERS.md) removes.  The slot API splits that dispatch into
-# chunk-granular pieces over a persistent [slots, beam, ...] state so a
-# host scheduler (serve/batcher.ContinuousBatcher) can retire finished
-# articles and refill their slots between chunks.
+# chunk-granular pieces over a persistent state for `slots` resident
+# articles, so a host scheduler (serve/batcher.ContinuousBatcher) can
+# retire finished articles and refill their slots between chunks.
 #
-# The request lifecycle is DISAGGREGATED into two stages (ISSUE 11):
+# The request lifecycle has two stages:
 #
 #   PREFILL — encoder + cross-attention cache build, at the article's
 #   micro-batcher bucket shape (config.parse_bucket_spec): one
@@ -441,19 +441,50 @@ def run_beam_search_jit(params, hps: HParams, arrays: Dict[str, Array],
 #   sequence's shape dictate the batch's cost") applied to the resident
 #   set, at block granularity.
 #
+# The resident state is PAGED — the vLLM/PagedAttention block-table idea
+# applied to this engine's T_enc axis, so a short article reserves its
+# own pages and not a full-width row:
+#
+#   * every enc-axis leaf of the resident state — the family encoder
+#     view (for tf/aan that IS the cross-attention KV cache), the
+#     extended-vocab ids, and the [K, T+1, T_enc] attention history —
+#     is a POOL of `resolve_enc_block`-row pages shared by all slots,
+#     sized by the arena (decode/arena.PageArena;
+#     config.resolve_arena_pages: by default slots x ceil(max_enc_steps
+#     / block), every slot at full length);
+#   * each slot's pages are named by a per-slot PAGE-TABLE row — int32
+#     DATA passed as a traced argument, never shape;
+#   * page index P (== arena capacity) is the SCRATCH page: every
+#     unused table entry points at it, inactive slots are routed to it
+#     inside the kernels, and its contents are garbage by contract —
+#     exactly the dead-column story the byte-diet histories already
+#     tell (see _SELECT_FIELDS);
+#   * dec_state stays DENSE on purpose: its big leaves (the tf
+#     self-attention KV cache) run over the DECODE axis, which the
+#     bimodal mix does not vary — paging them buys nothing at this
+#     workload while doubling the scatter traffic.  pg's [K, T_enc]
+#     coverage is enc-axis but second-order (one f32 row vs the 2H-wide
+#     encoder states); it rides dense too.
+#
+# Lifecycle (host side in decode/decoder.SlotDecodeEngine):
+#
+#     pages = resolve_arena_pages(hps, slots,
+#                                 paged_page_bytes(params, hps))
 #     pre   = prefill_jit(params, hps, bucket_arrays)       # per admit
-#     state = init_slots_jit(params, hps, zero_arrays)      # once
-#     state = pack_slot_jit(params, hps, state, i, pre)     # admit
-#     state, finished = step_slots_jit(params, hps, state, active, chunk)
-#     out = unpack_slot_jit(hps, state, i)                  # retire
+#     state = init_slots_jit(params, hps, zero_arrays, pages)    # once
+#     row   = arena.alloc(ceil(len/block)) padded with scratch   # admit
+#     state = pack_slot_jit(params, hps, state, i, pre, row)
+#     state, finished = step_slots_jit(params, hps, state, active,
+#                                      table, chunk)  # table: [slots, B]
+#     out   = unpack_slot_jit(hps, state, i, row); arena.free(row)
 #
 # Contracts:
 #   * every DECODE kernel is shape-stable — slot index, active mask,
-#     and valid lengths are TRACED arguments, so after the four warmup
-#     compiles NO request, slot choice, occupancy pattern, or article
-#     LENGTH pattern triggers a recompile; prefill_jit adds exactly one
-#     compile per serve bucket (the warm set is 4 + len(buckets),
-#     pinned by test);
+#     valid lengths and page-table contents are TRACED arguments, so
+#     after the four warmup compiles NO request, slot choice, occupancy
+#     pattern, article LENGTH pattern or allocation pattern triggers a
+#     recompile; prefill_jit adds exactly one compile per serve bucket
+#     (the warm set is 4 + len(buckets), pinned by test);
 #   * per-slot activity masks: an inactive slot's ORDER-SENSITIVE state
 #     (_SELECT_FIELDS: step counter, live beam, result pool) is carried
 #     through step_slots_jit unchanged — the same masked-update select
@@ -461,39 +492,25 @@ def run_beam_search_jit(params, hps: HParams, arrays: Dict[str, Array],
 #     token-exact with _search_one on the same inputs.  The history
 #     buffers and dec_state are NOT select-protected (the decode byte
 #     diet): a masked iteration writes garbage into them, confined to
-#     dead regions — the frozen-t / scratch column and a never-again-read
-#     dec_state — so an inactive slot's state is "unchanged" only where
-#     unpack_slot_jit reads, and a slot's leaves are trustworthy ONLY
-#     between pack and the step that finishes it (pack_slot_jit fully
-#     overwrites on reuse; do not snapshot or inspect a slot's raw state
-#     outside that window);
+#     dead regions — the frozen-t column of the slot's own pages, the
+#     scratch page and a never-again-read dec_state — so an inactive
+#     slot's state is "unchanged" only where unpack_slot_jit reads, and
+#     a slot's leaves are trustworthy ONLY between pack and the step
+#     that finishes it (pack_slot_jit fully overwrites on reuse; do not
+#     snapshot or inspect a slot's raw state outside that window);
+#   * token-exactness is by construction, not tolerance: gathers through
+#     the table reconstruct each ACTIVE slot's exact full-width view
+#     (garbage beyond a slot's valid pages sits behind the valid-length
+#     masks, whose exact-zero softmax contributes 0.0), and the per-step
+#     attention row is scattered into the pool at the slot's own (page,
+#     t) coordinates — the parity suite pins all three families bitwise
+#     against the batch search at page boundaries;
 #   * pack/unpack happen ONLY at chunk boundaries — the host never
 #     observes (or mutates) mid-chunk state.
 #
 # The per-article search itself is the SAME _make_beam_body /
 # _init_beam_state / _finalize_beam code the batch path runs; the slot
 # layer adds routing, not semantics.
-
-
-class SlotState(NamedTuple):
-    """Persistent decode state for `slots` resident articles.
-
-    beam leaves lead with [slots, ...] (each slot an independent
-    _BeamState); enc_view is the family's per-article encoder pytree
-    stacked over slots; enc_mask/ext_ids are [slots, T_enc].  All
-    shapes static: T_enc is fixed for the state's lifetime (one
-    resident shape is what makes slot recycling shape-stable) — but a
-    resident's COST is not: ``enc_valid_len`` carries each article's
-    true length, the prefill stage fills only the valid prefix (zeros
-    past it), and step_slots_jit bounds the cross-attention block chain
-    by the longest active valid length (ISSUE 11).
-    """
-
-    beam: Any  # _BeamState with [slots, ...] leaves
-    enc_view: Any  # family encoder view, [slots, ...] leaves
-    enc_mask: Array  # [slots, T_enc]
-    ext_ids: Array  # [slots, T_enc]
-    enc_valid_len: Array  # [slots] int32 true (pre-padding) article length
 
 
 class PrefillState(NamedTuple):
@@ -522,24 +539,6 @@ def _init_slot_beams(params, hps: HParams, enc_view, enc_mask,
                                 attn_cols=attn_cols)
 
     return jax.vmap(one)(enc_view, enc_mask)
-
-
-@functools.partial(jax.jit, static_argnames=("hps",))
-def init_slots_jit(params, hps: HParams,
-                   arrays: Dict[str, Array]) -> SlotState:
-    """The all-empty persistent state from a [slots, T_enc] arrays dict
-    (zeros are fine: inactive slots are never stepped unmasked and are
-    fully overwritten by pack_slot_jit before first use)."""
-    family = get_family(hps.model_family)
-    enc_view = family.beam_encode(params, hps, arrays)
-    slots = arrays["enc_padding_mask"].shape[0]
-    return SlotState(
-        beam=_init_slot_beams(params, hps, enc_view,
-                              arrays["enc_padding_mask"]),
-        enc_view=enc_view,
-        enc_mask=arrays["enc_padding_mask"],
-        ext_ids=arrays["enc_batch_extend_vocab"],
-        enc_valid_len=jnp.zeros((slots,), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("hps",))
@@ -576,156 +575,17 @@ def prefill_jit(params, hps: HParams,
         enc_valid_len=arrays["enc_lens"].astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("hps",))
-def pack_slot_jit(params, hps: HParams, state: SlotState, idx,
-                  pre: PrefillState) -> SlotState:
-    """Admit ONE PREFILLED article into slot `idx` — the
-    pack-with-length-mask (ISSUE 11): scatter the padded encoder view,
-    initialize the slot's search, and stamp the resident's true valid
-    length (what the decode stage's block chain and attention masks key
-    on).  `idx` is traced — one compile serves every slot, and because
-    prefill already normalized every bucket to the resident width, one
-    compile serves every bucket too."""
-    beam1 = _init_slot_beams(params, hps, pre.enc_view, pre.enc_mask)
+class SlotState(NamedTuple):
+    """Persistent decode state for `slots` resident articles.
 
-    def write(dst, src):
-        return dst.at[idx].set(src[0])
-
-    return SlotState(
-        beam=jax.tree_util.tree_map(write, state.beam, beam1),
-        enc_view=jax.tree_util.tree_map(write, state.enc_view,
-                                        pre.enc_view),
-        enc_mask=state.enc_mask.at[idx].set(pre.enc_mask[0]),
-        ext_ids=state.ext_ids.at[idx].set(pre.ext_ids[0]),
-        enc_valid_len=state.enc_valid_len.at[idx].set(
-            pre.enc_valid_len[0]))
-
-
-@functools.partial(jax.jit, static_argnames=("hps", "chunk"))
-def step_slots_jit(params, hps: HParams, state: SlotState, active,
-                   chunk: int):
-    """Advance every ACTIVE slot by up to `chunk` masked decode steps.
-
-    active: [slots] bool.  Returns (state', finished) where finished[i]
-    marks an active slot whose search is done (horizon reached or beam
-    full of results) — the host retires it via unpack_slot_jit and may
-    refill.  Inactive slots run the same chunk on garbage state (the
-    cost of shape stability): every ORDER-SENSITIVE update is discarded
-    by the _SELECT_FIELDS mask — a NaN in a dead lane never escapes
-    into the selected leaves — while the dead lane's history columns
-    and dec_state DO take garbage writes, all confined to regions
-    unpack_slot_jit never reads and fully overwritten by the next
-    pack_slot_jit (see the slot-contract comment above).
-
-    Length-masked decode (ISSUE 11): the chunk's cross-attention block
-    chain is bounded by ``nb`` = ceil(max active enc_valid_len /
-    resolve_enc_block) — a TRACED scalar, uniform across the vmapped
-    slots, so the conditional chain survives the vmap as real branches
-    and one compile serves every length pattern.  Work executed per
-    chunk scales with the longest ACTIVE resident's true article
-    length; shorter co-residents' extra blocks are masked to the same
-    energy floor the dense path gives padding, so trajectories stay
-    token-exact with the batch search."""
-    family = get_family(hps.model_family)
-    _, step_fn = family.beam_adapter_masked(hps)
-    cond = _beam_cond(hps)
-    from textsummarization_on_flink_tpu.config import resolve_enc_block
-
-    block = resolve_enc_block(hps)
-    valid = jnp.where(active, state.enc_valid_len,
-                      jnp.zeros_like(state.enc_valid_len))
-    nb = (jnp.max(valid) + block - 1) // block  # scalar, traced
-
-    def one(beam, act, enc_one, mask, ext):
-        def step_nb(p, e, m, x, t, latest, s):
-            return step_fn(p, e, m, x, nb, t, latest, s)
-
-        body = _make_beam_body(params, hps, step_nb, enc_one, mask, ext)
-
-        def masked_cond(s):
-            return jnp.logical_and(act, cond(s))
-
-        scan_body = _masked_scan_body(masked_cond, body)
-        s, _ = jax.lax.scan(scan_body, beam, None, length=chunk)
-        return s, jnp.logical_and(act, jnp.logical_not(cond(s)))
-
-    beam, finished = jax.vmap(one)(state.beam, active, state.enc_view,
-                                   state.enc_mask, state.ext_ids)
-    return state._replace(beam=beam), finished
-
-
-@functools.partial(jax.jit, static_argnames=("hps",))
-def unpack_slot_jit(hps: HParams, state: SlotState, idx) -> BeamSearchOutput:
-    """The finished hypothesis for slot `idx` (no batch axis), ranked
-    exactly like the batch path's tail.  `idx` is traced — one compile.
-    The slot is NOT cleared here; the host's activity mask retires it
-    and the next pack overwrites the state."""
-    s = jax.tree_util.tree_map(lambda x: x[idx], state.beam)
-    return _finalize_beam(hps, s, state.enc_mask.shape[1])
-
-
-# --------------------------------------------------------------------------
-# Paged resident state: the block-granular slot arena (ISSUE 20)
-# --------------------------------------------------------------------------
-#
-# PR 11's length masks cut the slot engine's COMPUTE to true article
-# lengths, but every resident still owned full-width encoder-axis
-# buffers: slot COUNT stayed provisioned for the worst-case article.
-# The paged kernel set below drops the per-slot reservation to page
-# granularity — the vLLM/PagedAttention block-table idea applied to this
-# engine's T_enc axis:
-#
-#   * every enc-axis leaf of the resident state — the family encoder
-#     view (for tf/aan that IS the cross-attention KV cache), the
-#     extended-vocab ids, and the [K, T+1, T_enc] attention history —
-#     becomes a POOL of `resolve_enc_block`-row pages shared by all
-#     slots, sized by the arena (decode/arena.PageArena) instead of
-#     slots x max_enc_steps;
-#   * each slot's pages are named by a per-slot PAGE-TABLE row — int32
-#     DATA passed as a traced argument, never shape: page-table
-#     contents, occupancy, and allocation pattern can never recompile
-#     (the PR 6/11 discipline), and the warm set stays 4 decode
-#     compiles + one prefill per bucket;
-#   * page index P (== arena capacity) is the SCRATCH page: every
-#     unused table entry points at it, inactive slots are routed to it
-#     inside the kernels, and its contents are garbage by contract —
-#     exactly the dead-column story the byte-diet histories already
-#     tell (see _SELECT_FIELDS);
-#   * dec_state stays DENSE on purpose: its big leaves (the tf
-#     self-attention KV cache) run over the DECODE axis, which the
-#     bimodal mix does not vary — paging them buys nothing at this
-#     workload while doubling the scatter traffic.  pg's [K, T_enc]
-#     coverage is enc-axis but second-order (one f32 row vs the 2H-wide
-#     encoder states); it rides dense too.
-#
-# Token-exactness is by construction, not tolerance: gathers through
-# the table reconstruct each ACTIVE slot's exact dense view (garbage
-# beyond a slot's valid pages sits behind the PR 11 valid-length masks,
-# whose exact-zero softmax contributes 0.0), and the per-step attention
-# row is scattered into the pool at the same (slot, t) coordinates the
-# dense path writes — the parity suite pins all three families bitwise
-# at page boundaries.
-#
-# Lifecycle (host side in decode/decoder.SlotDecodeEngine):
-#   pages = resolve_arena_pages(hps, paged_page_bytes(params, hps))
-#   state = init_slots_paged_jit(params, hps, zeros, pages=pages)
-#   row   = arena.alloc(ceil(len/block)) padded with scratch    # admit
-#   state = pack_slot_paged_jit(params, hps, state, i, pre, row)
-#   state, fin = step_slots_paged_jit(params, hps, state, active,
-#                                     table, chunk)   # table: [slots, B]
-#   out   = unpack_slot_paged_jit(hps, state, i, row); arena.free(row)
-
-
-class PagedSlotState(NamedTuple):
-    """Persistent decode state for the paged engine (ISSUE 20).
-
-    Relative to SlotState: the enc-axis leaves live in shared page
-    pools with one extra SCRATCH page at index [-1]; ``enc_rest`` keeps
-    the family enc_view's TREE STRUCTURE with each pooled leaf squeezed
-    to width 0 on its time axis (zero bytes, but the treedef and the
-    non-time leaves — e.g. pointer-generator's dec_in_state — survive
-    in place, so the kernels can rebuild the exact dense view by
-    re-probing `pad_enc_view`, the same single source of truth
+    Slot-leading leaves (beam, enc_rest, enc_mask, enc_valid_len) carry
+    one row a slot; the enc-axis leaves live in page pools shared by
+    all slots, with one extra SCRATCH page at index [-1].  ``enc_rest``
+    keeps the family enc_view's TREE STRUCTURE with each pooled leaf
+    squeezed to width 0 on its time axis (zero bytes, but the treedef
+    and the non-time leaves — e.g. pointer-generator's dec_in_state —
+    survive in place, so the kernels can rebuild the exact full-width
+    view by re-probing `pad_enc_view`, the same single source of truth
     prefill's padding uses).  The beam's attention history is a width-1
     scratch column; each step's row is scattered into ``attn_pool`` at
     the slot's pages.  ``enc_mask``/``enc_valid_len`` stay dense —
@@ -831,12 +691,14 @@ def paged_page_bytes(params, hps: HParams) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("hps", "pages"))
-def init_slots_paged_jit(params, hps: HParams, arrays: Dict[str, Array],
-                         pages: int) -> PagedSlotState:
-    """The all-empty paged state: pools sized by the arena (`pages` is
-    the ONE static knob — fixed for the engine's lifetime, so this
-    stays one compile), everything else zeros.  Pool row `pages` is the
-    scratch page."""
+def init_slots_jit(params, hps: HParams, arrays: Dict[str, Array],
+                   pages: int) -> SlotState:
+    """The all-empty persistent state from a [slots, T_enc] arrays dict
+    (zeros are fine: inactive slots are never stepped unmasked and are
+    fully overwritten by pack_slot_jit before first use): pools sized
+    by the arena (`pages` is the ONE static knob — fixed for the
+    engine's lifetime, so this stays one compile), everything else
+    zeros.  Pool row `pages` is the scratch page."""
     family = get_family(hps.model_family)
     enc_view = family.beam_encode(params, hps, arrays)
     slots = arrays["enc_padding_mask"].shape[0]
@@ -853,7 +715,7 @@ def init_slots_paged_jit(params, hps: HParams, arrays: Dict[str, Array],
         pools.append(jnp.zeros((pages + 1, block) + tail, leaf.dtype))
         rest.append(jax.lax.slice_in_dim(leaf, 0, 0, axis=ta))
     K, T = hps.beam_size, hps.max_dec_steps
-    return PagedSlotState(
+    return SlotState(
         beam=_init_slot_beams(params, hps, enc_view,
                               arrays["enc_padding_mask"], attn_cols=1),
         enc_rest=jax.tree_util.tree_unflatten(treedef, rest),
@@ -865,8 +727,8 @@ def init_slots_paged_jit(params, hps: HParams, arrays: Dict[str, Array],
 
 
 @functools.partial(jax.jit, static_argnames=("hps",))
-def pack_slot_paged_jit(params, hps: HParams, state: PagedSlotState, idx,
-                        pre: PrefillState, row) -> PagedSlotState:
+def pack_slot_jit(params, hps: HParams, state: SlotState, idx,
+                  pre: PrefillState, row) -> SlotState:
     """Admit ONE prefilled article into slot `idx` with page-table row
     `row` ([b_max] int32 — the slot's freshly allocated pages, padded
     with the scratch id).  `idx` and `row` are both traced: one compile
@@ -901,7 +763,7 @@ def pack_slot_paged_jit(params, hps: HParams, state: PagedSlotState, idx,
     pad = b_max * block - ext.shape[0]
     if pad:
         ext = jnp.pad(ext, (0, pad))
-    return PagedSlotState(
+    return SlotState(
         beam=jax.tree_util.tree_map(write, state.beam, beam1),
         enc_rest=jax.tree_util.tree_unflatten(treedef, rest_new),
         enc_pages=tuple(pool_new),
@@ -913,26 +775,40 @@ def pack_slot_paged_jit(params, hps: HParams, state: PagedSlotState, idx,
 
 
 @functools.partial(jax.jit, static_argnames=("hps", "chunk"))
-def step_slots_paged_jit(params, hps: HParams, state: PagedSlotState,
-                         active, table, chunk: int):
+def step_slots_jit(params, hps: HParams, state: SlotState,
+                   active, table, chunk: int):
     """Advance every ACTIVE slot by up to `chunk` masked decode steps,
     gathering encoder state through the page table (`table`: [slots,
     b_max] int32, traced DATA — occupancy and allocation pattern can
-    never recompile).
+    never recompile).  Returns (state', finished) where finished[i]
+    marks an active slot whose search is done (horizon reached or beam
+    full of results) — the host retires it via unpack_slot_jit and may
+    refill.  Inactive slots run the same chunk on garbage state (the
+    cost of shape stability): every ORDER-SENSITIVE update is discarded
+    by the _SELECT_FIELDS mask — a NaN in a dead lane never escapes
+    into the selected leaves (see the slot-contract comment above).
 
-    Structure: the dense per-slot encoder views are gathered ONCE per
-    chunk (loop-invariant — the gather cost amortizes over the chunk's
-    steps), then a top-level scan runs the chunk with a vmapped
-    per-slot masked step inside — scan-of-vmap instead of the dense
-    kernel's vmap-of-scan, which commutes (slots are independent; nb is
-    computed once outside either way) but exposes each step's
-    attention row for ONE scatter into the shared pool at (slot pages,
-    pre-step t).  Inactive slots' table rows are routed to the scratch
+    Length-masked decode (ISSUE 11): the chunk's cross-attention block
+    chain is bounded by ``nb`` = ceil(max active enc_valid_len /
+    resolve_enc_block) — a TRACED scalar, uniform across the vmapped
+    slots, so the conditional chain survives the vmap as real branches
+    and one compile serves every length pattern.  Work executed per
+    chunk scales with the longest ACTIVE resident's true article
+    length; shorter co-residents' extra blocks are masked to the same
+    energy floor the batch search gives padding, so trajectories stay
+    token-exact with it.
+
+    Structure: the full-width per-slot encoder views are gathered ONCE
+    per chunk (loop-invariant — the gather cost amortizes over the
+    chunk's steps), then a top-level scan runs the chunk with a vmapped
+    per-slot masked step inside, which exposes each step's attention
+    row for ONE scatter into the shared pool at (slot pages, pre-step
+    t).  Inactive slots' table rows are routed to the scratch
     page before either the gather or the scatter, so a harvested slot's
     stale table can never read from — or write garbage into — pages the
     arena has re-issued to a new tenant.  Masked (post-finish) lanes
     scatter garbage at their frozen t — a dead column of their OWN
-    pages, exactly the column the dense kernel lets them dirty."""
+    pages."""
     family = get_family(hps.model_family)
     _, step_fn = family.beam_adapter_masked(hps)
     cond = _beam_cond(hps)
@@ -1005,15 +881,17 @@ def step_slots_paged_jit(params, hps: HParams, state: PagedSlotState,
 
 
 @functools.partial(jax.jit, static_argnames=("hps",))
-def unpack_slot_paged_jit(hps: HParams, state: PagedSlotState, idx,
-                          row) -> BeamSearchOutput:
+def unpack_slot_jit(hps: HParams, state: SlotState, idx,
+                    row) -> BeamSearchOutput:
     """The finished hypothesis for slot `idx`: gather the slot's
-    attention pages back into the dense [K, T+1, T_enc] history the
-    finalize backtrack expects (`row` is the slot's CURRENT table row —
-    the host frees the pages only after this call), zero columns past
-    the valid length (where the dense path's masked softmax wrote exact
-    zeros but a recycled page holds a previous tenant's rows), and run
-    the SAME _finalize_beam as every other path."""
+    attention pages back into the [K, T+1, T_enc] history the finalize
+    backtrack expects (`row` is the slot's CURRENT table row — the host
+    frees the pages only after this call), zero columns past the valid
+    length (where the batch search's masked softmax writes exact zeros
+    but a recycled page holds a previous tenant's rows), and run the
+    SAME _finalize_beam as every other path.  `idx` and `row` are
+    traced — one compile.  The slot is NOT cleared here; the host's
+    activity mask retires it and the next pack overwrites the state."""
     K, T = hps.beam_size, hps.max_dec_steps
     _, b_max, t_pad = _pool_spec(hps)
     T_enc = state.enc_mask.shape[1]

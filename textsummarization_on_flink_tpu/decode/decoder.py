@@ -671,18 +671,20 @@ class SlotDecodeEngine:
     disaggregated into a bucketed prefill stage and a length-masked
     decode stage (ISSUE 11).
 
-    Owns the [slots, beam, ...] resident state and the per-slot activity
-    mask; the scheduler above it (serve/batcher.ContinuousBatcher) owns
-    request bookkeeping.  Single-threaded by design — the one
+    Owns the resident state (per-slot leaves plus the page pools its
+    PageArena hands out, ISSUE 20), the page table and the per-slot
+    activity mask; the scheduler above it
+    (serve/batcher.ContinuousBatcher) owns request bookkeeping.
+    Single-threaded by design — the one
     continuous-dispatch thread calls prefill/pack/step/unpack; the ONLY
     chunk boundary host sync is reading the `finished` mask in step().
 
     Shape discipline: the RESIDENT state keeps one shape
     (``hps.max_enc_steps`` wide — that is what makes slot recycling
     shape-stable), so the decode kernels warm exactly four compiles
-    (init/pack/step/unpack) with slot index, occupancy, and valid
-    lengths all traced.  The COST no longer follows the shape: prefill
-    runs the encoder at the article's micro-batcher bucket
+    (init/pack/step/unpack) with slot index, occupancy, valid lengths
+    and page-table rows all traced.  The COST no longer follows the
+    shape: prefill runs the encoder at the article's micro-batcher bucket
     (``serve_buckets`` — one prefill compile per bucket), and each
     decode chunk's cross-attention is bounded by the longest active
     resident's true length (beam_search.step_slots_jit).  Compile
@@ -695,7 +697,8 @@ class SlotDecodeEngine:
     (documented in SERVING.md; same shapes, so no recompile).
 
     Multi-chip serving (ISSUE 8): on a dp x tp mesh the resident
-    [slots, ...] state shards over dp and params tp-shard, both against
+    [slots, ...] leaves shard over dp, the page pools replicate, and
+    params tp-shard, all against
     the sharding registry (parallel/sharding.py) — the same layout
     story as training and the micro-batch sharded search.  Slots must
     divide by dp.  The kernels themselves are unchanged: sharded inputs
@@ -723,25 +726,20 @@ class SlotDecodeEngine:
         self._state = None  # lazy: first pack pays the init compile
         self._active = np.zeros(slots, dtype=bool)
         self._obs = obs.registry_for(self._hps)
-        # ---- paged resident state (ISSUE 20) ----
-        # resolve_arena_pages > 0 switches the engine to the paged
-        # kernel set: enc-axis resident leaves pool into a shared
+        # ---- the page arena (ISSUE 20) ----
+        # enc-axis resident leaves pool into a shared
         # decode_enc_block-row page arena, each admission allocates
         # ceil(true_len/block) pages, and the per-slot page-table rows
-        # ride into the kernels as traced DATA.  Same four compile
-        # sites, same warm-set budget — paging changes the memory
-        # story, never the compile story.
+        # ride into the kernels as traced DATA.  With no arena option
+        # set the arena holds every slot at full length and no
+        # admission waits for pages (config.resolve_arena_pages).
         self._block = resolve_enc_block(self._hps)
         self._b_max = -(-self._hps.max_enc_steps // self._block)
-        self._page_bytes = 0
-        if self._hps.serve_arena_pages > 0 or self._hps.serve_arena_mb > 0:
-            self._page_bytes = beam_search.paged_page_bytes(
-                decoder._params_snapshot()[0], self._hps)
-        self._arena_pages = resolve_arena_pages(self._hps,
-                                                self._page_bytes or None)
-        self.paged = self._arena_pages > 0
-        self._arena: Optional[PageArena] = (
-            PageArena(self._arena_pages) if self.paged else None)
+        self._page_bytes = beam_search.paged_page_bytes(
+            decoder._params_snapshot()[0], self._hps)
+        self._arena_pages = resolve_arena_pages(self._hps, slots,
+                                                self._page_bytes)
+        self._arena = PageArena(self._arena_pages)
         # scratch-filled page table; row i mirrors slot i's allocation
         self._table = np.full((slots, self._b_max), self._arena_pages,
                               np.int32)
@@ -839,16 +837,10 @@ class SlotDecodeEngine:
             specs = reg.slot_batch_specs()
             zero = {k: jax.device_put(v, reg.named(specs[k]))
                     for k, v in zero.items()}
-        if self.paged:
-            self._state = self._pin_state(
-                self._jitted("decode/init_slots_jit",
-                             beam_search.init_slots_paged_jit, params,
-                             self._hps, zero, self._arena_pages))
-        else:
-            self._state = self._pin_state(
-                self._jitted("decode/init_slots_jit",
-                             beam_search.init_slots_jit, params,
-                             self._hps, zero))
+        self._state = self._pin_state(
+            self._jitted("decode/init_slots_jit",
+                         beam_search.init_slots_jit, params,
+                         self._hps, zero, self._arena_pages))
 
     def _register_prefill_cost(self, bucket: int) -> None:
         """Queue analytic pricing of one prefill bucket for the
@@ -890,9 +882,7 @@ class SlotDecodeEngine:
     def pages_needed(self, item) -> int:
         """Arena pages one admission consumes: ceil(true_len / block),
         read from the HOST-side example length (never the device
-        array — pack is a TS002 hot path).  0 when paging is off."""
-        if not self.paged:
-            return 0
+        array — pack is a TS002 hot path)."""
         enc_len = min(int(item.example.enc_len if isinstance(
             item, PrefilledArticle) else item.enc_len),
             self._hps.max_enc_steps)
@@ -900,48 +890,40 @@ class SlotDecodeEngine:
 
     def free_pages(self) -> int:
         """Free arena pages (for the batcher's admit-by-free-pages
-        check); paging off reports the arena as bottomless."""
-        if not self.paged:
-            return 1 << 30
+        check)."""
         return self._arena.free_pages
 
-    def arena_stats(self) -> Optional[Dict[str, float]]:
+    def arena_stats(self) -> Dict[str, float]:
         """Arena occupancy snapshot for the serve metrics/bench
-        evidence fields; None when paging is off.  Pure host counters —
-        no device sync."""
-        if not self.paged:
-            return None
+        evidence fields.  Pure host counters — no device sync."""
         a = self._arena
         return {"capacity": a.capacity, "free": a.free_pages,
                 "in_use": a.pages_in_use, "fill": a.fill}
 
     def resident_bytes_per_slot(self) -> float:
         """Mean resident HBM bytes one resident actually consumes —
-        the ISSUE 20 evidence figure.  Dense engine: the static
-        state-bytes / slots (every slot owns worst-case width whether
-        occupied or not).  Paged engine: the dense (non-pooled) per-slot
-        share plus the IN-USE pages' bytes averaged over current
-        residents — array metadata and host counters only, no sync."""
+        the ISSUE 20 evidence figure: the per-slot share of the
+        non-pooled leaves plus the IN-USE pages' bytes averaged over
+        current residents — array metadata and host counters only, no
+        sync."""
         if self._state is None:
             return 0.0
         import jax
 
         leaves = jax.tree_util.tree_leaves(self._state)
         total = float(sum(x.nbytes for x in leaves))
-        if not self.paged:
-            return total / self.slots
         pools = list(self._state.enc_pages) + [self._state.ext_pool,
                                                self._state.attn_pool]
-        dense = total - float(sum(x.nbytes for x in pools))
+        fixed = total - float(sum(x.nbytes for x in pools))
         n_active = max(1, int(self._active.sum()))
-        return (dense / self.slots
+        return (fixed / self.slots
                 + self._arena.pages_in_use * self._page_bytes / n_active)
 
     def pack(self, idx: int, item) -> None:
         """Admit one prefilled article (or a raw SummaryExample, which
         is prefilled inline) into slot `idx` (must be free).
 
-        Paged engine: allocates the admission's pages first — a typed
+        Allocates the admission's pages first — a typed
         ArenaExhaustedError propagates to the batcher BEFORE any device
         state changes (requeue, never a wrong decode), and a pack
         failure after allocation frees the pages (no leak)."""
@@ -951,38 +933,28 @@ class SlotDecodeEngine:
             item = self.prefill(item)
         params = self._params()
         self._ensure_state(params)
-        if self.paged:
-            need = self.pages_needed(item)
-            ids = self._arena.alloc(need)  # may raise ArenaExhaustedError
-            row = np.full(self._b_max, self._arena_pages, np.int32)
-            row[:need] = ids
-            try:
-                self._state = self._pin_state(
-                    self._jitted("decode/pack_slot_jit",
-                                 beam_search.pack_slot_paged_jit, params,
-                                 self._hps, self._state, idx, item.state,
-                                 row))
-            except BaseException:
-                self._arena.free(ids)
-                raise
-            self._table[idx] = row
-            self._page_rows[idx] = ids
-        else:
+        need = self.pages_needed(item)
+        ids = self._arena.alloc(need)  # may raise ArenaExhaustedError
+        row = np.full(self._b_max, self._arena_pages, np.int32)
+        row[:need] = ids
+        try:
             self._state = self._pin_state(
                 self._jitted("decode/pack_slot_jit",
                              beam_search.pack_slot_jit, params,
-                             self._hps, self._state, idx, item.state))
+                             self._hps, self._state, idx, item.state,
+                             row))
+        except BaseException:
+            self._arena.free(ids)
+            raise
+        self._table[idx] = row
+        self._page_rows[idx] = ids
         self._active[idx] = True
 
-    def _step_call(self, params):
-        """(jitted slot step, its arguments) at the engine's current
-        state: what step() runs and compiled_step() lowers."""
-        if self.paged:
-            return beam_search.step_slots_paged_jit, (
-                params, self._hps, self._state, self._active, self._table,
+    def _step_args(self, params):
+        """The slot step's arguments at the engine's current state:
+        what step() runs and compiled_step() lowers."""
+        return (params, self._hps, self._state, self._active, self._table,
                 self.chunk)
-        return beam_search.step_slots_jit, (
-            params, self._hps, self._state, self._active, self.chunk)
 
     def compiled_step(self):
         """The ``jax.stages.Compiled`` of the slot step at the engine's
@@ -996,8 +968,8 @@ class SlotDecodeEngine:
         if self._state is None:
             raise RuntimeError("the slot step has no shapes yet: nothing "
                                "was packed into this engine")
-        fn, args = self._step_call(self._params())
-        return fn.lower(*args).compile()
+        return beam_search.step_slots_jit.lower(
+            *self._step_args(self._params())).compile()
 
     def step(self) -> List[int]:
         """One chunk for every resident slot; returns the slot indices
@@ -1011,9 +983,9 @@ class SlotDecodeEngine:
         # timestamp via its slot/tick lifecycle events, not by trace_id
         with self._prof.phase("decode/slot_chunk",
                               active=int(self._active.sum())):
-            fn, args = self._step_call(params)
-            self._state, finished = self._jitted("decode/step_slots_jit",
-                                                 fn, *args)
+            self._state, finished = self._jitted(
+                "decode/step_slots_jit", beam_search.step_slots_jit,
+                *self._step_args(params))
             self._state = self._pin_state(self._state)
             # the one sanctioned chunk-boundary sync: the host scheduler
             # needs the finished mask to retire and refill slots.  Its
@@ -1029,16 +1001,10 @@ class SlotDecodeEngine:
         OOV map travel with the request, not the device state)."""
         if not self._active[idx]:
             raise AssertionError(f"slot {idx} is not resident")
-        if self.paged:
-            out = self._jitted("decode/unpack_slot_jit",
-                               beam_search.unpack_slot_paged_jit,
-                               self._hps, self._state, idx,
-                               self._table[idx])
-            self._free_slot_pages(idx)
-        else:
-            out = self._jitted("decode/unpack_slot_jit",
-                               beam_search.unpack_slot_jit, self._hps,
-                               self._state, idx)
+        out = self._jitted("decode/unpack_slot_jit",
+                           beam_search.unpack_slot_jit, self._hps,
+                           self._state, idx, self._table[idx])
+        self._free_slot_pages(idx)
         self._active[idx] = False
         res = self._dec._make_result(
             np.asarray(out.tokens), int(out.length),
@@ -1066,9 +1032,8 @@ class SlotDecodeEngine:
     def release(self, idx: int) -> None:
         """Free slot `idx` WITHOUT unpacking (deadline eviction): the
         stale state is masked out until the next pack overwrites it,
-        and a paged slot's pages go straight back to the arena."""
-        if self.paged:
-            self._free_slot_pages(idx)
+        and the slot's pages go straight back to the arena."""
+        self._free_slot_pages(idx)
         self._active[idx] = False
 
     def active_count(self) -> int:
@@ -1078,22 +1043,11 @@ class SlotDecodeEngine:
         """Jit-cache entry counts of the four decode kernels plus the
         bucketed prefill — the 'bounded compile cache' evidence (tests
         assert the decode kernels never grow after warmup and prefill
-        stays at one entry per serve bucket).  In paged mode the four
-        kernels are the *_paged variants (ISSUE 20) — counting the
-        kernels this engine actually dispatches is what makes the pin
-        meaningful (the dense caches would sit frozen regardless)."""
-        if self.paged:
-            kernels = (beam_search.init_slots_paged_jit,
-                       beam_search.prefill_jit,
-                       beam_search.pack_slot_paged_jit,
-                       beam_search.step_slots_paged_jit,
-                       beam_search.unpack_slot_paged_jit)
-        else:
-            kernels = (beam_search.init_slots_jit, beam_search.prefill_jit,
-                       beam_search.pack_slot_jit, beam_search.step_slots_jit,
-                       beam_search.unpack_slot_jit)
+        stays at one entry per serve bucket)."""
         out: Dict[str, int] = {}
-        for fn in kernels:
+        for fn in (beam_search.init_slots_jit, beam_search.prefill_jit,
+                   beam_search.pack_slot_jit, beam_search.step_slots_jit,
+                   beam_search.unpack_slot_jit):
             try:
                 out[fn.__wrapped__.__name__] = fn._cache_size()
             except Exception:  # tslint: disable=TS005 — private jax API; absent on some builds

@@ -233,14 +233,11 @@ class ShardingRegistry:
             attn_dists=P("dp"), p_gens=P("dp"))
 
     def slot_state_specs(self, state: PyTree) -> PyTree:
-        """Continuous-serving SlotState: every leaf leads with the
-        [slots, ...] axis, sharded over dp (slots % dp == 0, validated
-        by the engine); per-slot beams stay chip-local like the batch
-        search.
-
-        Paged resident state (ISSUE 20): a PagedSlotState splits into
-        two placement classes.  Slot-leading leaves (beam, enc_rest,
-        masks/lengths) keep the dp rule above.  The page POOLS and the
+        """Continuous-serving SlotState, in two placement classes
+        (ISSUE 20).  Slot-leading leaves (beam, enc_rest,
+        masks/lengths) lead with the [slots, ...] axis, sharded over dp
+        (slots % dp == 0, validated by the engine); per-slot beams stay
+        chip-local like the batch search.  The page POOLS and the
         scratch row lead with the [pages+1, ...] arena axis, which has
         no relation to dp — they replicate (role ``arena_pool``), and
         the page TABLE passed alongside as data replicates too (role
@@ -251,20 +248,18 @@ class ShardingRegistry:
         """
         from textsummarization_on_flink_tpu.decode import beam_search
 
-        if isinstance(state, beam_search.PagedSlotState):
-            dp = jax.tree_util.tree_map(lambda _: P("dp"), state)
-            rep = jax.tree_util.tree_map(lambda _: self.arena_pool_spec(),
-                                         state)
-            return beam_search.PagedSlotState(
-                beam=dp.beam, enc_rest=dp.enc_rest,
-                enc_pages=rep.enc_pages, ext_pool=rep.ext_pool,
-                attn_pool=rep.attn_pool, enc_mask=dp.enc_mask,
-                enc_valid_len=dp.enc_valid_len)
-        return jax.tree_util.tree_map(lambda _: P("dp"), state)
+        dp = jax.tree_util.tree_map(lambda _: P("dp"), state)
+        rep = jax.tree_util.tree_map(lambda _: self.arena_pool_spec(),
+                                     state)
+        return beam_search.SlotState(
+            beam=dp.beam, enc_rest=dp.enc_rest,
+            enc_pages=rep.enc_pages, ext_pool=rep.ext_pool,
+            attn_pool=rep.attn_pool, enc_mask=dp.enc_mask,
+            enc_valid_len=dp.enc_valid_len)
 
     def arena_pool_spec(self) -> P:
-        """Page pools ([pages+1, block, ...] leaves of a
-        PagedSlotState): replicated — the arena axis is allocator
+        """Page pools ([pages+1, block, ...] leaves of a SlotState):
+        replicated — the arena axis is allocator
         bookkeeping, not a device axis (see slot_state_specs)."""
         return P()
 

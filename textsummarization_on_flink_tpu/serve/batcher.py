@@ -169,6 +169,22 @@ class MicroBatcher:
                      real_mask=mask)
 
 
+class NoArena:
+    """What an engine that pools nothing (the process fleet's stub
+    child, the jax-free simulated engines of the tests) answers the
+    ContinuousBatcher about pages: an admission needs none, the arena
+    never runs out, and there is nothing to observe."""
+
+    def pages_needed(self, item: Any) -> int:
+        return 0
+
+    def free_pages(self) -> int:
+        return 1 << 30
+
+    def arena_stats(self) -> None:
+        return None
+
+
 class ContinuousBatcher:
     """Continuous batching: admit into free decode slots, step a chunk,
     harvest finished sequences — no dispatch-window barrier (ISSUE 6).
@@ -287,12 +303,10 @@ class ContinuousBatcher:
         # per-tenant cost accounting (ISSUE 15): decoded tokens charged
         # to the tenant whose request occupied the slot
         self._c_tenant_tokens = reg.counter("serve/tenant_tokens_total")
-        # paged-resident-state telemetry (ISSUE 20): arena occupancy per
-        # tick plus the allocation-failure backpressure count.  Emitted
-        # HERE rather than in the engine so the jax-free sim engines the
-        # SLO gate drives light the same series the real engine does —
-        # an engine without an arena surface simply never updates them.
-        self._supports_arena = bool(getattr(engine, "paged", False))
+        # page-arena telemetry (ISSUE 20): arena occupancy per tick plus
+        # the allocation-failure backpressure count.  Emitted HERE
+        # rather than in the engine so the jax-free sim engines the SLO
+        # gate drives light the same series the real engine does.
         self._arena_blocked = False  # rising-edge state for the trigger
         self._g_arena_pages = reg.gauge("serve/arena_pages_in_use")
         self._c_arena_fail = reg.counter("serve/arena_alloc_failures_total")
@@ -496,7 +510,7 @@ class ContinuousBatcher:
                     if req is None:
                         return
                     payload = req.example
-                if self._supports_arena and self._supports_prefill:
+                if self._supports_prefill:
                     # admit by FREE PAGES, not free slots (ISSUE 20):
                     # an admission that cannot get its pages goes BACK
                     # to the head of the prefill queue — requeued, never
@@ -511,8 +525,7 @@ class ContinuousBatcher:
                 try:
                     with self._prof.phase("serve/pack",
                                           trace_id=_trace_id(req)):
-                        if (self._supports_arena
-                                and self._faults is not None
+                        if (self._faults is not None
                                 and self._faults.fire("serve.arena_full")):
                             raise ArenaExhaustedError(
                                 "injected serve.arena_full fault",
@@ -525,8 +538,7 @@ class ContinuousBatcher:
                     # chaos sweep's injection path): same requeue-never-
                     # reject contract.  Only the prefill path holds a
                     # repackable payload; a legacy direct-pack engine
-                    # with an arena would have to reject — the engine
-                    # guarantees prefill support whenever paged.
+                    # has to reject.
                     if not self._supports_prefill:
                         self._c_errors.inc()
                         self._close_stage(req, "slot_wait")
@@ -543,8 +555,7 @@ class ContinuousBatcher:
                     self._close_stage(req, "slot_wait")
                     req.future._reject(e)
                     raise
-                if self._supports_arena and self._arena_blocked:
-                    self._arena_blocked = False  # pages freed; edge re-arms
+                self._arena_blocked = False  # pages freed; edge re-arms
                 self._resident[idx] = req
                 self._chunks[idx] = 0
                 self._c_refills.inc()
@@ -613,9 +624,8 @@ class ContinuousBatcher:
     def _observe_arena(self) -> None:
         """Per-tick arena occupancy series (ISSUE 20): pages in use and
         the fill fraction — host counters off the engine's arena
-        surface, no device sync."""
-        if not self._supports_arena:
-            return
+        surface, no device sync (a stub engine that pools nothing
+        answers None)."""
         stats = self._engine.arena_stats()
         if not stats:
             return
@@ -626,14 +636,12 @@ class ContinuousBatcher:
         """One flight-recorder frame per scheduler round (the serve-tick
         analogue of the trainer's per-step frame): what the engine was
         doing on the rounds BEFORE a failure trigger fires."""
-        extra = {}
-        if self._supports_arena:
-            extra["arena_free"] = self._engine.free_pages()
         flightrec.record(
             self._reg, "serve_tick", tick=self._tick,
             occupancy=round(occupancy, 4), queue_depth=self._q.qsize(),
             evictions=self._tick_evictions, refills=self._tick_refills,
-            prefilled=len(self._prefilled), **extra)
+            prefilled=len(self._prefilled),
+            arena_free=self._engine.free_pages())
 
     def tick(self, poll: float = 0.05) -> bool:
         """One scheduler round: evict -> refill -> step -> harvest.

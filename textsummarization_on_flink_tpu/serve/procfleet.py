@@ -85,6 +85,7 @@ from textsummarization_on_flink_tpu.resilience.policy import (
     CircuitBreaker,
     RetryPolicy,
 )
+from textsummarization_on_flink_tpu.serve.batcher import NoArena
 from textsummarization_on_flink_tpu.serve.errors import (
     ReplicaKilledError,
     ServeClosedError,
@@ -1282,7 +1283,7 @@ class _StubDecoder:
         return last
 
 
-class _StubEngine:
+class _StubEngine(NoArena):
     """SlotDecodeEngine over wall-clock sleeps: each request occupies a
     slot for a couple of chunks so a SIGKILL mid-decode really orphans
     in-flight work.  Process-machinery tests only (TS_REPLICA_STUB) —

@@ -151,7 +151,7 @@ DEFAULT: Dict[str, Any] = {
                 r"^HierarchicalSummarizer\.(_fan_out|_chunk_done"
                 r"|_record_chunk|_map_complete|_reduce_done)$",
                 r"^DocumentAssembler\.feed$",
-                # the paged resident state (ISSUE 20): page alloc/free
+                # the slot engine's page arena (ISSUE 20): page alloc/free
                 # run inside every admission/harvest on the dispatch
                 # thread, the engine's page accounting gates every
                 # refill, and the arena-occupancy observer fires every
