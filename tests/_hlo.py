@@ -1,5 +1,6 @@
 """Reading a compiled program's text in tests (no test lives here)."""
 
+import math
 import re
 
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
@@ -54,3 +55,34 @@ def wide_row_orderings(text: str, width: int) -> list:
         if any(wide.search(s) for s in shapes):
             bad.append(f"{name} = {shape} {opcode}({rest}"[:200])
     return bad
+
+
+def wide_gathers(text: str, width: int) -> list:
+    """The gathers of ``compiled.as_text()`` that read an operand with
+    a dimension of exactly ``width``, each as (instruction, the
+    operand's dimensions): with the vocabulary's width, a lookup by
+    word id.  Whose rows they are is in the other dimensions: a
+    parameter matrix's hidden width, or the [slots, beam] of a step's
+    score block.  (The operand is printed by name, so its shape is
+    looked up where it is defined.)"""
+    instrs = _instructions(text)
+    shape_of = {name: shape for name, shape, _, _ in instrs}
+    out = []
+    for name, shape, opcode, rest in instrs:
+        if opcode != "gather":
+            continue
+        operand = re.match(r"\s*%?([\w.\-]+)", rest)
+        dims = _dims(shape_of.get(operand.group(1), "")) if operand else []
+        if width in dims:
+            out.append((f"{name} = {shape} gather({rest}"[:200], dims))
+    return out
+
+
+def score_gathers(text: str, vocab: int, rows: int) -> list:
+    """``wide_gathers`` whose operand is a step's score block, ``rows``
+    (slots x beam) rows of ``vocab`` scores, flattened or not — and not
+    a parameter matrix (an embedding [V, emb_dim], a projection [V, H]
+    or [H, V], its bias [V]): choose shapes where ``rows`` is none of
+    those other widths."""
+    return [g for g, dims in wide_gathers(text, vocab)
+            if math.prod(dims) == rows * vocab]

@@ -2,7 +2,10 @@
 the contract the beam step's candidate ranking stands on (ISSUE 31) —
 ids equal, values within 2e-6 relative — and token-equal decodes of the
 three families through both slot engines with the candidates in place
-of the extended row.
+of the extended row.  Since ISSUE 33 a caller that decodes in a loop
+supplies the article-side scores (a product with ``head_at``'s
+columns) in place of the gather from the row: the same contract, up to
+the float32 accumulation order of an article word's logit.
 """
 
 import jax
@@ -15,6 +18,7 @@ import __graft_entry__ as ge
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.models import get_family
 from textsummarization_on_flink_tpu.ops import topk
+from textsummarization_on_flink_tpu.ops.losses import project_scores
 
 V, T, OOV = 1024, 24, 8  # the candidates' side of _mixture_plan
 LEAD = (3, 2)  # slots, beam: the two axes the slot step vmaps over
@@ -54,6 +58,15 @@ def _oov_ids(rng):
     ids[..., 3], ids[..., 7], ids[..., 11] = V, V + 2, V + 2
     ids[..., 5] = V + OOV - 1
     attn[..., [3, 5, 7]] += 0.5
+    return z, attn / attn.sum(-1, keepdims=True), p, ids
+
+
+def _past_the_row(rng):
+    """Ids past the extended row, attended: the scatter drops them, and
+    so do the candidates; a bucket id beside them stays."""
+    z, attn, p, ids = _base(rng)
+    ids[..., 2], ids[..., 9], ids[..., 10] = V + OOV, V + OOV + 5, V + 1
+    attn[..., [2, 9, 10]] += 0.5
     return z, attn / attn.sum(-1, keepdims=True), p, ids
 
 
@@ -141,7 +154,7 @@ def _one_rounding(make):
 
 
 CASES = {"duplicate_ids": _duplicate_ids, "oov_ids": _oov_ids,
-         "padded_tail": _padded_tail,
+         "past_the_row": _past_the_row, "padded_tail": _padded_tail,
          "article_holds_best": _article_holds_the_vocabularys_best,
          "article_outside_best": _article_outside_the_vocabularys_best,
          "ties_across_sets": _ties_across_the_sets,
@@ -167,39 +180,98 @@ def _cell_width(rng):
             rng.uniform(0.2, 0.8, size=LEAD).astype(np.float32), ids)
 
 
+def _projected(rng, compute_dtype, hidden=32):
+    """A row that IS a projection, ``h @ W + b`` through the program's
+    own matmul, with the head it came from: an article that holds three
+    of each row's best words (one of them twice), bucket ids, an id
+    past the extended row and repeats.  Returns the case and (h, W, b)
+    for the caller's side of the product."""
+    _, attn, p, ids = _duplicate_ids(rng)
+    h = rng.normal(size=LEAD + (hidden,)).astype(np.float32)
+    w = rng.normal(size=(hidden, V)).astype(np.float32) * 0.5
+    b = rng.normal(size=(V,)).astype(np.float32)
+    z = np.asarray(project_scores(jnp.asarray(h), jnp.asarray(w),
+                                  compute_dtype) + b)
+    # shared by the beam's rows under vmap, so each slot's hypothesis 0's
+    ids[..., 6:9] = _best(z, 3)[:, :1]
+    ids[..., 9] = ids[..., 6]
+    ids[..., 3], ids[..., 5], ids[..., 7] = V + 2, V + OOV - 1, V + OOV + 3
+    attn[..., [3, 5, 6, 7]] += 0.3
+    return (z, attn / attn.sum(-1, keepdims=True), p, ids), (h, w, b)
+
+
+#: where the article-side scores come from: gathered from the row by
+#: mixture_top_k itself (a caller with no loop); supplied, as the exact
+#: product of a one-hot state with the case's own rows (every crafted
+#: tie survives); or projected, the row and the article's scores both
+#: real products of one random head (float32 and bfloat16 operands)
+ART = ("gathered", "supplied", "projected")
+
+
 def _params():
-    out = [pytest.param(name, V + OOV, k, dtype, vmapped,
-                        id=f"{name}-k{k}-{dtype}-{how}")
-           for name in CASES for k in (2, 8)
+    out = [pytest.param(name, V + OOV, k, dtype, vmapped, art,
+                        id=f"{name}-k{k}-{dtype}-{how}"
+                        + ("" if art == "gathered" else "-" + art))
+           for art in ART[:2] for name in CASES for k in (2, 8)
            for dtype in ("float32", "bfloat16")
            for vmapped, how in ((False, "plain"), (True, "vmap"))]
     out += [pytest.param("dense_fallback", 64 + 4, k, "float32", vm,
-                         id=f"dense_fallback-k{k}-{how}")
+                         "gathered", id=f"dense_fallback-k{k}-{how}")
             for k in (2, 8) for vm, how in ((False, "plain"), (True, "vmap"))]
-    out.append(pytest.param("cell_width", 50128, 8, "float32", True,
-                            id="cell_width-k8-float32-vmap"))
+    out += [pytest.param("cell_width", 50128, 8, "float32", True, art,
+                         id="cell_width-k8-float32-vmap"
+                         + ("" if art == "gathered" else "-" + art))
+            for art in ART[:2]]
+    out += [pytest.param("projected", V + OOV, k, dtype, vmapped,
+                         "projected", id=f"projected-k{k}-{dtype}-{how}")
+            for k in (2, 8) for dtype in ("float32", "bfloat16")
+            for vmapped, how in ((False, "plain"), (True, "vmap"))]
     return out
 
 
-@pytest.mark.parametrize("case,ext_size,k,dtype,vmapped", _params())
+def _head_scores(h, w, b, ids, k, compute_dtype, vmapped):
+    """A caller's side: the head's columns at the ids (``head_at``,
+    once) and the product a step makes with them, through the matmul
+    the row came from."""
+    head = topk.head_at(w, b, ids, k)
+    assert head is not None and head.w.shape == ids.shape + h.shape[-1:]
+
+    def one(x, hw, hv):  # [rows, H] on [T, H], as pg.head_scores
+        return project_scores(x, hw.T, compute_dtype) + hv
+
+    if vmapped:  # ids [S, T] under the beam's rows [S, K, H]
+        return jax.vmap(one)(h, head.w, head.v)
+    return jax.vmap(one)(h[:, None], head.w, head.v)[:, 0]  # ids [R, T]
+
+
+@pytest.mark.parametrize("case,ext_size,k,dtype,vmapped,art", _params())
 def test_mixture_top_k_is_top_k_of_the_mixture(case, ext_size, k, dtype,
-                                               vmapped):
+                                               vmapped, art):
     rng = np.random.default_rng(31)
     make = {"dense_fallback": _dense_fallback,
-            "cell_width": _cell_width}.get(case) or CASES[case]
-    if dtype == "bfloat16":
-        make = _one_rounding(make)
-    z, attn, p, ids = make(rng)
+            "cell_width": _cell_width}.get(case) or CASES.get(case)
+    if case == "projected":  # float32 arrays; dtype is the matmul's
+        (z, attn, p, ids), (h, w, b) = _projected(rng, dtype)
+        compute_dtype, dtype = dtype, "float32"
+    else:
+        if dtype == "bfloat16":
+            make = _one_rounding(make)
+        z, attn, p, ids = make(rng)
     z, attn, p = (jnp.asarray(x).astype(dtype) for x in (z, attn, p))
     plan = topk._mixture_plan(z.shape[-1], attn.shape[-1], k)
     assert plan == ("dense" if case == "dense_fallback" else "candidates")
+    if art == "supplied":  # row r is state e_r on the rows themselves
+        rows = int(np.prod(LEAD))
+        h = jnp.eye(rows, dtype=z.dtype).reshape(LEAD + (rows,))
+        w, b = z.reshape(rows, -1), jnp.zeros(z.shape[-1:], z.dtype)
+        compute_dtype = "float32"  # exact in either: ones and zeros
 
     def want(z, attn, p, ids):
         return jax.lax.top_k(topk.extended_mixture(
             jax.nn.softmax(z, axis=-1), attn, p, ids, ext_size), k)
 
-    def got(z, attn, p, ids):
-        return topk.mixture_top_k(z, attn, p, ids, k, ext_size)
+    def got(z, attn, p, ids, *scores):
+        return topk.mixture_top_k(z, attn, p, ids, k, ext_size, *scores)
 
     if vmapped:  # over slots, the beam's rows sharing the article's ids
         ids = jnp.asarray(ids[:, 0])
@@ -207,8 +279,17 @@ def test_mixture_top_k_is_top_k_of_the_mixture(case, ext_size, k, dtype,
     else:  # rank 2, every row its own ids
         z, attn, p, ids = (jnp.reshape(x, (-1,) + x.shape[len(LEAD):])
                            for x in (z, attn, p, jnp.asarray(ids)))
+    scores = ()
+    if art != "gathered":
+        if not vmapped:
+            h = h.reshape((-1,) + h.shape[len(LEAD):])
+        scores = (_head_scores(h, jnp.asarray(w), jnp.asarray(b), ids, k,
+                               compute_dtype, vmapped).astype(dtype),)
+        if case in ("oov_ids", "past_the_row", "projected"):
+            # some id is past the vocabulary: its column is place 0's
+            assert (np.asarray(ids) >= z.shape[-1]).any()
     want_v, want_i = jax.jit(want)(z, attn, p, ids)
-    got_v, got_i = jax.jit(got)(z, attn, p, ids)
+    got_v, got_i = jax.jit(got)(z, attn, p, ids, *scores)
     assert got_v.dtype == want_v.dtype and got_i.dtype == want_i.dtype
     np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
     np.testing.assert_allclose(

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 import __graft_entry__ as ge
-from _hlo import wide_dimensions, wide_row_orderings, wide_scatters
+from _hlo import (score_gathers, wide_dimensions, wide_gathers,
+                  wide_row_orderings, wide_scatters)
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -163,10 +164,41 @@ def test_the_detectors_see_the_extended_row():
         _builds_no_extended_row(text, hps)
 
 
+SLOTS = 3  # slots x beam = 12 rows: no parameter matrix's other width
+SCORE_ROWS = SLOTS * PG_WIDE.beam_size
+assert SCORE_ROWS not in (PG_WIDE.hidden_dim, PG_WIDE.emb_dim)
+
+
+def test_the_detector_sees_a_gather_from_the_scores():
+    """The parent's form, ``mixture_top_k`` with no scores supplied: the
+    article's words are looked up in the [slots, beam, V] block.  With
+    them supplied nothing is looked up there, and the once-a-loop
+    gather of the head's columns reads a parameter matrix."""
+    hps = PG_WIDE
+    V, K, T = hps.vocab_size, hps.beam_size, hps.max_enc_steps
+    z, attn = jnp.zeros((SLOTS, K, V)), jnp.zeros((SLOTS, K, T))
+    p, ids = jnp.zeros((SLOTS, K)), jnp.zeros((SLOTS, T), jnp.int32)
+    art = jnp.zeros((SLOTS, K, T))
+    gathered = jax.jit(jax.vmap(lambda *a: topk.mixture_top_k(
+        *a, 8, WIDE))).lower(z, attn, p, ids).compile().as_text()
+    assert score_gathers(gathered, V, SCORE_ROWS)
+    supplied = jax.jit(jax.vmap(lambda z, a, p, i, s: topk.mixture_top_k(
+        z, a, p, i, 8, WIDE, s))).lower(z, attn, p, ids, art).compile()
+    assert not wide_gathers(supplied.as_text(), V)
+    head = jax.jit(lambda w, v, i: topk.head_at(w, v, i, 8)).lower(
+        jnp.zeros((hps.hidden_dim, V)), jnp.zeros((V,)), ids).compile()
+    assert wide_gathers(head.as_text(), V)
+    assert not score_gathers(head.as_text(), V, SCORE_ROWS)
+
+
 def test_engine_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
     """The engine's own executable (SlotDecodeEngine.compiled_step(),
-    behind ServingServer.compiled_slot_step()): two slots over an
-    8-page arena at pg_see2017's vocabulary and beam."""
+    behind ServingServer.compiled_slot_step()): three slots over an
+    8-page arena at pg_see2017's vocabulary and beam.  The article's
+    words are scored by a product with the head's columns (ISSUE 33):
+    what is still looked up by word id is a parameter matrix, the
+    embedding in the loop and the projection once before it, never the
+    step's scores."""
     from textsummarization_on_flink_tpu.data.vocab import Vocab
     from textsummarization_on_flink_tpu.obs import Registry
     from textsummarization_on_flink_tpu.serve.server import ServingServer
@@ -178,7 +210,7 @@ def test_engine_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
     assert vocab.size() == 50000
     hps = PG_WIDE.replace(
         max_enc_steps=16, max_dec_steps=4, min_dec_steps=1,
-        serve_buckets="16", serve_mode="continuous", serve_slots=2,
+        serve_buckets="16", serve_mode="continuous", serve_slots=SLOTS,
         serve_refill_chunk=2, serve_arena_pages=8)
     params = trainer_lib.init_train_state(hps, vocab.size(), seed=0).params
     server = ServingServer(hps, vocab, params=params,
@@ -188,6 +220,8 @@ def test_engine_slot_step_at_the_cells_vocabulary_orders_no_wide_row(tmp_path):
         server.submit("the cat sat .", uuid="a").result(timeout=600)
         text = server.compiled_slot_step().as_text()
     _builds_no_extended_row(text, hps)
+    assert wide_gathers(text, hps.vocab_size)  # the parameters' rows
+    assert not score_gathers(text, hps.vocab_size, SCORE_ROWS)
 
 
 def test_transformer_slot_step_at_the_cells_vocabulary_orders_no_wide_row():

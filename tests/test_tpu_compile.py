@@ -22,7 +22,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import __graft_entry__ as ge
-from _hlo import wide_dimensions, wide_row_orderings, wide_scatters
+from _hlo import (score_gathers, wide_dimensions, wide_row_orderings,
+                  wide_scatters)
 from textsummarization_on_flink_tpu.config import HParams, resolve_enc_block
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
@@ -174,9 +175,14 @@ def _orders_no_vocabulary_row(compiled, hps):
     26, and expands the mixture's scatter-add over that row into half
     of what was left (ISSUE 31) — in the chip's own compiler's output
     no sort and no top-k may have an operand as wide as the vocabulary,
-    no scatter a result that wide, and nothing the extended width."""
+    no scatter a result that wide, and nothing the extended width.  Nor
+    is a word's score looked up by id in the step's score block: the
+    article's words are scored by a product with the head's columns,
+    gathered once before the loop (ISSUE 33; XLA:TPU gathers at 18-19
+    ns an index, 102 400 of them a step in the cell)."""
     text = compiled.as_text()
     V, width = hps.vocab_size, hps.vocab_size + hps.max_oov_buckets
+    assert not score_gathers(text, V, SLOTS * hps.beam_size)
     assert wide_dimensions(text, V)  # the step does hold the vocabulary
     assert not wide_row_orderings(text, V)
     assert not wide_row_orderings(text, width)

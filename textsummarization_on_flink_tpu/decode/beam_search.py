@@ -157,10 +157,13 @@ def _beam_cond(hps: HParams):
 
 
 def _make_beam_body(params, hps: HParams, step_fn, enc_one, enc_mask,
-                    ext_ids, attn_col_fn=None):
+                    ext_ids, head, attn_col_fn=None):
     """One decode step for one article, closed over its encoder view —
     shared verbatim by the batch search (_search_one) and the slot loop
     (step_slots_jit), so the paths cannot drift.
+
+    head: the article's row of the family's ``beam_head``, which both
+    callers gather before their loop and the step only passes on.
 
     attn_col_fn(t) overrides the attention-history write column — the
     slot loop writes every step into its width-1 scratch column
@@ -175,7 +178,7 @@ def _make_beam_body(params, hps: HParams, step_fn, enc_one, enc_mask,
         latest = jnp.where(s.latest >= V, UNK_ID,
                            s.latest)  # beam_search.py:112
         step = step_fn(params, enc_one, enc_mask, ext_ids, s.t, latest,
-                       s.dec_state)
+                       s.dec_state, head=head)
         with jax.named_scope("beam_select"):
             # candidate pool: every live hyp x its 2K continuations
             cand_lp = s.sum_lp[:, None] + step.topk_log_probs  # [K, 2K]
@@ -283,11 +286,12 @@ def _masked_scan_body(cond, body):
 
 
 def _search_one(params, hps: HParams, init_state_fn, step_fn, loop, chunk,
-                enc_one, enc_mask, ext_ids) -> BeamSearchOutput:
+                enc_one, enc_mask, ext_ids, head) -> BeamSearchOutput:
     """Beam search for ONE article (un-batched inputs; vmapped below).
 
     enc_one: the family's per-article encoder view (pytree, no batch
-    axis); enc_mask: [T_enc]; ext_ids: [T_enc] extended-vocab ids.
+    axis); enc_mask: [T_enc]; ext_ids: [T_enc] extended-vocab ids;
+    head: the article's row of the family's ``beam_head``.
     init_state_fn/step_fn: the family's beam adapter (models/__init__).
     loop: 'while', 'scan', or 'chunked' (see _loop_kind); chunk: the
     chunked inner-scan length, or None for the TS_BEAM_CHUNK env default
@@ -297,7 +301,8 @@ def _search_one(params, hps: HParams, init_state_fn, step_fn, loop, chunk,
     T_enc = enc_mask.shape[0]
     init = _init_beam_state(hps, T_enc, init_state_fn(params, enc_one))
     cond = _beam_cond(hps)
-    body = _make_beam_body(params, hps, step_fn, enc_one, enc_mask, ext_ids)
+    body = _make_beam_body(params, hps, step_fn, enc_one, enc_mask, ext_ids,
+                           head)
     scan_body = _masked_scan_body(cond, body)
 
     if loop == "while":
@@ -399,10 +404,11 @@ def _search_batch(params, hps: HParams, arrays: Dict[str, Array],
     family = get_family(hps.model_family)
     enc_view = family.beam_encode(params, hps, arrays)
     init_state_fn, step_fn = family.beam_adapter(hps)
+    ext = arrays["enc_batch_extend_vocab"]
     fn = functools.partial(_search_one, params, hps, init_state_fn, step_fn,
                            _loop_kind(loop), chunk)
-    return jax.vmap(fn)(enc_view, arrays["enc_padding_mask"],
-                        arrays["enc_batch_extend_vocab"])
+    return jax.vmap(fn)(enc_view, arrays["enc_padding_mask"], ext,
+                        family.beam_head(params, hps, ext))
 
 
 @functools.partial(jax.jit, static_argnames=("hps", "loop", "chunk"))
@@ -798,9 +804,10 @@ def step_slots_jit(params, hps: HParams, state: SlotState,
     energy floor the batch search gives padding, so trajectories stay
     token-exact with it.
 
-    Structure: the full-width per-slot encoder views are gathered ONCE
-    per chunk (loop-invariant — the gather cost amortizes over the
-    chunk's steps), then a top-level scan runs the chunk with a vmapped
+    Structure: the full-width per-slot encoder views, and the family's
+    output head at their ids (``beam_head``), are gathered ONCE per
+    chunk (loop-invariant — the gather cost amortizes over the chunk's
+    steps), then a top-level scan runs the chunk with a vmapped
     per-slot masked step inside, which exposes each step's attention
     row for ONE scatter into the shared pool at (slot pages, pre-step
     t).  Inactive slots' table rows are routed to the scratch
@@ -840,13 +847,14 @@ def step_slots_jit(params, hps: HParams, state: SlotState,
                                                T_enc))
         ext = state.ext_pool[pages].reshape(slots, t_pad)[:, :T_enc]
     enc_view = jax.tree_util.tree_unflatten(treedef, dense_leaves)
+    head = family.beam_head(params, hps, ext)
 
-    def one_step(beam, act, enc_one, mask, ext_one):
-        def step_nb(p, e, m, x, t, latest, s):
-            return step_fn(p, e, m, x, nb, t, latest, s)
+    def one_step(beam, act, enc_one, mask, ext_one, head_one):
+        def step_nb(p, e, m, x, t, latest, s, head):
+            return step_fn(p, e, m, x, nb, t, latest, s, head=head)
 
         body = _make_beam_body(params, hps, step_nb, enc_one, mask,
-                               ext_one, attn_col_fn=lambda t: 0)
+                               ext_one, head_one, attn_col_fn=lambda t: 0)
 
         def masked_cond(s):
             return jnp.logical_and(act, cond(s))
@@ -860,7 +868,7 @@ def step_slots_jit(params, hps: HParams, state: SlotState,
         beams, attn_pool = carry
         t_old = beams.t  # [slots] pre-step write column (t <= T always)
         beams2 = jax.vmap(one_step)(beams, active, enc_view,
-                                    state.enc_mask, ext)
+                                    state.enc_mask, ext, head)
         with jax.named_scope("page_io"):
             attn = beams2.attn_steps[:, :, 0, :]  # [slots, K, T_enc]
             pad = t_pad - T_enc

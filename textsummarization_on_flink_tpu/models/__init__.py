@@ -7,6 +7,8 @@ family-agnostic:
   init_params(hps, vsize, key) -> Params
   forward_train(params, hps, arrays) -> TrainOutput
   beam_encode(params, hps, arrays) -> per-batch encoder view (pytree)
+  beam_head(params, hps, ext_ids) -> the output head at the articles'
+      ids, gathered once a decode loop (or None: ops/topk.head_at)
   beam_adapter(hps) -> (init_state, step) beam-search closures
 
 Select with ``hps.model_family`` (the reference has a single hardcoded
@@ -41,9 +43,9 @@ def masked_adapter(beam_adapter_fn):
         init_state, step = beam_adapter_fn(hps)
 
         def step_masked(params, enc_one, enc_mask, ext_ids, nb, t, latest,
-                        state):
+                        state, head=None):
             return step(params, enc_one, enc_mask, ext_ids, t, latest,
-                        state, nb=nb)
+                        state, nb=nb, head=head)
 
         return init_state, step_masked
 

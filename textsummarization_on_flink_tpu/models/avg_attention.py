@@ -404,8 +404,8 @@ def beam_encode(params: Params, hps: HParams, arrays: Dict[str, Array],
 def decode_onestep(params: Params, hps: HParams,
                    enc_one: TransformerEncView, enc_mask: Array,
                    ext_ids: Array, t: Array, latest: Array,
-                   aan_sum: Array, nb=None) -> Tuple[Array, Array, Array,
-                                                     Array, Array, Array]:
+                   aan_sum: Array, nb=None, head=None,
+                   ) -> Tuple[Array, Array, Array, Array, Array, Array]:
     """One AAN decode step for K hypotheses: O(1) in history — the only
     carried decode state is the [K, L, H] running sum (f32), updated by
     one add; no cache gather, no attention over past positions.
@@ -437,7 +437,8 @@ def decode_onestep(params: Params, hps: HParams,
         y = y + tf._ffn_block(layer["ffn"], tf._ln(layer["ln2"], y))
         cross_ctx = cross_out
     topk_probs, topk_ids, p_gen, h = tf.decode_output_tail(
-        params, dhps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size)
+        params, dhps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size,
+        head)
     new_sum = jnp.stack(new_sums, axis=1)  # [K, L, H_d]
     return topk_probs, topk_ids, attn_dist, p_gen, h, new_sum
 
@@ -458,10 +459,10 @@ def beam_adapter(hps: HParams):
 
     def step(params: Params, enc_one: TransformerEncView, enc_mask: Array,
              ext_ids: Array, t: Array, latest: Array, state,
-             nb=None) -> BeamStepOut:
+             nb=None, head=None) -> BeamStepOut:
         topk_probs, topk_ids, attn_dist, p_gen, _, new_sum = decode_onestep(
             params, hps, enc_one, enc_mask, ext_ids, t, latest,
-            state["aan_sum"], nb=nb)
+            state["aan_sum"], nb=nb, head=head)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,
@@ -476,5 +477,6 @@ beam_adapter_masked = models_lib.masked_adapter(beam_adapter)
 
 
 #: the AAN encoder view IS the transformer's (same K/V precompute), so
-#: the prefill pad hand-off is the transformer's too
+#: the prefill pad hand-off is the transformer's too; so is the head
 pad_enc_view = tf.pad_enc_view
+beam_head = tf.beam_head

@@ -334,32 +334,59 @@ def final_distribution(hps: HParams, vocab_dist: Array, attn_dist: Array,
 
 
 def step_top_k(hps: HParams, vocab_scores: Array, attn_dist: Array,
-               p_gen: Array, ext_ids: Array, k: int) -> Tuple[Array, Array]:
+               p_gen: Array, ext_ids: Array, k: int,
+               art_scores: Optional[Array] = None) -> Tuple[Array, Array]:
     """Every family's decode step ends here: the k best (probabilities,
     extended ids) of each row, ``top_k(final_distribution(hps, softmax(
-    vocab_scores), ...), k)``.  ext_ids: [B, T_enc], or [T_enc] shared."""
+    vocab_scores), ...), k)``.  ext_ids: [B, T_enc], or [T_enc] shared;
+    art_scores: ``head_scores`` where the caller holds a head."""
     if not hps.pointer_gen:
         vocab_dist = jax.nn.softmax(vocab_scores, axis=-1)
         with jax.named_scope("topk"):
             return topk_ops.top_k(vocab_dist, k)
     return topk_ops.mixture_top_k(vocab_scores, attn_dist, p_gen, ext_ids, k,
-                                  vocab_scores.shape[-1] + hps.max_oov_buckets)
+                                  vocab_scores.shape[-1] + hps.max_oov_buckets,
+                                  art_scores)
+
+
+def head_at(hps: HParams, w: Array, v: Array, ext_ids: Array,
+            ) -> Optional[topk_ops.ArticleHead]:
+    """A family's ``beam_head`` over its own projection ``x @ w + v``:
+    the head's columns at the articles' ids [.., T_enc], which a search
+    gathers ONCE before its loop and hands to every step (the ids do not
+    change while an article decodes); None where the step would read
+    none (ops/topk.head_at), and then the step is as without."""
+    if not hps.pointer_gen:
+        return None
+    return topk_ops.head_at(w, v, ext_ids, 2 * hps.beam_size)
+
+
+def head_scores(hps: HParams, x: Array,
+                head: Optional[topk_ops.ArticleHead]) -> Optional[Array]:
+    """The vocabulary scores of x [K, H] at the article's ids [K,
+    T_enc], by the projection the row itself comes from (``_proj``, so
+    the configuration's precision), onto ``head_at``'s columns."""
+    if head is None:
+        return None
+    return _proj(hps, x, head.w.T) + head.v
 
 
 @jax.named_scope("vocab_dist")
 def _vocab_scores(params: Params, hps: HParams, cell_out: Array,
                   context: Array, new_state: Tuple[Array, Array], x: Array,
-                  ) -> Tuple[Array, Array]:
+                  head: Optional[topk_ops.ArticleHead] = None,
+                  ) -> Tuple[Array, Array, Optional[Array]]:
     """The decode step's output head, shared by decode_onestep and
     decode_onestep_shared: p_gen and the output projection.  Returns
-    (vocab_scores, p_gen); ``step_top_k`` normalises and ranks."""
+    (vocab_scores, p_gen, ``head_scores``); ``step_top_k`` normalises
+    and ranks."""
     dp = params["decoder"]
     p_gen = jax.nn.sigmoid(
         _linear(dp["pgen_linear"], context, new_state[0], new_state[1], x))[:, 0]
     output = _linear(dp["output_linear"], cell_out, context)
     vocab_scores = _proj(hps, output, params["output_projection"]["w"]) + \
         params["output_projection"]["v"]
-    return vocab_scores, p_gen
+    return vocab_scores, p_gen, head_scores(hps, output, head)
 
 
 def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
@@ -398,8 +425,8 @@ def decode_onestep(params: Params, hps: HParams, enc: EncoderOutput,
         context, attn_dist, _ = attn_ops.attend(
             dp["attention"], enc.enc_states, enc.enc_features,
             enc_padding_mask, new_state, cov if use_cov else None, use_cov)
-    vocab_scores, p_gen = _vocab_scores(params, hps, cell_out, context,
-                                        new_state, x)
+    vocab_scores, p_gen, _ = _vocab_scores(params, hps, cell_out, context,
+                                           new_state, x)
     k = 2 * hps.beam_size  # model.py:284 (batch_size==beam_size there)
     topk_probs, topk_ids = step_top_k(hps, vocab_scores, attn_dist, p_gen,
                                       enc_batch_extend_vocab, k)
@@ -413,7 +440,9 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
                           enc_mask: Array, ext_ids: Array,
                           latest_tokens: Array, state: Tuple[Array, Array],
                           prev_coverage: Array,
-                          nb: Optional[Array] = None) -> DecodeStepOutput:
+                          nb: Optional[Array] = None,
+                          head: Optional[topk_ops.ArticleHead] = None,
+                          ) -> DecodeStepOutput:
     """decode_onestep with the PER-ARTICLE encoder view shared across
     the K beam hypotheses (decode byte diet, ISSUE 7): enc_one leaves
     are [T_enc, ...] with no hypothesis axis, enc_mask/ext_ids [T_enc].
@@ -427,7 +456,11 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
     ``nb`` (length-masked slot decode, ISSUE 11): traced active-block
     count routing both attends through the blocked conditional chain
     (ops/attention._attend_shared_blocked) so per-step encoder traffic
-    scales with the longest active resident's true length."""
+    scales with the longest active resident's true length.
+
+    ``head``: the article's ``beam_head`` from a caller that decodes in
+    a loop; the article's words are then scored by a product with it
+    and not by a gather from the step's row (ops/topk.py)."""
     dp = params["decoder"]
     use_cov = hps.coverage
     block = config_lib.resolve_enc_block(hps) if nb is not None else 0
@@ -447,10 +480,10 @@ def decode_onestep_shared(params: Params, hps: HParams, enc_one: EncoderOutput,
             dp["attention"], enc_one.enc_states, enc_one.enc_features,
             enc_mask, new_state, cov if use_cov else None, use_cov,
             nb=nb, block=block)
-    vocab_scores, p_gen = _vocab_scores(params, hps, cell_out, context,
-                                        new_state, x)
+    vocab_scores, p_gen, art_scores = _vocab_scores(
+        params, hps, cell_out, context, new_state, x, head)
     topk_probs, topk_ids = step_top_k(hps, vocab_scores, attn_dist, p_gen,
-                                      ext_ids, 2 * hps.beam_size)
+                                      ext_ids, 2 * hps.beam_size, art_scores)
     return DecodeStepOutput(topk_ids=topk_ids,
                             topk_log_probs=jnp.log(topk_probs),
                             state=new_state, attn_dist=attn_dist, p_gen=p_gen,
@@ -480,9 +513,17 @@ def beam_encode(params: Params, hps: HParams, arrays: Dict[str, Array],
     return run_encoder(params, hps, arrays)
 
 
+def beam_head(params: Params, hps: HParams, ext_ids: Array,
+              ) -> Optional[topk_ops.ArticleHead]:
+    """The output projection at the articles' ids (``head_at``)."""
+    op = params["output_projection"]
+    return head_at(hps, op["w"], op["v"], ext_ids)
+
+
 def beam_adapter(hps: HParams):
     """(init_state, step) closures implementing the beam protocol for the
-    LSTM pointer-generator.  State = decoder cell (c, h) + coverage."""
+    LSTM pointer-generator.  State = decoder cell (c, h) + coverage.
+    ``step``'s ``head`` is the article's ``beam_head``, or None."""
     K = hps.beam_size
 
     def init_state(params: Params, enc_one: EncoderOutput):
@@ -497,14 +538,14 @@ def beam_adapter(hps: HParams):
 
     def step(params: Params, enc_one: EncoderOutput, enc_mask: Array,
              ext_ids: Array, t: Array, latest: Array, state,
-             nb=None) -> BeamStepOut:
+             nb=None, head=None) -> BeamStepOut:
         del t  # the LSTM state carries all positional context
         # per-article encoder view handed through UN-broadcast (decode
         # byte diet): only cell state + coverage carry the K axis
         out = decode_onestep_shared(params, hps, enc_one, enc_mask, ext_ids,
                                     latest,
                                     (state["cell_c"], state["cell_h"]),
-                                    state["coverage"], nb=nb)
+                                    state["coverage"], nb=nb, head=head)
         return BeamStepOut(
             topk_ids=out.topk_ids, topk_log_probs=out.topk_log_probs,
             attn_dist=out.attn_dist, p_gen=out.p_gen,

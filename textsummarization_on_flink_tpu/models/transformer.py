@@ -381,23 +381,31 @@ def forward_train(params: Params, hps: HParams, arrays: Dict[str, Array],
     return train_output_tail(params, hps, arrays, h, cross_ctx, attn_dist)
 
 
+def _head_operands(params: Params, hps: HParams, h: Array,
+                   ) -> Tuple[Array, Array]:
+    """(x, w) with the vocabulary scores ``x @ w + out_bias``: the tied
+    embedding under h, or the factored head's w2 [r, V] under h @ w1."""
+    vh = params.get("vocab_head")
+    if vh is not None:
+        return loss_ops.project_scores(h, vh["w1"], hps.compute_dtype), \
+            vh["w2"]
+    return h, params["embedding"].T
+
+
 def vocab_scores_of(params: Params, hps: HParams, h: Array) -> Array:
     """Raw vocabulary scores for final-LN decoder states ``h``
     [..., H_dec]: the tied-embedding projection, or — when the family
     carries a factored low-rank head (the distilled narrow draft,
     ISSUE 12) — ``(h @ w1) @ w2`` with w1 [H_d, r], w2 [r, V], never
     materializing the [H_d, V] product.  ONE source: the train loss
-    head and every decode output tail route the projection through
-    here, so the two heads cannot drift.  Both factored matmuls route
+    head routes the projection through here and every decode output
+    tail through the same ``_head_operands``, so the two heads cannot
+    drift.  Both factored matmuls route
     through the ONE dtype-aware projection (ops/losses.project_scores,
     bf16 operands + f32 accumulation under compute_dtype=bfloat16) —
     same kernel as the tied branch and the streaming chunk bodies."""
-    vh = params.get("vocab_head")
-    if vh is not None:
-        hr = loss_ops.project_scores(h, vh["w1"], hps.compute_dtype)
-        return loss_ops.project_scores(hr, vh["w2"], hps.compute_dtype) \
-            + params["out_bias"]
-    return pg._proj(hps, h, params["embedding"].T) + params["out_bias"]
+    x, w = _head_operands(params, hps, h)
+    return pg._proj(hps, x, w) + params["out_bias"]
 
 
 def vocab_proj_weight(params: Params) -> Array:
@@ -586,23 +594,35 @@ def cross_attend_layer(hps: HParams, layer: Dict[str, Any], y: Array,
 @jax.named_scope("vocab_dist")
 def decode_output_tail(params: Params, hps: HParams, y: Array,
                        cross_ctx: Array, attn_dist: Array, ext_ids: Array,
-                       k: int) -> Tuple[Array, Array, Array, Array]:
+                       k: int, head=None,
+                       ) -> Tuple[Array, Array, Array, Array]:
     """Decoder output head shared by every transformer-shaped decode
     path (beam adapter step, ``spec_verify``, the AAN step): final LN,
-    vocab projection via ``vocab_scores_of`` (tied, or the narrow
-    draft's factored head), p_gen, and the k best of the pointer
+    vocab projection as ``vocab_scores_of`` makes it (tied, or the
+    narrow draft's factored head), p_gen, and the k best of the pointer
     mixture (``pg.step_top_k``; its selection over the vocabulary
-    carries the ``topk`` scope).  Returns (topk_probs [R, k], topk_ids
-    [R, k], p_gen [R], h [R, H_dec] f32)."""
+    carries the ``topk`` scope).  ``head``: the article's ``beam_head``
+    from a caller that decodes in a loop, or None.  Returns (topk_probs
+    [R, k], topk_ids [R, k], p_gen [R], h [R, H_dec] f32)."""
     h = _ln(params["decoder"]["ln_out"], y).astype(jnp.float32)
-    vocab_scores = vocab_scores_of(params, hps, h)
+    x, w = _head_operands(params, hps, h)
+    vocab_scores = pg._proj(hps, x, w) + params["out_bias"]
     p_gen = jax.nn.sigmoid(
         jnp.concatenate([h, cross_ctx.astype(jnp.float32)], axis=-1)
         @ params["pgen_linear"]["kernel"]
         + params["pgen_linear"]["bias"])[:, 0]
     topk_probs, topk_ids = pg.step_top_k(hps, vocab_scores, attn_dist, p_gen,
-                                         ext_ids, k)
+                                         ext_ids, k,
+                                         pg.head_scores(hps, x, head))
     return topk_probs, topk_ids, p_gen, h
+
+
+def beam_head(params: Params, hps: HParams, ext_ids: Array):
+    """The vocabulary head (``_head_operands``' w) at the articles' ids
+    (pg.head_at); the AAN family's too."""
+    vh = params.get("vocab_head")
+    w = vh["w2"] if vh is not None else params["embedding"].T
+    return pg.head_at(hps, w, params["out_bias"], ext_ids)
 
 
 def beam_adapter(hps: HParams):
@@ -632,10 +652,12 @@ def beam_adapter(hps: HParams):
         }
 
     def step(params: Params, enc_one: TransformerEncView, enc_mask: Array,
-             ext_ids: Array, t: Array, latest: Array, state, nb=None):
+             ext_ids: Array, t: Array, latest: Array, state, nb=None,
+             head=None):
         """enc_one leaves are per-article (no batch axis); latest: [K].
         nb: traced active-block count for the length-masked slot path
-        (None = dense cross-attention, the batch-search default)."""
+        (None = dense cross-attention, the batch-search default);
+        head: the article's ``beam_head``, or None."""
         y = _embed_dec(params, hps, latest, t)  # [K, H]
         pos_ok = (jnp.arange(T) <= t).astype(jnp.float32)  # [T]
         cache_k, cache_v = state["cache_k"], state["cache_v"]
@@ -670,7 +692,8 @@ def beam_adapter(hps: HParams):
             y = y + _ffn_block(layer["ffn"], _ln(layer["ln2"], y))
             cross_ctx = cross_out
         topk_probs, topk_ids, p_gen, _ = decode_output_tail(
-            params, hps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size)
+            params, hps, y, cross_ctx, attn_dist, ext_ids, 2 * hps.beam_size,
+            head)
         return BeamStepOut(topk_ids=topk_ids,
                            topk_log_probs=jnp.log(topk_probs + 1e-10),
                            attn_dist=attn_dist, p_gen=p_gen,

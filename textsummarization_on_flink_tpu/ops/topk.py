@@ -38,9 +38,20 @@ a row are ranked as (value, id) pairs, and the extended row
 around them, half of the slot step before ISSUE 31 — is never built.
 ``extended_mixture`` builds it: the definition, and the path for a
 vocabulary too short for the candidates to win (``_mixture_plan``).
+
+An article word's vocabulary share needs its score, and a gather of
+102 400 of them from the [256, 4, 50 000] block was 2.0 ms of every
+step with 0.6 more for the relayout XLA makes for it (XLA:TPU gathers
+at 18-19 ns an index whatever the form; PERF.md section 6, "PR 33").
+The ids do not change while an article decodes, so a search gathers the
+output head's COLUMNS at them once before its loop (``head_at``), its
+step projects onto those beside the row (``output . W[:, w] + v[w]``,
+the same operands through the same matmul), and ``mixture_top_k`` takes
+the scores from the caller.  A caller with no loop passes none and the
+scores are gathered from the row.
 """
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -132,6 +143,35 @@ def _mixture_plan(v: int, t_enc: int, k: int) -> str:
     return "dense"
 
 
+class ArticleHead(NamedTuple):
+    """The output head at an article's ids (``head_at``)."""
+
+    w: Array  # [.., T_enc, H]: the projection's columns, one a row
+    v: Array  # [.., T_enc]: the bias
+
+
+def _in_row(ext_ids: Array, v: int) -> Array:
+    """An article's ids as indices into the vocabulary's row: an OOV
+    id reads place 0, and ``_mixture_candidates`` masks what it read."""
+    return jnp.where(ext_ids < v, ext_ids, 0)
+
+
+def head_at(w: Array, v: Array, ext_ids: Array, k: int,
+            ) -> Optional[ArticleHead]:
+    """The columns of the output head ``scores = x @ w + v`` (w [H, V],
+    v [V]) at the articles' ids [.., T_enc], for a step that ranks k:
+    loop-invariant, so a search gathers them once and its step gives
+    ``mixture_top_k`` the article-side scores ``x @ head.w.T + head.v``.
+    None where ``_mixture_plan`` builds the row, which reads no score
+    by id.  Rows of the transposed matrix: 1 KB an index."""
+    if k <= 0 or _mixture_plan(w.shape[-1], ext_ids.shape[-1],
+                               k) != "candidates":
+        return None
+    at = _in_row(ext_ids, w.shape[-1])
+    with jax.named_scope("vocab_dist"):
+        return ArticleHead(w=w.T[at], v=v[at])
+
+
 def extended_mixture(vocab_dist: Array, attn_dist: Array, p_gen: Array,
                      ext_ids: Array, ext_size: int) -> Array:
     """The pointer mixture over the extended vocabulary [B, ext_size]
@@ -151,6 +191,7 @@ def extended_mixture(vocab_dist: Array, attn_dist: Array, p_gen: Array,
 
 def _mixture_candidates(vocab_scores: Array, attn_dist: Array, p_gen: Array,
                         ext_ids: Array, k: int, ext_size: int,
+                        art_scores: Optional[Array] = None,
                         ) -> Tuple[Array, Array]:
     V = vocab_scores.shape[-1]
     dtype = vocab_scores.dtype
@@ -158,8 +199,8 @@ def _mixture_candidates(vocab_scores: Array, attn_dist: Array, p_gen: Array,
     # (under mixture_top_k's ``vocab_dist`` scope, all but the selection)
     # jax.nn.softmax's own arithmetic, with its two row statistics kept:
     # a word's probability is exp(score - top) / mass wherever it is
-    # computed, so the article's few are made from their gathered
-    # SCORES and no normalised row is written out for a gather
+    # computed, so the article's few are made from their SCORES (the
+    # caller's, or gathered here) and no normalised row is written out
     top = jnp.max(vocab_scores, axis=-1, keepdims=True)
     unnormalized = jnp.exp(vocab_scores - top)
     mass = jnp.sum(unnormalized, axis=-1, keepdims=True)
@@ -175,11 +216,12 @@ def _mixture_candidates(vocab_scores: Array, attn_dist: Array, p_gen: Array,
     same = ext_ids[..., :, None] == ext_ids[..., None, :]  # [.., T, T]
     copy = jnp.sum(jnp.where(same, copy[:, None, :], 0), axis=-1)
     in_vocab = ext_ids < V
-    at = jnp.where(in_vocab, ext_ids, 0)
-    if ext_ids.ndim == 1:  # one article under all rows: one gather
-        base = jnp.take(vocab_scores, at, axis=-1)
+    if art_scores is not None:  # the caller projected onto head_at's
+        base = art_scores
+    elif ext_ids.ndim == 1:  # one article under all rows: one gather
+        base = jnp.take(vocab_scores, _in_row(ext_ids, V), axis=-1)
     else:
-        base = jnp.take_along_axis(vocab_scores, at, axis=-1)
+        base = jnp.take_along_axis(vocab_scores, _in_row(ext_ids, V), -1)
     base = (p_gen[:, None] * (jnp.exp(base - top) / mass)).astype(dtype)
     base = jnp.where(in_vocab, base, 0)
     # an id past the extended row has no place in it (the scatter
@@ -196,21 +238,27 @@ def _mixture_candidates(vocab_scores: Array, attn_dist: Array, p_gen: Array,
 
 def mixture_top_k(vocab_scores: Array, attn_dist: Array, p_gen: Array,
                   ext_ids: Array, k: int, ext_size: int,
+                  art_scores: Optional[Array] = None,
                   ) -> Tuple[Array, Array]:
     """``top_k(extended_mixture(softmax(vocab_scores), attn_dist, p_gen,
     ext_ids, ext_size), k)``: the same ids in the same order, the values
     equal up to the order in which one id's copy mass is summed (which
-    the scatter-add leaves open too) — from the candidates where
-    ``_mixture_plan`` says so (module docstring).
+    the scatter-add leaves open too), and up to the float32 accumulation
+    order of an article word's logit, when the caller supplies it — from
+    the candidates where ``_mixture_plan`` says so (module docstring).
     vocab_scores [B, V] are the output projection's, before the
-    softmax; the other shapes as ``extended_mixture``'s.  The
+    softmax; the other shapes as ``extended_mixture``'s.  art_scores
+    [B, T_enc], optional: the same projection's scores at the article's
+    ids, from ``head_at``'s columns (an OOV id's place is masked here,
+    and the row's own top and mass normalise them); without them they
+    are gathered from the row.  The
     vocabulary-wide selection carries the ``topk`` named scope and the
     rest the ``vocab_dist`` scope, whichever path is taken."""
     V, T = vocab_scores.shape[-1], attn_dist.shape[-1]
     with jax.named_scope("vocab_dist"):
         if k > 0 and _mixture_plan(V, T, k) == "candidates":
             return _mixture_candidates(vocab_scores, attn_dist, p_gen,
-                                       ext_ids, k, ext_size)
+                                       ext_ids, k, ext_size, art_scores)
         row = extended_mixture(jax.nn.softmax(vocab_scores, axis=-1),
                                attn_dist, p_gen, ext_ids, ext_size)
         with jax.named_scope("topk"):
