@@ -139,6 +139,10 @@ def serve_numbers(cfg: Dict[str, Any], seed: int, finished: Sequence[Any],
                  (3% of searches, PERF.md): the widest swings with that
                  from seed to seed, the median does not, so it is held
                  tightly and the widest loosely
+    The two beam numbers are there only where the cell samples a search
+    (`check.sample.beam` >= 1): one cacheless search of a large model can
+    cost more than the window, and a number that was not measured is left
+    out, so that `judge` fails a limit kept for it.
     """
     import jax
     import jax.numpy as jnp
@@ -166,16 +170,17 @@ def serve_numbers(cfg: Dict[str, Any], seed: int, finished: Sequence[Any],
         served = np.asarray([r.avg_log_prob for _, r in picked])
     gaps = np.abs(served - r_avg)
     i = int(np.argmax(gaps))
-    return {"score_gap": float(gaps[i]),
-            "beam_gap": float(max(beam_gaps)) if beam_gaps else 0.0,
-            "beam_gap_median": (float(statistics.median(beam_gaps))
-                                if beam_gaps else 0.0),
-            "_detail": {"sampled": len(picked),
-                        "served_tokens": int(sum(len(o) for o in outs)),
-                        "worst_uuid": picked[i][0].uuid,
-                        "worst_served": float(served[i]),
-                        "worst_reference": float(r_avg[i]),
-                        "beam_gaps": beam_gaps}}
+    numbers = {"score_gap": float(gaps[i])}
+    if beam_gaps:
+        numbers.update(beam_gap=float(max(beam_gaps)),
+                       beam_gap_median=float(statistics.median(beam_gaps)))
+    numbers["_detail"] = {"sampled": len(picked),
+                          "served_tokens": int(sum(len(o) for o in outs)),
+                          "worst_uuid": picked[i][0].uuid,
+                          "worst_served": float(served[i]),
+                          "worst_reference": float(r_avg[i]),
+                          "beam_gaps": beam_gaps}
+    return numbers
 
 
 def judge(numbers: Dict[str, Any], limits: Dict[str, float],

@@ -1,11 +1,17 @@
 """One module a model family, found by a configuration's "family" key
 (`reference.family(name)` imports `harness.families.<name>`).  Everything
 the harness knows about a family lives in its module; nothing outside
-this directory branches on a family's name.  A module imports nothing of
-the program and holds:
+this directory, the benchmark's tests included, branches on a family's,
+a configuration's or a cell's name.  A module imports nothing of the
+program and holds:
 
   param_specs(hp)      {leaf path: (shape, init kind)} in the program's
-                       parameter layout (weights.py draws the kinds)
+                       parameter layout.  weights.py draws the kinds:
+                       `matrix` / `lstm` / `vocab` normal(0, gain /
+                       sqrt(shape[0])); `stacked`, for experts or layers
+                       stacked on leading axes, normal(0, gain /
+                       sqrt(shape[-2])); `embedding` / `tied_embedding`,
+                       `vector`, `vocab_bias`, `ones`, `zeros`
   encode, decode,      the plain reference (reference.py has what the
   LOG_EPS              families share: mixture, loss, Adagrad, beam search)
   token_logprobs,      optional, in place of reference.py's pointer
@@ -18,12 +24,39 @@ the program and holds:
   forward_macs_per_row, decode_step_macs_per_hyp, beam_state_bytes,
   enc_view_bytes, prefill_macs_and_weights
                        what counts.py's train_step / slot_chunk / prefill
-                       are composed of
+                       are composed of (`counts.prefill` reads
+                       hp["hidden_dim"]: a family names its width that)
   count_<name>(hp, dep, ctx)
                        any further count a metric file may name
                        (readers.py resolves a name it does not know here)
   wire, length_code,   optional: how seed-made weights end a summary at
-  word_for_length      the length the article's first word codes
-                       (init.summary_clock); a configuration that asks
-                       for a clock its family does not offer is an error
+  word_for_length,     the length the article's first word codes
+  MID_CLOCK            (init.summary_clock); a configuration that asks
+                       for a clock its family does not offer is an error.
+                       MID_CLOCK is data for the tests: the `init` keys
+                       (`stop_bias`, `summary_clock`) of the clock at
+                       tests/tiny.py's middle size.  A family with no
+                       unit that can count decode steps offers none of
+                       the four; its configurations have no
+                       `init.summary_clock`, its mixes no `summary` block,
+                       and every summary it serves is `max_dec_steps` long
+
+What a configuration and a cell state of their own, beside the family:
+
+  param_dtype          the type the parameters are made and served in
+  rehearse             sizes for `run.py --rehearse 1` and the CPU tests,
+                       laid over benchmark/rehearse.json's: `hparams`
+                       (the family's own widths, which the shared blocks
+                       do not know: ranks, expert counts, a selection's k
+                       small enough that it still cuts), `deployment`,
+                       `init`.  The tests' shrinkers (tests/tiny.py
+                       `tiny_config`, `mid_config`) cut the widths they
+                       know and take `rehearse.hparams` for the rest
+  check.sample         (the cell's file) how many finished requests the
+                       reference scores (`score` >= 1, always) and how
+                       many it searches itself (`beam`; 0 where one
+                       cacheless search costs more than the window).
+                       `correct.serve_numbers` gives `beam_gap` and
+                       `beam_gap_median` only where `beam` >= 1, and the
+                       cell's `limits` hold them if and only if it does
 """

@@ -175,6 +175,15 @@ def param_specs(hp: Dict[str, Any]) -> Dict[str, Any]:
 
 # ------------------------------------------------------ the summary clock
 
+# The clock at the benchmark tests' middle size (tests/tiny.py MID): what
+# a test lays over the `init` of a configuration that asks for a clock.
+# It codes the lengths 5..24, and test_control.py holds every summary to
+# within 3 tokens of the length coded.
+MID_CLOCK = {"stop_bias": -12.6,
+             "summary_clock": {"units": 4, "gain": 24.0, "step": 0.03,
+                               "phase": 0.002, "c_star": 1.0, "codes": 20,
+                               "min_tokens": 5}}
+
 def length_code(clock: Dict[str, Any], ids) -> Any:
     """The summary length (tokens, STOP included) that word id `ids`
     codes for as an article's first word."""

@@ -9,15 +9,17 @@ checkpoint uses), because that is the interface the weights cross; it is
 the family module's `param_specs(hp)` (harness/families/).
 
 Init (configs/<config>.json "init"): matrices normal(0, gain/sqrt(fan_in)),
-embeddings and position tables normal(0, embedding_std), layer-norm scale
-1, biases 0.  Every leaf is drawn in float32 and rounded ONCE to the
-parameter type, a leaf at a time inside the one program, so that a tree
-of several GB is never held twice.
+fan_in the first axis, or for the kind `stacked` (experts or layers
+stacked on leading axes) the last but one; embeddings and position tables
+normal(0, embedding_std), layer-norm scale 1, biases 0.  Every leaf is
+drawn in float32 and rounded ONCE to the parameter type, a leaf at a time
+inside the one program, so that a tree of several GB is never held twice.
 
 `init.summary_clock` asks the family to wire, into the tree it is handed,
 the way a summary ends at the length the article's first word codes
 (the family's `wire`, `length_code`, `word_for_length`); a family that
-does not offer it is an error.
+does not offer it is an error.  A configuration with no clock is served
+as it is: every summary then runs to `max_dec_steps`.
 """
 
 from __future__ import annotations
@@ -93,10 +95,12 @@ def make_params(cfg: Dict[str, Any], seed: int):
             elif kind == "vector":
                 x = float(init.get("vector_std", 0.05)) * jax.random.normal(
                     k, shape, jnp.float32)
-            else:  # matrix / lstm / vocab: normal(0, gain / sqrt(fan_in))
+            else:  # matrix / lstm / vocab / stacked: normal(0, gain /
+                # sqrt(fan_in)); stacked matrices [..., fan_in, fan_out]
                 gain = float(init.get(f"{kind}_gain",
                                       init.get("matrix_gain", 1.0)))
-                x = (gain / np.sqrt(shape[0])) * jax.random.normal(
+                fan_in = shape[-2] if kind == "stacked" else shape[0]
+                x = (gain / np.sqrt(fan_in)) * jax.random.normal(
                     k, shape, jnp.float32)
             out.append(x)
         tree = jax.tree_util.tree_unflatten(treedef, out)

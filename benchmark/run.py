@@ -86,7 +86,13 @@ def apply_rehearsal(cfg, mix, cell_file):
         mix["summary"].update(tiny["summary"])
     if "summary_clock" in cfg["init"]:
         cfg["init"].update(tiny["init"])
+    # the rehearsal's sample caps the cell's own: a cell that samples no
+    # search of the reference's (`beam` 0) rehearses none either
+    asked = cell_file["check"].get("sample", {})
     cell_file["check"].update(tiny["check"])
+    cell_file["check"]["sample"] = {
+        k: min(v, int(asked.get(k, v)))
+        for k, v in tiny["check"]["sample"].items()}
     # then the configuration's own rehearsal sizes: a family with widths
     # of its own shrinks them in its own file
     own = cfg.get("rehearse", {})

@@ -1,8 +1,9 @@
 """A new configuration, traffic mix, cell and per-layer metric over an
-existing source kind, and a new MODEL FAMILY with a parameter type, a
-rehearsal block and a count of its own, are added by new files and new
-entries alone: done here in a temporary copy, and the added cells
-rehearse."""
+existing source kind, a new MODEL FAMILY with a parameter type, a
+rehearsal block and a count of its own, and a SERVING CELL OF A FAMILY
+WITH NO SUMMARY CLOCK are added by new files and new entries alone: done
+here in a temporary copy; the added cells rehearse, and the last passes
+the copy's own control, fault and file tests."""
 import json
 import os
 import shutil
@@ -62,6 +63,7 @@ def test_add_a_cell_and_a_metric_by_files_alone(tmp_path):
     json.dump({"name": "pg_other_even",
                "check": {"sample": {"score": 4, "beam": 1}},
                "limits": {"score_gap": 1e-3, "beam_gap": 1e-3,
+                          "beam_gap_median": 1e-3,
                           "compiles_in_window": 0}},
               open(nb / "workloads" / "pg_other_even.json", "w"))
     metric = {"name": "refills_per_s.even", "unit": "1/s",
@@ -163,6 +165,7 @@ def count_vocab_rows(hp, dep, ctx):
     json.dump({"name": "pg_third_even",
                "check": {"sample": {"score": 4, "beam": 1}},
                "limits": {"score_gap": 1e-3, "beam_gap": 1e-3,
+                          "beam_gap_median": 1e-3,
                           "compiles_in_window": 0}},
               open(nb / "workloads" / "pg_third_even.json", "w"))
     layer = "models and kernels (models/, ops/)"
@@ -200,5 +203,95 @@ def count_vocab_rows(hp, dep, ctx):
     assert abs(got.pop("value") - 5.12 / 37.5 * 100.0) < 1e-9
     assert got == {"hidden_dim": 24, "slots": 3, "units": 3,
                    "dtype": "float32"}
+    for rel, data in before.items():
+        assert open(nb / rel, "rb").read() == data, rel
+
+
+# Limits of the throw-away clockless cells, set from readings here on the
+# CPU in float32 (PR 34; never a chip's).  `score_gap`: sound 4.4e-7 at
+# the middle size and 4e-8 to 2.4e-7 rehearsed, the bfloat16 reference
+# 2.8e-3, a swapped token 0.59 to 1.23.  The beam numbers: sound under
+# 3.3e-7, one altered answer 0.53, five 0.67 (median 0.56), a swapped
+# token 0.21 to 0.75.
+CLOCKLESS_LIMITS = {"score_gap": 3e-4, "compiles_in_window": 0}
+CLOCKLESS_BEAM_LIMITS = {"beam_gap": 0.05, "beam_gap_median": 0.01}
+
+
+def test_add_a_serving_cell_of_a_clockless_family_by_files_alone(tmp_path):
+    """What the next model's serving cell looks like: a family module
+    that offers no `wire` (the transformer's, which the program serves
+    through the slot engine), a configuration with no
+    `init.summary_clock` and a `rehearse` block of its own, a mix with no
+    `summary` block, so that every summary runs to `max_dec_steps`, and a
+    cell that samples no search of the reference's own (`beam` 0) and
+    holds `score_gap` alone (`tf_served_b0`); a second cell of the same
+    configuration and mix samples one (`beam` 1) and holds the beam
+    numbers too (`tf_served_b1`; a pair of configuration and traffic
+    appears once in BENCHMARK.json, so it has a copy of the mix under
+    another name).  Files and entries only.  Both cells rehearse
+    `correct`, and the COPY's own control, fault and file tests pass over
+    them and over the accepted cells, with no file that was there
+    changed."""
+    before = _copy(tmp_path)
+    b = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    nb = tmp_path / "benchmark"
+    served = [w["name"] for w in b["workloads"] if json.load(open(
+        nb / "traffic" / (w["traffic"] + ".json")))["kind"] == "open_loop"]
+    cfg = json.load(open(nb / "configs" / "tf_cnndm.json"))
+    assert "summary_clock" not in cfg["init"]
+    cfg["name"] = "tf_served"
+    cfg["deployment"]["serve"] = {
+        "serve_mode": "continuous", "serve_slots": 64,
+        "serve_max_queue": 4096, "serve_buckets": "100,200,400"}
+    cfg["rehearse"] = {"hparams": {"hidden_dim": 24, "ffn_dim": 48},
+                       "deployment": {"serve": {"serve_slots": 3}}}
+    json.dump(cfg, open(nb / "configs" / "tf_served.json", "w"))
+    b["configs"].append({"name": "tf_served", "source": cfg["source"],
+                         "file": "benchmark/configs/tf_served.json",
+                         "reduced": [], "why": "throw-away"})
+    mix = json.load(open(nb / "traffic" / "news_open_loop.json"))
+    del mix["summary"]
+    added = {"tf_served_b0": 0, "tf_served_b1": 1}
+    for cell, beam in added.items():
+        json.dump(mix, open(nb / "traffic" / f"news_full_length{beam}.json",
+                            "w"))
+        json.dump({"name": cell,
+                   "check": {"sample": {"score": 4, "beam": beam}},
+                   "limits": dict(CLOCKLESS_LIMITS, **(
+                       CLOCKLESS_BEAM_LIMITS if beam else {}))},
+                  open(nb / "workloads" / f"{cell}.json", "w"))
+        b["workloads"].append({
+            "name": cell, "config": "tf_served",
+            "traffic": f"news_full_length{beam}", "chips": 1,
+            "why": "throw-away: every summary max_dec_steps"})
+    # the cells report the two summary percentiles and, for the copy's
+    # file tests, the served cells' per-layer metrics
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].extend(added)
+    json.dump(b, open(tmp_path / "BENCHMARK.json", "w"))
+    for cell in added:
+        _rehearse(tmp_path, cell)
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-rA", "benchmark/tests/test_control.py",
+         "benchmark/tests/test_faults.py", "benchmark/tests/test_files.py"],
+        cwd=str(tmp_path), capture_output=True, text=True, timeout=3000,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=""))
+    assert p.returncode == 0, p.stdout[-6000:] + p.stderr[-2000:]
+    passed = [line.split()[1] for line in p.stdout.splitlines()
+              if line.startswith("PASSED ")]
+
+    def ran(test, cell):
+        return any(f"::{test}[" in t and cell in t for t in passed)
+
+    for cell in served + list(added):
+        assert ran("test_serving_control_fails_the_limits", cell), passed
+        assert ran("test_a_token_altered_where_it_is_produced", cell), passed
+    # one altered answer is held against the cells that hold a beam number
+    altered = "test_an_altered_answer_fails_a_beam_number"
+    assert all(ran(altered, cell) for cell in served), passed
+    for cell, beam in added.items():
+        assert ran(altered, cell) == bool(beam), passed
     for rel, data in before.items():
         assert open(nb / rel, "rb").read() == data, rel

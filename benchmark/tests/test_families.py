@@ -16,6 +16,7 @@ import pytest
 
 import run as bench_run
 from harness import counts, readers, reference, traffic, weights
+from tests import tiny
 from tests.tiny import BENCH, tiny_config
 
 
@@ -144,6 +145,43 @@ def test_a_configuration_carries_its_own_rehearsal_sizes():
     assert dep["serve_slots"] == 3 and dep["serve_buckets"] == "8,16"
     assert cfg["init"]["stop_bias"] == -5.0
     assert cfg["init"]["summary_clock"]["codes"] == 6  # the shared block's
+
+
+def test_the_tests_shrinkers_take_a_configurations_own_sizes(
+        tmp_path, monkeypatch):
+    """`tiny_config` and `mid_config` cut the keys they know and take the
+    configuration's `rehearse.hparams` for the rest: a width they do not
+    know is never run at its published size, and a selection still
+    cuts."""
+    cfg = _config("pg_see2017")
+    cfg["hparams"].update(kv_lora_rank=512, index_topk=2048)
+    cfg["rehearse"] = {"hparams": {"kv_lora_rank": 8, "index_topk": 4}}
+    os.makedirs(tmp_path / "configs")
+    with open(tmp_path / "configs" / "own_widths.json", "w") as f:
+        json.dump(cfg, f)
+    monkeypatch.setattr(tiny, "BENCH", str(tmp_path))
+    for shrunk, sizes in ((tiny.tiny_config("own_widths"), tiny.TINY),
+                          (tiny.mid_config("own_widths"), tiny.MID)):
+        hp = shrunk["hparams"]
+        assert (hp["hidden_dim"], hp["kv_lora_rank"], hp["index_topk"]) == (
+            sizes["hidden_dim"], 8, 4)
+        assert hp["index_topk"] < hp["max_enc_steps"]  # the selection cuts
+
+
+def test_stacked_leaves_take_their_fan_in_from_the_last_axis_but_one(
+        monkeypatch):
+    """Experts stacked on a leading axis are each drawn as a matrix of
+    their own: normal(0, gain / sqrt(shape[-2]))."""
+    fam = types.ModuleType("harness.families._stacked")
+    fam.param_specs = lambda hp: {"dense": ((256, 64), "matrix"),
+                                  "experts": ((6, 256, 64), "stacked")}
+    monkeypatch.setattr(reference, "family", lambda name: fam)
+    tree = weights.make_params({"family": "_stacked", "hparams": {},
+                                "init": {"matrix_gain": 2.0}}, 2 ** 31 + 11)
+    want = 2.0 / np.sqrt(256)
+    assert float(jnp.std(tree["dense"])) == pytest.approx(want, rel=0.03)
+    per_expert = np.asarray(jnp.std(tree["experts"], axis=(1, 2)))
+    assert per_expert == pytest.approx(np.full(6, want), rel=0.03)
 
 
 def test_a_familys_own_distributions_replace_the_pointer_mixture():

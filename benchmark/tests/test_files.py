@@ -7,7 +7,7 @@ import re
 
 import numpy as np
 
-from tests.tiny import BENCH
+from tests.tiny import BENCH, cell_file, cells
 
 ROOT = os.path.dirname(BENCH)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -142,3 +142,20 @@ def test_cross_references():
             coded = set(fam.length_code(clock, np.arange(
                 4, int(cfg["hparams"]["vocab_size"]))).tolist())
             assert set(range(spec["min"], spec["max"] + 1)) <= coded
+
+
+def test_a_serving_cell_holds_the_numbers_it_samples():
+    """Every served cell has the reference score some of what it served
+    (`check.sample.score` >= 1 and a `score_gap` limit); it holds the two
+    beam numbers if and only if it samples a search of the reference's
+    own (`check.sample.beam` >= 1): a limit on a number never measured
+    fails every run, a number measured and not held catches nothing."""
+    served = cells("open_loop")
+    assert served
+    for cell, _ in served:
+        cf = cell_file(cell)
+        sample, limits = cf["check"]["sample"], cf["limits"]
+        assert int(sample["score"]) >= 1 and "score_gap" in limits, cell
+        beam = {"beam_gap", "beam_gap_median"} & set(limits)
+        assert beam == ({"beam_gap", "beam_gap_median"}
+                        if int(sample["beam"]) >= 1 else set()), cell

@@ -1,41 +1,90 @@
 """Tiny configurations for the CPU tests: every size cut, never used on
-the chip."""
-import copy
+the chip; and the cells, as the tests read them out of BENCHMARK.json."""
 import json
 import os
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
 
 TINY = {"hidden_dim": 16, "emb_dim": 8, "vocab_size": 64,
         "max_enc_steps": 12, "max_dec_steps": 8, "beam_size": 2,
         "min_dec_steps": 2, "max_oov_buckets": 4, "num_heads": 2,
         "ffn_dim": 32, "enc_layers": 2, "dec_layers": 2}
+# the middle size: what the control tests (test_control.py) run the plain
+# reference at, wide enough for bfloat16 to part from float32
+MID = {"hidden_dim": 64, "emb_dim": 32, "vocab_size": 2000,
+       "max_enc_steps": 64, "max_dec_steps": 24, "beam_size": 2,
+       "min_dec_steps": 4, "max_oov_buckets": 8, "num_heads": 4,
+       "ffn_dim": 128, "enc_layers": 2, "dec_layers": 2}
+
+
+def _load(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+def _shrink(hp, sizes):
+    hp.update({k: v for k, v in sizes.items() if k in hp})
 
 
 def tiny_config(name: str):
-    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
-        cfg = json.load(f)
-    cfg = copy.deepcopy(cfg)
-    for k, v in TINY.items():
-        if k in cfg["hparams"]:
-            cfg["hparams"][k] = v
+    """The configuration with TINY over the keys it has, then its own
+    `rehearse.hparams` (as `run.apply_rehearsal` takes them): a family
+    with widths of its own shrinks them in its own file."""
+    cfg = _load(BENCH, "configs", f"{name}.json")
+    _shrink(cfg["hparams"], TINY)
+    cfg["hparams"].update(cfg.get("rehearse", {}).get("hparams", {}))
     return cfg
 
 
-ROOT = os.path.dirname(BENCH)
+def mid_config(name: str):
+    """The tiny configuration with MID over the keys it has: the widths
+    TINY and MID do not know stay at their rehearsal size."""
+    cfg = tiny_config(name)
+    _shrink(cfg["hparams"], MID)
+    return cfg
+
+
+def held_cells():
+    """The held cells' files (benchmark/held/*.json), in name order."""
+    import glob
+
+    return [_load(p) for p in sorted(glob.glob(
+        os.path.join(BENCH, "held", "*.json")))]
+
+
+def _entries(held):
+    return ([h["workload"] for h in held_cells()] if held
+            else _load(ROOT, "BENCHMARK.json")["workloads"])
+
+
+def mix_file(traffic_name):
+    return _load(BENCH, "traffic", traffic_name + ".json")
+
+
+def cell_file(cell):
+    return _load(BENCH, "workloads", cell + ".json")
+
+
+def cells(kind, held=False):
+    """(cell, configuration) of BENCHMARK.json's cells (or of the held
+    cells, benchmark/held/) whose mix is of `kind`."""
+    return [(w["name"], w["config"]) for w in _entries(held)
+            if mix_file(w["traffic"])["kind"] == kind]
+
+
+def traffic_of(cell):
+    """The traffic file of a cell, BENCHMARK.json's or held."""
+    return mix_file({w["name"]: w["traffic"] for w in
+                     _entries(False) + _entries(True)}[cell])
 
 
 def benchmark_with_held(tmp_dir) -> str:
     """A BENCHMARK.json in tmp_dir holding the real entries and those of
     the held cells (benchmark/held/*.json), so that the code a held cell
     runs stays under test.  Returns its path."""
-    import glob
-
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        b = json.load(f)
-    for path in sorted(glob.glob(os.path.join(BENCH, "held", "*.json"))):
-        with open(path) as f:
-            h = json.load(f)
+    b = _load(ROOT, "BENCHMARK.json")
+    for h in held_cells():
         b["configs"].append(h["config"])
         b["workloads"].append(h["workload"])
         b["end_to_end"][-1:-1] = h["end_to_end"]
