@@ -3,15 +3,29 @@ against the plain reference, each number beside a limit of its own (the
 limits are data, in the cell's file under benchmark/workloads/, and
 PERF.md gives the readings each was set from).
 
-The reference computes as the configurations state: float32 arrays,
-matmuls at the chip's default precision (which on a TPU rounds their
-inputs to bfloat16 and accumulates in float32).  The control
-(`control=True`) puts the reference in the program's place in the nearest
-precision below that, bfloat16 parameters AND activations (recurrent
-state, residual stream, layer norms, softmax inputs), and must come out
-as not correct.  The program's own lower path (`compute_dtype=bfloat16`)
-is read against the same limits by benchmark/tools/probe.py (PERF.md
-gives both readings for every number).
+The activations' type is stated on every call of the reference here, apart
+from the type the configuration stores its parameters in (`param_dtype`):
+the rule is in harness/families/__init__.py.  The SOUND reference keeps
+float32 activations for every configuration, over the leaves as they are
+stored, each widened where it is read, with matmuls at the chip's default
+precision (which on a TPU rounds their inputs to bfloat16 and accumulates
+in float32).  The control (`control=True`) puts the reference in the
+program's place in the nearest precision below what the configurations
+state, bfloat16 leaves AND activations (recurrent state, residual stream,
+layer norms, softmax inputs), and must come out as not correct:
+
+  parameters stored    the control's leaves              its activations
+  float32              rounded to bfloat16 once, before  bfloat16
+                       the program (`low_precision`: a
+                       copy of half the tree's size)
+  bfloat16             as they are stored                bfloat16
+
+so a bfloat16 configuration's control parts from its sound reference by
+the activations alone, and a float32 configuration's reads as it always
+has.  `train_numbers` follows the same rule (no training cell states a
+`param_dtype` yet).  The program's own lower path
+(`compute_dtype=bfloat16`) is read against the same limits by
+benchmark/tools/probe.py (PERF.md gives both readings for every number).
 """
 
 from __future__ import annotations
@@ -37,13 +51,18 @@ def _gap(prog: np.ndarray, refn: np.ndarray, keep: np.ndarray,
 
 
 def low_precision(params):
-    """The control's parameters: bfloat16, so that the reference (which
-    computes in the type of the parameters it is handed) keeps every
-    activation in bfloat16 too."""
+    """The control's leaves: the tree rounded to the control's type.  A
+    float32 tree gets one rounded copy, half its size; a bfloat16 tree is
+    handed through as it is.  Rounded HERE, before the reference's
+    program, and not where a leaf is read: inside one program XLA may
+    drop a float32 -> bfloat16 -> float32 round trip (it allows excess
+    precision by default), so a cast where a leaf is read is not
+    certain to round it; and the training control differentiates at
+    these leaves, so that its gradients are accumulated in bfloat16
+    across a leaf's uses, as a mixed-precision step keeps them."""
     import jax
-    import jax.numpy as jnp
 
-    return jax.tree_util.tree_map(lambda x: x.astype(jnp.bfloat16), params)
+    return jax.tree_util.tree_map(lambda x: x.astype(ref.CONTROL), params)
 
 
 def train_numbers(cfg: Dict[str, Any], seed: int, run, block: int,
@@ -60,25 +79,35 @@ def train_numbers(cfg: Dict[str, Any], seed: int, run, block: int,
     fam = ref.family(cfg["family"])
     p0 = weights.make_params(cfg, seed)
     names = ref.leaf_names(p0)
-    r_loss, r_g1, r_d3 = ref.train_steps(fam, p0, hp, run.batches, block)
+
+    def loss_grad(act, read=lambda q: q):
+        """(parameters, rows) -> (loss, gradients) with the activations
+        in `act`, differentiated at `read(parameters)`; the gradients
+        come back in the type the parameters are stored in."""
+        def fn(q, a):
+            loss, g = jax.value_and_grad(lambda x: ref.batch_loss(
+                fam, x, hp, a, act=act))(read(q))
+            return loss.astype(jnp.float32), jax.tree_util.tree_map(
+                lambda x, y: y.astype(x.dtype), q, g)
+        return fn
+
+    sound = loss_grad(ref.SOUND)
+    r_loss, r_g1, r_d3 = ref.train_steps(fam, p0, hp, run.batches, block,
+                                         sound)
     if control == "half_batch":
         # a fault, planted in the reference put in the program's place:
         # half of each batch left out, the mean taken over the rest
         halves = [{k: v[:v.shape[0] // 2] for k, v in b.items()}
                   for b in run.batches]
         prog_loss, prog_g1, prog_d3 = ref.train_steps(
-            fam, p0, hp, halves, block)
+            fam, p0, hp, halves, block, sound)
     elif control:
         # the reference in the program's place, forward and backward in
-        # bfloat16 (master weights and optimizer state stay float32, as
-        # mixed-precision training keeps them)
-        def low(q, a):
-            loss, g = jax.value_and_grad(lambda x: ref.batch_loss(
-                fam, x, hp, a))(low_precision(q))
-            return loss.astype(jnp.float32), jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.float32), g)
+        # bfloat16 (master weights and optimizer state stay as they are
+        # stored, as mixed-precision training keeps them)
         prog_loss, prog_g1, prog_d3 = ref.train_steps(
-            fam, p0, hp, run.batches, block, loss_grad=low)
+            fam, p0, hp, run.batches, block,
+            loss_grad(ref.CONTROL, low_precision))
     else:
         prog_loss, prog_g1, prog_d3 = run.losses, run.g1, run.d3
     r_loss = np.asarray(r_loss)
@@ -144,9 +173,6 @@ def serve_numbers(cfg: Dict[str, Any], seed: int, finished: Sequence[Any],
     cost more than the window, and a number that was not measured is left
     out, so that `judge` fails a limit kept for it.
     """
-    import jax
-    import jax.numpy as jnp
-
     hp = cfg["hparams"]
     fam = ref.family(cfg["family"])
     params = weights.make_params(cfg, seed)
@@ -158,14 +184,16 @@ def serve_numbers(cfg: Dict[str, Any], seed: int, finished: Sequence[Any],
         outs.append(t)
         lens.append(n)
     lens = np.asarray(lens, np.float64)
-    r_avg = ref.score_tokens(fam, params, hp, arts, outs) / lens
+    r_avg = ref.score_tokens(fam, params, hp, arts, outs,
+                             act=ref.SOUND) / lens
     beam_gaps = []
     for (a, _), avg in list(zip(picked, r_avg))[:int(sample["beam"])]:
-        _, best = ref.beam_search(fam, params, hp, a.ids, a.ext)
+        _, best = ref.beam_search(fam, params, hp, a.ids, a.ext,
+                                  act=ref.SOUND)
         beam_gaps.append(float(best - avg))
     if control:
         served = ref.score_tokens(fam, low_precision(params), hp, arts,
-                                  outs) / lens
+                                  outs, act=ref.CONTROL) / lens
     else:
         served = np.asarray([r.avg_log_prob for _, r in picked])
     gaps = np.abs(served - r_avg)

@@ -10,10 +10,10 @@ backward direction runs over the valid prefix only, reduced initial
 state, Bahdanau attention with the padding renormalised away, coverage
 off.  `decode_mode` selects the reference's initial_state_attention=True
 quirk: at decode time the first step's context is the attention at the
-initial state, in training it is zero.  Every function computes in the
-dtype of the parameters it is handed (float32; the low-precision control
-rounds the parameters it hands in), and the caller sets the matmul
-precision.
+initial state, in training it is zero.  Every function computes in `act`,
+the activations' type its caller states (families/__init__.py has the
+rule): a leaf is cast to it where it is read, whatever type the tree
+stores, and the caller sets the matmul precision.
 
 THE SUMMARY CLOCK.  Random weights give STOP a log probability near -11
 that hardly moves from step to step, so beam search never ends (PR 22),
@@ -55,28 +55,29 @@ F32 = 4
 LOG_EPS = 0.0  # the family's log(p + eps) at decode time and in the loss
 
 
-def _lstm(cell, x, c, h):
-    z = jnp.concatenate([x, h], -1) @ cell["kernel"] + cell["bias"]
+def _lstm(cell, x, c, h, act):
+    z = (jnp.concatenate([x, h], -1) @ cell["kernel"].astype(act)
+         + cell["bias"].astype(act))
     i, j, f, o = jnp.split(z, 4, -1)
     c2 = c * jax.nn.sigmoid(f + 1.0) + jax.nn.sigmoid(i) * jnp.tanh(j)
     return c2, jnp.tanh(c2) * jax.nn.sigmoid(o)
 
 
-def encode(p, hp, ids, n):
+def encode(p, hp, ids, n, act):
     """ids: [T] fixed-vocabulary ids (padding past n is ignored).
     Returns the encoder view the decoder needs."""
     del hp
-    emb = p["embedding"][ids]
+    emb = p["embedding"][ids].astype(act)
     T = ids.shape[0]
     H = p["reduce"]["w_reduce_c"].shape[1]
     valid = jnp.arange(T) < n
-    zero = jnp.zeros((H,), emb.dtype)
+    zero = jnp.zeros((H,), act)
 
     def run(cell, reverse):
         def step(carry, xs):
             x, ok = xs
             c, h = carry
-            c2, h2 = _lstm(cell, x, c, h)
+            c2, h2 = _lstm(cell, x, c, h, act)
             return ((jnp.where(ok, c2, c), jnp.where(ok, h2, h)),
                     jnp.where(ok, h2, 0))
 
@@ -87,25 +88,31 @@ def encode(p, hp, ids, n):
     fw, fc, fh = run(p["encoder"]["fw"], False)
     bw, bc, bh = run(p["encoder"]["bw"], True)
     states = jnp.concatenate([fw, bw], -1)  # [T, 2H]
-    r = p["reduce"]
+    r = {k: v.astype(act) for k, v in p["reduce"].items()}
     c0 = jax.nn.relu(jnp.concatenate([fc, bc]) @ r["w_reduce_c"]
                      + r["bias_reduce_c"])
     h0 = jax.nn.relu(jnp.concatenate([fh, bh]) @ r["w_reduce_h"]
                      + r["bias_reduce_h"])
-    feats = states @ p["decoder"]["attention"]["W_h"]
+    feats = states @ p["decoder"]["attention"]["W_h"].astype(act)
     return {"states": states, "feats": feats, "valid": valid,
             "c0": c0, "h0": h0}
 
 
-def _attend(a, enc, c, h):
-    dec = jnp.concatenate([c, h]) @ a["linear_kernel"] + a["linear_bias"]
-    e = jnp.sum(a["v"] * jnp.tanh(enc["feats"] + dec), -1).astype(jnp.float32)
+def _attend(a, enc, c, h, act):
+    dec = (jnp.concatenate([c, h]) @ a["linear_kernel"].astype(act)
+           + a["linear_bias"].astype(act))
+    e = jnp.sum(a["v"].astype(act) * jnp.tanh(enc["feats"] + dec),
+                -1).astype(jnp.float32)
     e = jnp.where(enc["valid"], e, -jnp.inf)
-    att = jax.nn.softmax(e).astype(enc["states"].dtype)
+    att = jax.nn.softmax(e).astype(act)
     return att @ enc["states"], att
 
 
-def decode(p, hp, enc, dec_inputs, decode_mode):
+def _linear(lin, x, act):
+    return x @ lin["kernel"].astype(act) + lin["bias"].astype(act)
+
+
+def decode(p, hp, enc, dec_inputs, decode_mode, act):
     """Teacher-forced decoder over dec_inputs [Td] (fixed-vocabulary ids).
     Returns (proj_in [Td, H], W [H, V], b [V], att [Td, T], p_gen [Td]):
     vocabulary scores are proj_in @ W + b."""
@@ -113,26 +120,24 @@ def decode(p, hp, enc, dec_inputs, decode_mode):
     d = p["decoder"]
     c, h = enc["c0"], enc["h0"]
     if decode_mode:
-        ctx, _ = _attend(d["attention"], enc, c, h)
+        ctx, _ = _attend(d["attention"], enc, c, h, act)
     else:
-        ctx = jnp.zeros((enc["states"].shape[-1],), c.dtype)
+        ctx = jnp.zeros((enc["states"].shape[-1],), act)
 
     def step(carry, tok):
         c, h, ctx = carry
-        x = (jnp.concatenate([p["embedding"][tok], ctx])
-             @ d["input_linear"]["kernel"] + d["input_linear"]["bias"])
-        c2, h2 = _lstm(d["cell"], x, c, h)
-        ctx2, att = _attend(d["attention"], enc, c2, h2)
-        pgen = jax.nn.sigmoid(
-            jnp.concatenate([ctx2, c2, h2, x]) @ d["pgen_linear"]["kernel"]
-            + d["pgen_linear"]["bias"])[0]
-        out = (jnp.concatenate([h2, ctx2]) @ d["output_linear"]["kernel"]
-               + d["output_linear"]["bias"])
+        x = _linear(d["input_linear"], jnp.concatenate(
+            [p["embedding"][tok].astype(act), ctx]), act)
+        c2, h2 = _lstm(d["cell"], x, c, h, act)
+        ctx2, att = _attend(d["attention"], enc, c2, h2, act)
+        pgen = jax.nn.sigmoid(_linear(
+            d["pgen_linear"], jnp.concatenate([ctx2, c2, h2, x]), act))[0]
+        out = _linear(d["output_linear"], jnp.concatenate([h2, ctx2]), act)
         return (c2, h2, ctx2), (out, att, pgen)
 
     _, (outs, atts, pgens) = jax.lax.scan(step, (c, h, ctx), dec_inputs)
-    return (outs, p["output_projection"]["w"], p["output_projection"]["v"],
-            atts, pgens)
+    head = p["output_projection"]
+    return outs, head["w"].astype(act), head["v"].astype(act), atts, pgens
 
 
 # ----------------------------------------------------------------- layout

@@ -4,7 +4,10 @@ reference and its counts.  Imports nothing of the program.  It offers no
 summary clock (its decoder has no recurrent state; PERF.md section 7).
 
 The reference is straightforward jax.numpy, one article at a time, no
-kernels, no KV cache, no batching.
+kernels, no KV cache, no batching.  It computes in `act`, the
+activations' type its caller states (families/__init__.py has the rule):
+a leaf is cast to it where it is read, whatever type the tree stores; the
+layer norm's statistics and the softmax's inputs are float32 in either.
 
 Layer equations (departures from OpenNMT-py are listed in
 configs/tf_cnndm.json "assumed"): pre-LN residual blocks, learned
@@ -18,84 +21,92 @@ nothing here), but the family takes log(p + 1e-10).
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 F32 = 4
 
 LOG_EPS = 1e-10
 
 
-def _ln(p, x):
+def _ln(p, x, act):
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, -1, keepdims=True)
     var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + 1e-6) * p["scale"]
-            + p["bias"]).astype(x.dtype)
+    return ((x32 - mu) * jax.lax.rsqrt(var + 1e-6) * p["scale"].astype(act)
+            + p["bias"].astype(act)).astype(act)
 
 
 def _gelu(x):
+    # a Python float, which JAX types weakly: a NumPy scalar here would
+    # widen the bfloat16 control's residual stream to float32 from the
+    # first feed-forward block on (PERF.md section 6, PR 36)
     return 0.5 * x * (1.0 + jnp.tanh(
-        np.sqrt(2.0 / np.pi) * (x + 0.044715 * x ** 3)))
+        math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
 
 
-def _mha(p, nh, q_in, kv_in, mask):
+def _mha(p, nh, q_in, kv_in, mask, act):
     """q_in [Tq, H], kv_in [Tk, H], mask [Tq, Tk] bool.  Returns
     (output [Tq, H], head-averaged probabilities [Tq, Tk])."""
     Tq, H = q_in.shape
     hd = H // nh
-    q = (q_in @ p["wq"]).reshape(Tq, nh, hd)
-    k = (kv_in @ p["wk"]).reshape(-1, nh, hd)
-    v = (kv_in @ p["wv"]).reshape(-1, nh, hd)
+    q = (q_in @ p["wq"].astype(act)).reshape(Tq, nh, hd)
+    k = (kv_in @ p["wk"].astype(act)).reshape(-1, nh, hd)
+    v = (kv_in @ p["wv"].astype(act)).reshape(-1, nh, hd)
     logits = jnp.einsum("qnd,knd->nqk", q, k).astype(jnp.float32) * hd ** -0.5
     logits = jnp.where(mask[None], logits, -1e30)
     probs = jax.nn.softmax(logits, -1)
-    ctx = jnp.einsum("nqk,knd->qnd", probs.astype(v.dtype), v).reshape(Tq, H)
-    return ctx @ p["wo"], jnp.mean(probs, 0)
+    ctx = jnp.einsum("nqk,knd->qnd", probs.astype(act), v).reshape(Tq, H)
+    return ctx @ p["wo"].astype(act), jnp.mean(probs, 0)
 
 
-def _ffn(p, x):
-    return _gelu(x @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+def _ffn(p, x, act):
+    return (_gelu(x @ p["w1"].astype(act) + p["b1"].astype(act))
+            @ p["w2"].astype(act) + p["b2"].astype(act))
 
 
-def encode(p, hp, ids, n):
+def encode(p, hp, ids, n, act):
     T = ids.shape[0]
     nh = int(hp["num_heads"])
     valid = jnp.arange(T) < n
-    x = p["embedding"][ids] + p["pos_enc"][:T]
+    x = p["embedding"][ids].astype(act) + p["pos_enc"][:T].astype(act)
     mask = jnp.broadcast_to(valid[None, :], (T, T))
     for layer in p["encoder"]["layers"]:
-        h = _ln(layer["ln1"], x)
-        x = x + _mha(layer["self_attn"], nh, h, h, mask)[0]
-        x = x + _ffn(layer["ffn"], _ln(layer["ln2"], x))
-    return {"out": _ln(p["encoder"]["ln_out"], x), "valid": valid}
+        h = _ln(layer["ln1"], x, act)
+        x = x + _mha(layer["self_attn"], nh, h, h, mask, act)[0]
+        x = x + _ffn(layer["ffn"], _ln(layer["ln2"], x, act), act)
+    return {"out": _ln(p["encoder"]["ln_out"], x, act), "valid": valid}
 
 
-def decode(p, hp, enc, dec_inputs, decode_mode):
+def decode(p, hp, enc, dec_inputs, decode_mode, act):
     """Teacher-forced decoder over dec_inputs [Td].  Returns (proj_in
     [Td, H], W [H, V], b [V], att [Td, T], p_gen [Td])."""
     del decode_mode
     nh = int(hp["num_heads"])
     Td = dec_inputs.shape[0]
-    y = p["embedding"][dec_inputs] + p["pos_dec"][:Td]
+    y = (p["embedding"][dec_inputs].astype(act)
+         + p["pos_dec"][:Td].astype(act))
     causal = jnp.tril(jnp.ones((Td, Td), bool))
     cross_mask = jnp.broadcast_to(enc["valid"][None, :],
                                   (Td, enc["valid"].shape[0]))
     for layer in p["decoder"]["layers"]:
-        h = _ln(layer["ln1"], y)
-        y = y + _mha(layer["self_attn"], nh, h, h, causal)[0]
-        cross, att = _mha(layer["cross_attn"], nh, _ln(layer["ln_cross"], y),
-                          enc["out"], cross_mask)
+        h = _ln(layer["ln1"], y, act)
+        y = y + _mha(layer["self_attn"], nh, h, h, causal, act)[0]
+        cross, att = _mha(layer["cross_attn"], nh,
+                          _ln(layer["ln_cross"], y, act), enc["out"],
+                          cross_mask, act)
         y = y + cross
-        y = y + _ffn(layer["ffn"], _ln(layer["ln2"], y))
-    h = _ln(p["decoder"]["ln_out"], y)
+        y = y + _ffn(layer["ffn"], _ln(layer["ln2"], y, act), act)
+    h = _ln(p["decoder"]["ln_out"], y, act)
     pgen = jax.nn.sigmoid(
-        jnp.concatenate([h, cross], -1) @ p["pgen_linear"]["kernel"]
-        + p["pgen_linear"]["bias"])[:, 0]
-    return h, p["embedding"].T, p["out_bias"], att, pgen
+        jnp.concatenate([h, cross], -1)
+        @ p["pgen_linear"]["kernel"].astype(act)
+        + p["pgen_linear"]["bias"].astype(act))[:, 0]
+    return (h, p["embedding"].T.astype(act), p["out_bias"].astype(act), att,
+            pgen)
 
 
 # ----------------------------------------------------------------- layout

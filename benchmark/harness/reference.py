@@ -7,7 +7,12 @@ Imports nothing of the program.
 configuration's "family": its `encode` / `decode` / `LOG_EPS` feed the
 pointer mixture here, unless the family gives `token_logprobs` /
 `next_dist` of its own (no copy distribution, a head over a slice of the
-vocabulary).  All functions take `hp`, the config file's "hparams" dict.
+vocabulary).  All functions take `hp`, the config file's "hparams" dict,
+and `act`, the type of the activations (the rule is in
+families/__init__.py): SOUND unless the caller states the CONTROL's.
+The parameters go into every jitted program in the type they are stored
+in, and a leaf is cast where the family reads it: no copy of the tree in
+another type is ever made.
 """
 
 from __future__ import annotations
@@ -21,6 +26,8 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness.families import CONTROL, SOUND  # noqa: F401  (callers' names)
 
 UNK_ID, PAD_ID, START_ID, STOP_ID = 0, 1, 2, 3
 
@@ -41,16 +48,17 @@ def family(name: str):
 # ---------------------------------------------------------------- mixture
 
 def _token_logprobs(fam, p, hp, ids, ext_ids, n, dec_inputs, targets,
-                    decode_mode):
+                    decode_mode, act):
     """log P(targets[t]) at every position, for one article: the
     family's own, else the pointer mixture.  ids/ext_ids [T],
     dec_inputs/targets [Td]."""
     if hasattr(fam, "token_logprobs"):
         return fam.token_logprobs(p, hp, ids, ext_ids, n, dec_inputs,
-                                  targets, decode_mode)
+                                  targets, decode_mode, act)
     V = int(hp["vocab_size"])
-    enc = fam.encode(p, hp, ids, n)
-    proj_in, W, b, att, pgen = fam.decode(p, hp, enc, dec_inputs, decode_mode)
+    enc = fam.encode(p, hp, ids, n, act)
+    proj_in, W, b, att, pgen = fam.decode(p, hp, enc, dec_inputs, decode_mode,
+                                          act)
     scores = (proj_in @ W + b).astype(jnp.float32)  # [Td, V]
     lse = jax.scipy.special.logsumexp(scores, -1)
     in_vocab = targets < V
@@ -65,20 +73,20 @@ def _token_logprobs(fam, p, hp, ids, ext_ids, n, dec_inputs, targets,
     return jnp.log(pg * gen + (1.0 - pg) * copy + fam.LOG_EPS)
 
 
-def final_dist_at(fam, p, hp, ids, ext_ids, n, dec_inputs, t):
+def final_dist_at(fam, p, hp, ids, ext_ids, n, dec_inputs, t, act=SOUND):
     """The extended-vocabulary distribution [V + oov] for the token that
     follows dec_inputs[:t+1] (decode semantics), one article, K rows:
     dec_inputs [K, Td]: each row the family's own `next_dist`, else the
     pointer mixture."""
     if hasattr(fam, "next_dist"):
         return jax.vmap(lambda row: fam.next_dist(
-            p, hp, ids, ext_ids, n, row, t))(dec_inputs)
+            p, hp, ids, ext_ids, n, row, t, act))(dec_inputs)
     V, n_oov = int(hp["vocab_size"]), int(hp["max_oov_buckets"])
-    enc = fam.encode(p, hp, ids, n)
+    enc = fam.encode(p, hp, ids, n, act)
     valid = jnp.arange(ids.shape[0]) < n
 
     def one(row):
-        proj_in, W, b, att, pgen = fam.decode(p, hp, enc, row, True)
+        proj_in, W, b, att, pgen = fam.decode(p, hp, enc, row, True, act)
         scores = (proj_in[t] @ W + b).astype(jnp.float32)
         vocab = jax.nn.softmax(scores)
         pg = pgen[t].astype(jnp.float32)
@@ -91,13 +99,13 @@ def final_dist_at(fam, p, hp, ids, ext_ids, n, dec_inputs, t):
 
 # ------------------------------------------------------------------- loss
 
-def batch_loss(fam, p, hp, arrays):
+def batch_loss(fam, p, hp, arrays, act=SOUND):
     """The training loss of one batch (dict of [B, ...] arrays as the
     trainer's feed delivers them): per row, the masked mean of the
     negative log mixture probability of the target; then the mean over
     rows."""
     def row(ids, ext, n, dec, tgt, mask):
-        lp = _token_logprobs(fam, p, hp, ids, ext, n, dec, tgt, False)
+        lp = _token_logprobs(fam, p, hp, ids, ext, n, dec, tgt, False, act)
         return jnp.sum(-lp * mask) / jnp.sum(mask)
 
     losses = jax.vmap(row)(
@@ -110,8 +118,8 @@ def batch_loss(fam, p, hp, arrays):
 def loss_and_grads(fam, p, hp, arrays, block: int, loss_grad=None):
     """Loss and gradients of one batch, computed in blocks of `block`
     rows so that the reference fits beside nothing else on the chip.
-    `loss_grad(params, rows) -> (loss, grads)` stands in for the plain
-    float32 computation where a control wants another one."""
+    `loss_grad(params, rows) -> (loss, grads)` stands in for the sound
+    computation where a control wants another one."""
     B = int(arrays["enc_batch"].shape[0])
     assert B % block == 0, (B, block)
     fn = jax.jit(loss_grad or jax.value_and_grad(
@@ -180,11 +188,10 @@ def leaf_names(tree) -> List[str]:
 
 # ---------------------------------------------------------------- serving
 
-def score_tokens(fam, p, hp, articles: Sequence[Tuple[np.ndarray, np.ndarray]],
-                 outputs: Sequence[Sequence[int]]) -> np.ndarray:
-    """For each (article ids, extended ids) and its served output tokens
-    (extended ids, STOP included where the search stopped): the sum of
-    log mixture probabilities of the tokens under decode semantics."""
+def score_inputs(hp, articles: Sequence[Tuple[np.ndarray, np.ndarray]],
+                 outputs: Sequence[Sequence[int]]) -> Tuple[np.ndarray, ...]:
+    """(ids, ext, lens, dec, tgt, mask), padded to the configuration's
+    lengths: what `score_program` takes after the parameters."""
     Te, Td = int(hp["max_enc_steps"]), int(hp["max_dec_steps"])
     V = int(hp["vocab_size"])
     n_art = len(articles)
@@ -201,26 +208,42 @@ def score_tokens(fam, p, hp, articles: Sequence[Tuple[np.ndarray, np.ndarray]],
         inp = [START_ID] + [t if t < V else UNK_ID for t in out[:-1]]
         dec[i, :len(inp)], tgt[i, :len(out)] = inp, out
         mask[i, :len(out)] = 1.0
+    return ids, ext, lens, dec, tgt, mask
 
+
+def score_program(fam, hp, act=SOUND):
+    """The jitted program behind `score_tokens`: (parameters as they are
+    stored, *score_inputs) -> each row's sum of log probabilities, one
+    article at a time."""
     @jax.jit
     def run(q, ids, ext, lens, dec, tgt, mask):
         def row(i, e, n, d, t, m):
-            return jnp.sum(_token_logprobs(fam, q, hp, i, e, n, d, t, True)
-                           * m)
+            return jnp.sum(_token_logprobs(fam, q, hp, i, e, n, d, t, True,
+                                           act) * m)
         return jax.lax.map(lambda xs: row(*xs),
                            (ids, ext, lens, dec, tgt, mask))
 
-    return np.asarray(run(p, ids, ext, lens, dec, tgt, mask))
+    return run
+
+
+def score_tokens(fam, p, hp, articles: Sequence[Tuple[np.ndarray, np.ndarray]],
+                 outputs: Sequence[Sequence[int]], act=SOUND) -> np.ndarray:
+    """For each (article ids, extended ids) and its served output tokens
+    (extended ids, STOP included where the search stopped): the sum of
+    log mixture probabilities of the tokens under decode semantics."""
+    return np.asarray(score_program(fam, hp, act)(
+        p, *score_inputs(hp, articles, outputs)))
 
 
 @functools.lru_cache(maxsize=None)
-def _topk_fn(family_module: str, hp_json: str):
+def _topk_fn(family_module: str, hp_json: str, act: str):
     """The 2K best continuations of K prefixes of one article."""
     fam, hp = importlib.import_module(family_module), json.loads(hp_json)
 
     @jax.jit
     def topk(q, ids, ext, n, rows, t):
-        dist = final_dist_at(fam, q, hp, ids, ext, n, rows, t)
+        dist = final_dist_at(fam, q, hp, ids, ext, n, rows, t,
+                             jnp.dtype(act))
         probs, toks = jax.lax.top_k(dist, 2 * int(hp["beam_size"]))
         return toks, jnp.log(probs + fam.LOG_EPS)
 
@@ -228,7 +251,7 @@ def _topk_fn(family_module: str, hp_json: str):
 
 
 def beam_search(fam, p, hp, art_ids: np.ndarray, ext_ids: np.ndarray,
-                on_step=None) -> Tuple[List[int], float]:
+                on_step=None, act=SOUND) -> Tuple[List[int], float]:
     """See et al.'s beam search (abisee beam_search.py) for one article,
     in plain Python over the reference's distributions.  Returns (the
     best hypothesis' generated tokens, its length-normalised log
@@ -242,7 +265,8 @@ def beam_search(fam, p, hp, art_ids: np.ndarray, ext_ids: np.ndarray,
     ext = np.full((Te,), PAD_ID, np.int32)
     ids[:len(art_ids)], ext[:len(art_ids)] = art_ids, ext_ids
     n = np.int32(len(art_ids))
-    topk = _topk_fn(fam.__name__, json.dumps(hp, sort_keys=True))
+    topk = _topk_fn(fam.__name__, json.dumps(hp, sort_keys=True),
+                    jnp.dtype(act).name)
 
     hyps = [([], 0.0)] * K  # (generated tokens, total log prob)
     results: List[Tuple[List[int], float]] = []

@@ -199,10 +199,11 @@ def test_a_familys_own_distributions_replace_the_pointer_mixture():
     shared = reference.score_tokens(pg, params, hp, [(art.ids, art.ext)],
                                     outs)
 
-    def token_logprobs(p, hp, ids, ext_ids, n, dec_inputs, targets, mode):
+    def token_logprobs(p, hp, ids, ext_ids, n, dec_inputs, targets, mode,
+                       act):
         return jnp.full(targets.shape, -2.0)
 
-    def next_dist(p, hp, ids, ext_ids, n, dec_inputs, t):
+    def next_dist(p, hp, ids, ext_ids, n, dec_inputs, t, act):
         return jnp.zeros((V + n_oov,)).at[reference.STOP_ID].set(
             0.75).at[5].set(0.25)
 

@@ -1,13 +1,20 @@
 """The plain references against the program on the CPU at a tiny size:
-training loss, gradients, and beam search, on seed-made weights."""
+training loss, gradients, and beam search, on seed-made weights; and the
+rule of harness/families/__init__.py, the activations' type stated apart
+from the parameters' stored type, for every family module on disk."""
+import glob
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from harness import correct
 from harness import reference as ref
-from harness import weights
-from tests.tiny import tiny_config
+from harness import traffic, weights
+from tests.tiny import BENCH, mid_batches, mid_config, tiny_config
 
 
 def _program_hps(cfg, batch):
@@ -44,6 +51,26 @@ def _arrays(cfg, rng, B):
         a["target_batch"][i, :d] = tgt
         a["dec_padding_mask"][i, :d] = 1
     return a
+
+
+def fixed_case(hp, n=4):
+    """n articles (a few out-of-vocabulary words among them) and an
+    output for each, every third token copied from its article and STOP
+    last: fixed, at the sizes of `hp`."""
+    V, Td = hp["vocab_size"], hp["max_dec_steps"]
+    mix = {"article": {"length": {"dist": "lognormal", "median": 40,
+                                  "sigma": 0.4, "min": 16,
+                                  "max": hp["max_enc_steps"]},
+                       "oov_share": 0.02, "oov_pool": 50,
+                       "max_oov_buckets": hp["max_oov_buckets"]}}
+    arts = traffic.make_articles(mix, V, n, 3)
+    rng = np.random.RandomState(3)
+    outs = []
+    for a in arts:
+        out = rng.randint(4, V, size=Td - 4)
+        out[::3] = a.ext[:len(out):3][:len(out[::3])]
+        outs.append([int(t) for t in out] + [ref.STOP_ID])
+    return [(a.ids, a.ext) for a in arts], outs
 
 
 @pytest.mark.parametrize("name", ["pg_see2017", "tf_cnndm"])
@@ -96,3 +123,119 @@ def test_beam_search_and_scores_agree(name):
     for b in range(4):
         assert abs(scores[b] / int(out.length[b])
                    - float(out.avg_log_prob[b])) < 1e-4
+
+
+# ------------------------------------------- the activations' type (PR 36)
+
+FAMILIES = sorted(os.path.basename(f)[:-3] for f in glob.glob(
+    os.path.join(BENCH, "harness", "families", "[!_]*.py")))
+with open(os.path.join(os.path.dirname(__file__),
+                       "parent_reference.json")) as f:
+    RECORDED = json.load(f)["families"]
+
+
+def _mid_config_of(family, param_dtype):
+    """The first configuration on disk of `family`, at the middle size,
+    storing its parameters in `param_dtype`."""
+    for path in sorted(glob.glob(os.path.join(BENCH, "configs", "*.json"))):
+        with open(path) as f:
+            if json.load(f)["family"] == family:
+                cfg = mid_config(os.path.basename(path)[:-5])
+                cfg["param_dtype"] = param_dtype
+                return cfg
+    raise AssertionError(f"no configuration of family {family!r} on disk")
+
+
+def _scores(cfg, params, act, case):
+    return ref.score_tokens(ref.family(cfg["family"]), params,
+                            cfg["hparams"], *case, act=act)
+
+
+def _close(got, want, rel=1e-6):
+    return np.asarray(got, np.float64) == pytest.approx(
+        np.asarray(want, np.float64), rel=rel)
+
+
+@pytest.mark.parametrize("family", sorted(RECORDED))
+def test_a_float32_tree_scores_as_recorded(family):
+    """(a) Over a float32 tree the cast where a leaf is read is nothing
+    (sound) or reads leaves already rounded (control), so `score_tokens`
+    reads what the parent of PR 36 read (bit for bit on the builder's
+    machine; another XLA:CPU may reorder a reduction), the transformer's
+    repaired control apart: parent_reference.json says which."""
+    assert family in FAMILIES
+    want = RECORDED[family]["score_tokens"]
+    cfg = _mid_config_of(family, "float32")
+    assert cfg["name"] == RECORDED[family]["config"]
+    params = weights.make_params(cfg, 3)
+    case = fixed_case(cfg["hparams"])
+    assert _close(_scores(cfg, params, ref.SOUND, case), want["sound"])
+    assert _close(_scores(cfg, correct.low_precision(params), ref.CONTROL,
+                          case), want["control"])
+
+
+@pytest.mark.parametrize("family", sorted(RECORDED))
+def test_a_float32_tree_trains_as_recorded(family):
+    """(a) for `train_numbers`: the sound reference's three steps and
+    the control's gaps from them.  A gap is a difference of norms that
+    agree to 1e-3, so it is held to 1e-4 of itself."""
+    want = RECORDED[family]
+    cfg = _mid_config_of(family, "float32")
+    hp = cfg["hparams"]
+
+    class Run:
+        batches = mid_batches(hp, rows=4)
+
+    losses, g1, d3 = ref.train_steps(
+        ref.family(family), weights.make_params(cfg, 3), hp, Run.batches, 2)
+    sound = want["train_steps"]
+    assert _close(losses, sound["losses"])
+    assert _close(g1, sound["first_gradient_norms"])
+    assert _close(d3, sound["change_norms"])
+    control = correct.train_numbers(cfg, 3, Run, block=2, control=True)
+    assert _close(control["_detail"]["ref_losses"], sound["losses"])
+    assert _close(control["_detail"]["losses"],
+                  want["train_control"]["losses"])
+    for k in ("loss_gap", "grad_norm_gap", "update_norm_gap"):
+        assert control[k] == pytest.approx(want["train_control"][k],
+                                           rel=1e-4, abs=1e-7), k
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_a_bfloat16_tree_has_a_float32_reference_and_a_control(family):
+    """(b) A configuration that stores bfloat16 parameters: its control
+    (the leaves as stored, bfloat16 activations) parts from its sound
+    reference (the leaves widened where read, everything else float32) by
+    more than 1e-3 a token on the worst of eight articles, and the sound
+    reference is the float32 computation over the same bfloat16 VALUES
+    held in a float32 tree."""
+    cfg = _mid_config_of(family, "bfloat16")
+    params = weights.make_params(cfg, 3)
+    assert {x.dtype for x in jax.tree_util.tree_leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    case = fixed_case(cfg["hparams"], 8)
+    sound = _scores(cfg, params, ref.SOUND, case)
+    control = _scores(cfg, correct.low_precision(params), ref.CONTROL, case)
+    tokens = np.asarray([len(out) + 1 for out in case[1]])
+    assert np.max(np.abs(sound - control) / tokens) > 1e-3
+    widened = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params)
+    assert _close(sound, _scores(cfg, widened, ref.SOUND, case))
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_sound_reference_takes_a_bfloat16_tree_as_it_is_stored(family):
+    """(c) No float32 copy of the tree is made outside the program: the
+    jitted sound reference's arguments are the tree's own bytes and the
+    inputs', within 1%."""
+    cfg = _mid_config_of(family, "bfloat16")
+    hp = cfg["hparams"]
+    params = weights.make_params(cfg, 3)
+    inputs = ref.score_inputs(hp, *fixed_case(hp))
+    compiled = ref.score_program(ref.family(family), hp, ref.SOUND).lower(
+        params, *inputs).compile()
+    stored = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
+    assert stored == 2 * weights.n_params(
+        ref.family(family).param_specs(hp))
+    want = stored + sum(x.nbytes for x in inputs)
+    got = compiled.memory_analysis().argument_size_in_bytes
+    assert abs(got - want) <= 0.01 * want, (got, want)

@@ -3,6 +3,8 @@ the chip; and the cells, as the tests read them out of BENCHMARK.json."""
 import json
 import os
 
+import numpy as np
+
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 
@@ -43,6 +45,31 @@ def mid_config(name: str):
     cfg = tiny_config(name)
     _shrink(cfg["hparams"], MID)
     return cfg
+
+
+def mid_batches(hp, rows=8, steps=3):
+    """`steps` training batches of `rows` full-length rows at the sizes
+    of `hp`, every other target copied from its article: what the
+    training control and the reference's record are read on."""
+    import numpy as np
+
+    rng = np.random.RandomState(0)
+    B, Te, Td, V = rows, hp["max_enc_steps"], hp["max_dec_steps"], \
+        hp["vocab_size"]
+    batches = []
+    for _ in range(steps):
+        ids = rng.randint(4, V, (B, Te)).astype(np.int32)
+        tgt = rng.randint(4, V, (B, Td)).astype(np.int32)
+        tgt[:, ::2] = ids[:, :Td:2][:, :tgt[:, ::2].shape[1]]
+        batches.append({
+            "enc_batch": ids, "enc_batch_extend_vocab": ids,
+            "enc_lens": np.full((B,), Te, np.int32),
+            "enc_padding_mask": np.ones((B, Te), np.float32),
+            "dec_batch": np.concatenate(
+                [np.full((B, 1), 2, np.int32), tgt[:, :-1]], 1),
+            "target_batch": tgt,
+            "dec_padding_mask": np.ones((B, Td), np.float32)})
+    return batches
 
 
 def held_cells():
