@@ -36,25 +36,45 @@ def wide_scatters(text: str, at_least: int) -> list:
             >= at_least]
 
 
+def _over_wide_operand(instrs: list, width: int):
+    """(name, shape, opcode, rest, operand shapes) of the instructions
+    whose result or an operand has a dimension of ``width``.  An
+    operand is printed by name alone, so its shape is looked up where
+    it is defined."""
+    wide = re.compile(rf"\[(?:\d+,)*{width}(?:,\d+)*\]")
+    shape_of = {name: shape for name, shape, _, _ in instrs}
+    for name, shape, opcode, rest in instrs:
+        operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
+        shapes = [shape_of.get(o, "") for o in operands]
+        if any(wide.search(s) for s in [shape] + shapes):
+            yield name, shape, opcode, rest, [
+                s for s in shapes if wide.search(s)]
+
+
 def wide_row_orderings(text: str, width: int) -> list:
     """The instructions of ``compiled.as_text()`` that sort, or take a
-    top-k of, an operand with a dimension of ``width``.  An operand is
-    printed by name alone, so its shape is looked up where it is
-    defined; a sort's or a TopK custom call's result is checked too."""
-    wide = re.compile(rf"\[(?:\d+,)*{width}(?:,\d+)*\]")
-    instrs = _instructions(text)
-    shape_of = {name: shape for name, shape, _, _ in instrs}
-    bad = []
-    for name, shape, opcode, rest in instrs:
-        if not (opcode in ("sort", "topk") or (
+    top-k of, an operand with a dimension of ``width`` (a sort's or a
+    TopK custom call's result is checked too)."""
+    return [f"{name} = {shape} {opcode}({rest}"[:200]
+            for name, shape, opcode, rest, _ in _over_wide_operand(
+                _instructions(text), width)
+            if opcode in ("sort", "topk") or (
                 opcode == "custom-call"
-                and re.search(r'custom_call_target="[^"]*TopK', rest))):
-            continue
-        operands = re.findall(r"%([\w.\-]+)", rest.split(")", 1)[0])
-        shapes = [shape] + [shape_of.get(o, "") for o in operands]
-        if any(wide.search(s) for s in shapes):
-            bad.append(f"{name} = {shape} {opcode}({rest}"[:200])
-    return bad
+                and re.search(r'custom_call_target="[^"]*TopK', rest))]
+
+
+def wide_reduces(text: str, width: int) -> list:
+    """The reduces of ``compiled.as_text()`` that carry an index along
+    (a variadic reduce whose result has an integer part) over an
+    operand with a dimension of exactly ``width``: with the
+    vocabulary's width, the passes of the selection (ops/topk.py), each
+    one read of the row.  A row's maximum and its sum reduce one
+    floating operand and are not among them."""
+    return [f"{name} = {shape} reduce({rest}"[:200]
+            for name, shape, opcode, rest, wide in _over_wide_operand(
+                _instructions(text), width)
+            if opcode == "reduce" and wide
+            and re.search(r"\b[su]\d+\[", shape)]
 
 
 def wide_gathers(text: str, width: int) -> list:

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import __graft_entry__ as ge
-from _hlo import (score_gathers, wide_dimensions, wide_gathers,
+from _hlo import (score_gathers, wide_dimensions, wide_gathers, wide_reduces,
                   wide_row_orderings, wide_scatters)
 from textsummarization_on_flink_tpu.config import HParams
 from textsummarization_on_flink_tpu.decode import beam_search
@@ -76,14 +76,29 @@ def _bits(a):
     return np.asarray(a.astype(jnp.float32)).view(np.uint32)
 
 
+def _picks_a_pass(k):
+    """Every m ``_select`` can take for k picks: the powers of two up
+    to the first that covers k."""
+    return [1 << e for e in range((k - 1).bit_length() + 1)]
+
+
+#: ``top_k`` as ``_plan`` routes it (m None) at every length, and the
+#: selection at each m a pass it can take, where a row is long enough
+CASES = [(n, k, None) for n in LENGTHS for k in (2, 8) if k <= n] + [
+    (n, k, m) for n in (1000, 50000, 152064) for k in (2, 8)
+    for m in _picks_a_pass(k)]
+
+
 @pytest.mark.parametrize("vmapped", [False, True], ids=["plain", "vmap"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("n,k", [(n, k) for n in LENGTHS for k in (2, 8)
-                                 if k <= n])
-def test_top_k_is_lax_top_k(n, k, dtype, vmapped):
+@pytest.mark.parametrize("n,k,m", CASES)
+def test_top_k_is_lax_top_k(n, k, m, dtype, vmapped):
     rng = np.random.default_rng(n + k)
-    fn = lambda r: topk.top_k(r, k)  # noqa: E731
+    if m is None:
+        fn = lambda r: topk.top_k(r, k)  # noqa: E731
+    else:
+        fn = lambda r: topk._select(r, k, m=m)  # noqa: E731
     if vmapped:  # two leading axes, as the slot step (slots, then beam)
         fn = jax.vmap(jax.vmap(fn))
     fn = jax.jit(fn)
@@ -98,14 +113,29 @@ def test_top_k_is_lax_top_k(n, k, dtype, vmapped):
                                       err_msg=name)
 
 
+def test_a_pass_that_overshoots_k_drops_the_rest():
+    """k = 6 (beam 3) at four a pass: two passes, the last two picks
+    of the second dropped."""
+    x = jnp.asarray(_many_ties(np.random.default_rng(6), 1000))
+    want_v, want_i = jax.lax.top_k(x, 6)
+    got_v, got_i = jax.jit(lambda r: topk._select(r, 6, m=4))(x)
+    np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
+    np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+
+
 def test_plan_is_pinned():
-    """The one choice, from the row's length and k alone: selection at
-    the cell's width, lax.top_k at a test vocabulary's."""
-    assert topk._plan(50128, 8) == "select"
-    assert topk._plan(152064, 8) == "select"
-    assert topk._plan(50128, 2) == "select"
-    assert topk._plan(64, 8) == "lax"
-    assert topk._plan(50128, 64) == "lax"
+    """The one choice, from the row's length and k alone: how many
+    picks a pass of the selection takes at the cell's width (never
+    more than cover k), and lax.top_k (0) at a test vocabulary's."""
+    m = topk.PICKS_A_PASS
+    assert m == 2
+    assert topk._plan(50000, 8) == m
+    assert topk._plan(50128, 8) == m
+    assert topk._plan(152064, 8) == m
+    assert topk._plan(50128, 2) == 2
+    assert topk._plan(50128, 1) == 1
+    assert topk._plan(64, 8) == 0
+    assert topk._plan(50128, 64) == 0
 
 
 def test_short_rows_and_integers_are_lax_top_k_itself():
@@ -138,12 +168,37 @@ def test_the_detector_sees_a_stock_top_k():
     assert not wide_row_orderings(ours.as_text(), WIDE)
 
 
+def _passes(k: int) -> int:
+    """The reads of the cell's vocabulary-wide row that selecting k may
+    make."""
+    return -(-k // topk._plan(WIDE, k))
+
+
+@pytest.mark.parametrize("m", [1, 2, 4, 8])
+def test_the_detector_sees_the_parents_eight(m):
+    """One pick a pass (the parent's form, ISSUE 26) is eight reduces
+    over the row under the slot step's two `vmap`s; m a pass are 8 / m,
+    and the row's top and mass are none of them."""
+    x = jnp.zeros((2, 4, WIDE), jnp.float32)
+    text = jax.jit(jax.vmap(jax.vmap(lambda r: (
+        topk._select(r, 8, m=m), r.max(-1), r.sum(-1))))).lower(
+            x).compile().as_text()
+    assert len(wide_reduces(text, WIDE)) == 8 // m
+    assert not wide_reduces(text, WIDE + 1)
+    ours = jax.jit(jax.vmap(jax.vmap(lambda r: topk.top_k(r, 8)))).lower(
+        x).compile().as_text()
+    assert 1 <= len(wide_reduces(ours, WIDE)) <= _passes(8) < 8
+
+
 def _builds_no_extended_row(text: str, hps: HParams) -> None:
     """The slot step ranks candidates (ops/topk.mixture_top_k): the
     vocabulary's own rows are 50 000 wide, none is sorted, none is
-    scattered into, and no instruction has the extended width."""
+    scattered into, no instruction has the extended width, and the
+    selection reads a row at most once for every ``_plan`` picks of the
+    2 x beam (ISSUE 37; the parent read it once a pick)."""
     V = hps.vocab_size
     assert wide_dimensions(text, V)  # the step does hold the vocabulary
+    assert 1 <= len(wide_reduces(text, V)) <= _passes(2 * hps.beam_size)
     assert not wide_row_orderings(text, V)
     assert not wide_row_orderings(text, V + hps.max_oov_buckets)
     assert not wide_scatters(text, V)

@@ -22,13 +22,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import __graft_entry__ as ge
-from _hlo import (score_gathers, wide_dimensions, wide_row_orderings,
-                  wide_scatters)
+from _hlo import (score_gathers, wide_dimensions, wide_reduces,
+                  wide_row_orderings, wide_scatters)
 from textsummarization_on_flink_tpu.config import HParams, resolve_enc_block
 from textsummarization_on_flink_tpu.decode import beam_search
 from textsummarization_on_flink_tpu.models import get_family
 from textsummarization_on_flink_tpu.models import transformer as tfm
 from textsummarization_on_flink_tpu.ops import pallas_attention as pa
+from textsummarization_on_flink_tpu.ops import topk
 from textsummarization_on_flink_tpu.parallel import mesh as mesh_lib
 from textsummarization_on_flink_tpu.train import trainer as trainer_lib
 
@@ -179,9 +180,14 @@ def _orders_no_vocabulary_row(compiled, hps):
     is a word's score looked up by id in the step's score block: the
     article's words are scored by a product with the head's columns,
     gathered once before the loop (ISSUE 33; XLA:TPU gathers at 18-19
-    ns an index, 102 400 of them a step in the cell)."""
+    ns an index, 102 400 of them a step in the cell).  And the
+    selection reads the row once for every ``_plan`` picks of its 2 x
+    beam, where the parent read it once a pick (ISSUE 37: each read of
+    the cell's [256, 4, 50 000] block is 0.25 ms of a 6.6 ms step)."""
     text = compiled.as_text()
     V, width = hps.vocab_size, hps.vocab_size + hps.max_oov_buckets
+    k = 2 * hps.beam_size
+    assert 1 <= len(wide_reduces(text, V)) <= -(-k // topk._plan(V, k)) < k
     assert not score_gathers(text, V, SLOTS * hps.beam_size)
     assert wide_dimensions(text, V)  # the step does hold the vocabulary
     assert not wide_row_orderings(text, V)
@@ -190,21 +196,25 @@ def _orders_no_vocabulary_row(compiled, hps):
     assert not wide_dimensions(text, width)
 
 
+def _slot_step(hps, slots, dev):
+    """The slot step compiled for the described chip, an arena of half
+    the pages that ``slots`` full-length articles would take."""
+    params = _params(hps, dev)
+    arrays = _enc_arrays(hps, slots, dev)
+    active = jax.ShapeDtypeStruct((slots,), np.bool_, sharding=dev)
+    b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
+    pages = slots * b_max // 2
+    state = _on(dev, jax.eval_shape(
+        lambda: beam_search.init_slots_jit(params, hps, arrays, pages)))
+    table = jax.ShapeDtypeStruct((slots, b_max), np.int32, sharding=dev)
+    return beam_search.step_slots_jit.lower(
+        params, hps, state, active, table, CHUNK).compile()
+
+
 @pytest.mark.parametrize("family", ["pointer_generator", "transformer"])
 def test_slot_step_compiles_for_v5e(family, one_chip):
     hps = _family_hps(family)
-    params = _params(hps, one_chip)
-    arrays = _enc_arrays(hps, SLOTS, one_chip)
-    active = jax.ShapeDtypeStruct((SLOTS,), np.bool_, sharding=one_chip)
-    b_max = -(-hps.max_enc_steps // resolve_enc_block(hps))
-    pages = SLOTS * b_max // 2
-    state = _on(one_chip, jax.eval_shape(
-        lambda: beam_search.init_slots_jit(params, hps, arrays, pages)))
-    table = jax.ShapeDtypeStruct((SLOTS, b_max), np.int32,
-                                 sharding=one_chip)
-    compiled = beam_search.step_slots_jit.lower(
-        params, hps, state, active, table, CHUNK).compile()
-    _orders_no_vocabulary_row(compiled, hps)
+    _orders_no_vocabulary_row(_slot_step(hps, SLOTS, one_chip), hps)
 
 
 # -- one program across the four chips -------------------------------------

@@ -9,14 +9,42 @@ per-article step, the operand arrives with rank 3, and ``lax.top_k``
 becomes a full sort of each row — 67.7 ms at [256, 4, 50 128] float32
 on one v5e (my chip run, PR 26), 86% of the slot step (PERF.md).
 
-The selection is k passes: pass j takes, in ONE variadic reduce, the
-first element of the row in (value descending, index ascending) order
-among those that come after pass j-1's pick.  No sort, no gather, no
-reshape, nothing to tune; k reads of the row, 2.25 ms at the shape
-above where k reads at the chip's 819 GB/s are 2.0 ms.  The other
-exact form tried (contiguous bin maxima, ``lax.top_k`` over them,
-gather of k bins and re-rank) took 4.2 ms there: its small sorts are
-rank 3 too.  Timings and the choice: PERF.md section 6, "PR 26".
+The selection is k / m passes: pass j takes, in ONE variadic reduce,
+the m first elements of the row in (value descending, index ascending)
+order among those that come after pass j-1's last pick; the reduce
+carries m (value, index) pairs and its combiner merges two sorted
+m-lists (``_merge``: a bitonic merge's half-cleaner and log2 m
+compare-exchange stages; m = 1 is a plain "which comes first").  No
+sort, no gather, no reshape, no pad.  A pass of one pick runs at the
+memory roofline of one read of the row (0.254 ms where 205 MB at the
+chip's 819 GB/s are 0.25), so one pick a pass was eight reads, 31% of
+the slot step (PERF.md section 5, PR 36); two picks a pass still nearly
+do (0.289 ms a pass in the cell), four or eight are bound by the vector
+unit (0.58, 1.37).  ms a call, k = 8, the cell's producer ``p_gen *
+exp(z - top) / mass`` fused into every pass, every form bit-equal to
+``lax.top_k`` on the chip (my chip run, PR 37):
+
+    shape                 m = 1    m = 2    m = 4    m = 8   lax.top_k
+    [256, 4, 50 000]      2.236    1.193    1.206    1.406   69.4
+    [64, 4, 50 000]       0.821    0.748    0.789    0.799   26.2
+    [1 024, 50 128]       2.249    1.222    1.279    1.431   1.85
+    [256, 4, 152 064]     8.292    8.786    8.848    9.679   404 (PR 26)
+
+``_plan(n, k)`` takes m from those: ``PICKS_A_PASS`` = 2, never more
+than cover k.  Two a pass loses 6-13% to one a pass where the row's
+length is a multiple of 128 and 32 768 or more (at [256, 4, n], n =
+32 768 / 65 536 / 131 072 / 152 064: m = 1 1.81 / 3.46 / 7.03 / 8.30
+against m = 2 1.93 / 3.76 / 7.97 / 8.78) and wins everywhere else
+(16 384 and 100 000: 1.01 / 4.41 against 0.97 / 2.79): a vocabulary
+is seldom such a multiple, so there is no rule by length.  The
+other exact form PR 26 tried (contiguous bin maxima, ``lax.top_k`` over
+them, gather of k bins and re-rank) took 4.2 ms at the first shape: its
+small sorts are rank 3 too.
+
+A kernel that reads the row ONCE (it walks the row in index order and
+keeps eight a sublane, 0.49 ms at the first shape) was built, was
+bit-equal, and waits: PERF.md section 7.  Timings and the choice:
+PERF.md section 6, "PR 26" and "PR 37".
 
 Where it agrees with ``lax.top_k``: every floating row without NaN
 (``lax.top_k`` sorts in a total order, NaN first and +0.0 before -0.0;
@@ -64,10 +92,12 @@ Array = jax.Array
 #: selection 0.42 ms; my chip run, PR 26), and the tests' vocabularies
 #: of a few hundred stay on the stock lowering
 MIN_ROW = 512
-#: one pass a pick, unrolled: cost is linear in k, and 16 (beam 8) is
-#: as far as the k = 8 timing is stretched (at 512 the sort's 1.04 ms
-#: buys about 20 passes)
-MAX_PASSES = 16
+#: the passes are unrolled and their cost is linear in k: 16 (beam 8)
+#: is as far as the k = 8 timing is stretched (at 512 the sort's 1.04
+#: ms buys about 20 single picks)
+MAX_K = 16
+#: picks a pass (a power of two; module docstring for the timings)
+PICKS_A_PASS = 2
 #: the candidates rank instead of the extended row only where they are
 #: at most this share of the vocabulary's row.  Timed at ONE shape,
 #: [256, 4, 50 000] with 400 article positions (candidates 0.8% of the
@@ -81,26 +111,59 @@ MAX_CANDIDATE_SHARE = 1 / 8
 _NO_ID = jnp.iinfo(jnp.int32).max
 
 
-def _plan(n: int, k: int) -> str:
-    """``"select"`` or ``"lax"`` for a row of length n and k picks:
-    all that is known at trace time (under `vmap` the leading axes are
-    not), and so all the choice may rest on."""
-    return "select" if n >= MIN_ROW and k <= MAX_PASSES else "lax"
+def _plan(n: int, k: int) -> int:
+    """The picks a pass of the selection, for a row of length n and k
+    picks, or 0 for ``lax.top_k``: all that is known at trace time
+    (under `vmap` the leading axes are not), and so all the choice may
+    rest on."""
+    if n < MIN_ROW or k > MAX_K:
+        return 0
+    return min(PICKS_A_PASS, 1 << (k - 1).bit_length())
+
+
+def _a_first(a, b):
+    """Whether ``top_k`` lists the (value, index) pair a before b."""
+    (av, ai), (bv, bi) = a, b
+    return (av > bv) | ((av == bv) & (ai < bi))
 
 
 def _first(a, b):
     """Of two (value, index) pairs, the one ``top_k`` lists first."""
-    (av, ai), (bv, bi) = a, b
-    a_first = (av > bv) | ((av == bv) & (ai < bi))
-    return jnp.where(a_first, av, bv), jnp.where(a_first, ai, bi)
+    a_first = _a_first(a, b)
+    return jnp.where(a_first, a[0], b[0]), jnp.where(a_first, a[1], b[1])
 
 
-def _select(x: Array, k: int, ids: Optional[Array] = None,
+def _in_order(a, b):
+    """Two (value, index) pairs as ``top_k`` lists them."""
+    a_first = _a_first(a, b)
+    return ((jnp.where(a_first, a[0], b[0]), jnp.where(a_first, a[1], b[1])),
+            (jnp.where(a_first, b[0], a[0]), jnp.where(a_first, b[1], a[1])))
+
+
+def _merge(a, b):
+    """The m first of two lists of m pairs, each in ``top_k``'s order
+    (m a power of two): the half-cleaner of a bitonic merge — place by
+    place the first of a[j] and b[m - 1 - j] are the m first of the 2m,
+    and bitonic — then the log2 m compare-exchange stages that order a
+    bitonic list.  m = 1 is ``_first``."""
+    m = len(a)
+    c = [_first(a[j], b[m - 1 - j]) for j in range(m)]
+    half = m // 2
+    while half:
+        for lo in range(m):
+            if not lo & half:
+                c[lo], c[lo + half] = _in_order(c[lo], c[lo + half])
+        half //= 2
+    return c
+
+
+def _select(x: Array, k: int, ids: Optional[Array] = None, m: int = 1,
             ) -> Tuple[Array, Array]:
     """The k first of the last axis in (value descending, id ascending)
-    order.  ``ids`` None: an element's id is its index.  ``ids`` given
-    (int32, x's shape, under ``_NO_ID``): elements equal in value AND
-    id are one element, picked once."""
+    order, m (a power of two) a pass.  ``ids`` None: an element's id is
+    its index.  ``ids`` given (int32, x's shape, under ``_NO_ID``; m
+    is 1): elements equal in value AND id are one element, picked
+    once."""
     axis = x.ndim - 1
     if ids is None:
         idx = lax.broadcasted_iota(jnp.int32, x.shape, axis)
@@ -110,35 +173,51 @@ def _select(x: Array, k: int, ids: Optional[Array] = None,
     # a spent element reads (-inf, beyond): it loses to every live one,
     # a live -inf included (its id is under that), and k <= n leaves one
     spent = (jnp.array(-jnp.inf, x.dtype), jnp.int32(beyond))
+    # an element enters a pass as the list of itself and m - 1 spent
+    fill = [(jnp.full(x.shape, s, s.dtype),) * (m - 1) if m > 1 else ()
+            for s in spent]
     vals, picks = [], []
     xv, xi = x, idx
-    for j in range(k):
+    for j in range(0, k, m):
         if j:  # what comes after the last pick, in top_k's order
             pv, pi = vals[-1][..., None], picks[-1][..., None]
             live = (x < pv) | ((x == pv) & (idx > pi))
             xv = jnp.where(live, x, spent[0])
             xi = jnp.where(live, idx, spent[1])
-        v, i = lax.reduce((xv, xi), spent, _first, (axis,))
-        vals.append(v)
-        picks.append(i)
-    return jnp.stack(vals, axis=-1), jnp.stack(picks, axis=-1)
+        out = lax.reduce(
+            (xv,) + fill[0] + (xi,) + fill[1],
+            (spent[0],) * m + (spent[1],) * m,
+            lambda a, b: _unzip(_merge(_zip(a), _zip(b))), (axis,))
+        vals.extend(out[:m])
+        picks.extend(out[m:])
+    return jnp.stack(vals[:k], axis=-1), jnp.stack(picks[:k], axis=-1)
+
+
+def _zip(flat):
+    """(v0 .. vm-1, i0 .. im-1), a variadic reduce's side, as pairs."""
+    m = len(flat) // 2
+    return list(zip(flat[:m], flat[m:]))
+
+
+def _unzip(pairs):
+    return tuple(v for v, _ in pairs) + tuple(i for _, i in pairs)
 
 
 def top_k(x: Array, k: int) -> Tuple[Array, Array]:
     """``jax.lax.top_k(x, k)``, by selection where ``_plan`` says the
     row is long enough for it to win (module docstring)."""
     n = x.shape[-1]
-    if (not 0 < k <= n or not jnp.issubdtype(x.dtype, jnp.floating)
-            or _plan(n, k) == "lax"):
+    m = _plan(n, k) if 0 < k <= n else 0
+    if not m or not jnp.issubdtype(x.dtype, jnp.floating):
         return lax.top_k(x, k)
-    return _select(x, k)
+    return _select(x, k, m=m)
 
 
 def _mixture_plan(v: int, t_enc: int, k: int) -> str:
     """``"candidates"`` or ``"dense"`` for a vocabulary row of length
     v, t_enc article positions and k picks: the two shapes the trace
     can see, as ``_plan`` chooses."""
-    if _plan(v, k) == "select" and (k + t_enc) <= v * MAX_CANDIDATE_SHARE:
+    if _plan(v, k) and (k + t_enc) <= v * MAX_CANDIDATE_SHARE:
         return "candidates"
     return "dense"
 
