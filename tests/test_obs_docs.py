@@ -105,8 +105,10 @@ def test_wildcard_families_really_exist():
 
 
 @pytest.mark.parametrize("span_name", [
-    "serve/dispatch", "decode/batch", "decode/slot_chunk",
-    "train/metrics_flush",
+    "serve/dispatch", "decode/batch", "serve/dispatch/wait_mask",
+    "train/metrics_flush", "serve/harvest/resolve", "decode/unpack/dispatch",
+    "decode/unpack/fetch", "decode/unpack/result", "decode/prefill/batch",
+    "decode/prefill/dispatch",
 ])
 def test_documented_span_names_exist_in_code(span_name):
     """The doc's span-name list points at real span call sites."""

@@ -498,8 +498,8 @@ class TestPhaseBracket:
         assert _RecordingAnnotation.log[2][2] == {"slot": 3}
 
     def test_flat_end_inside_a_phase_is_a_child_too(self, monkeypatch):
-        """compiled_call(phase=...) books through start()/end(): inside
-        a phase() it must not count twice toward coverage."""
+        """A flat start()/end() pair closed inside a phase() is a child:
+        it must not count twice toward coverage."""
         _, clock, prof = self._prof(monkeypatch)
         with prof.wall("serve/tick"):
             with prof.phase("serve/dispatch"):
@@ -509,6 +509,53 @@ class TestPhaseBracket:
         assert prof.phase_stats()["decode/beam_search"][0] == 1
         assert prof.coverage() == pytest.approx(1.0)
         assert [r[1] for r in prof.recent_phases()] == ["serve/dispatch"]
+
+    def test_compiled_call_phase_is_the_one_bracket(self, monkeypatch):
+        """compiled_call(phase=...) opens a Profiler.phase around the
+        call — annotation, span and ledger, a child inside an open phase
+        — and the compile ledger still sees the miss, then the hit."""
+        reg, _, prof = self._prof(monkeypatch)
+        fn = jax.jit(lambda x: x * 3.0)
+        with prof.wall("serve/tick"):
+            with prof.phase("serve/harvest/unpack"):
+                profile_lib.compiled_call(reg, "decode/unpack_slot_jit", fn,
+                                          jnp.ones((2,)), key="k",
+                                          phase="decode/unpack/dispatch")
+        profile_lib.compiled_call(reg, "decode/unpack_slot_jit", fn,
+                                  jnp.ones((2,)), key="k",
+                                  phase="decode/unpack/dispatch")
+        opened = [n for k, n, _ in _RecordingAnnotation.log if k == "enter"]
+        assert opened == ["serve/tick", "serve/harvest/unpack",
+                          "decode/unpack/dispatch", "decode/unpack/dispatch"]
+        site = prof.compile_stats()["decode/unpack_slot_jit"]
+        assert (site["compiles"], site["hits"]) == (1, 1)
+        assert reg.counter(
+            "decode/compile_cache_misses_total").value == 1.0
+        assert prof.phase_stats()["decode/unpack/dispatch"][0] == 2
+        spans = [s for s in obs.tracer_for(reg).finished()
+                 if s.name == "decode/unpack/dispatch"]
+        assert [s.parent for s in spans] == ["serve/harvest/unpack", None]
+        # inside a phase it is a child: out of the ring
+        assert [r[1] for r in prof.recent_phases()] == [
+            "serve/harvest/unpack", "decode/unpack/dispatch"]
+
+    def test_phase_and_wall_buckets_are_a_quarter_octave(self):
+        """A p95 read from the phase ledger is within 19% of the value
+        (the doubling default bucket was a factor of two)."""
+        reg = Registry()
+        prof = profile_lib.install_profiler(reg)
+        for name in ("profile/phase_seconds", "profile/wall_seconds"):
+            bs = reg.histogram(name).buckets
+            assert bs[0] == pytest.approx(1e-5)
+            assert bs[-1] > 100.0
+            ratios = {round(b / a, 9) for a, b in zip(bs, bs[1:])}
+            assert ratios == {round(2.0 ** 0.25, 9)}
+        # the labelled child is made once and kept
+        prof.end("serve/pack", prof.start())
+        child = prof._phase_series["serve/pack"]
+        prof.end("serve/pack", prof.start())
+        assert prof._phase_series["serve/pack"] is child
+        assert child.count == 2 and child.buckets == profile_lib.PHASE_BUCKETS
 
     def test_a_raising_body_still_closes_everything(self, monkeypatch):
         reg, clock, prof = self._prof(monkeypatch)

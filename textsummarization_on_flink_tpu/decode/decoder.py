@@ -810,14 +810,15 @@ class SlotDecodeEngine:
         return jax.device_put(
             state, reg.shardings(reg.slot_state_specs(state)))
 
-    def _jitted(self, site, fn, *args, key="", **kw):
+    def _jitted(self, site, fn, *args, key="", phase=None, **kw):
         """Run a slot kernel through the shared compile ledger
         (obs/profile.py, ISSUE 16): the jit-cache hit/miss telemetry
         this method used to hand-roll, plus per-site compile events so
         'no per-request recompiles' is runtime-monitored — growth past
-        the committed warm-set budget is a compile storm."""
+        the committed warm-set budget is a compile storm.  `phase`
+        brackets the call as that ``Profiler.phase``."""
         return profile_lib.compiled_call(self._obs, site, fn, *args,
-                                         key=key, **kw)
+                                         key=key, phase=phase, **kw)
 
     def _ensure_state(self, params) -> None:
         if self._state is not None:
@@ -861,17 +862,23 @@ class SlotDecodeEngine:
         prefill_jit compile per bucket, cost scaling with the bucket —
         returning the padded, valid-length-stamped handle pack()
         scatters into a slot.  Safe to run while other articles are
-        resident (the scheduler overlaps prefill with decode ticks)."""
+        resident (the scheduler overlaps prefill with decode ticks).
+        Its host work is two child phases: `decode/prefill/batch` (the
+        article's arrays) and `decode/prefill/dispatch`."""
         params = self._params()
         bucket = bucket_for(self._buckets, example.enc_len)
-        batch = Batch([example], self._hps1, self._dec._vocab,
-                      enc_steps=bucket)
-        arrays = {k: v for k, v in batch.as_arrays().items()
-                  if k.startswith("enc_")}
+        with self._prof.phase("decode/prefill/batch"):
+            batch = Batch([example], self._hps1, self._dec._vocab,
+                          enc_steps=bucket)
+            arrays = {k: v for k, v in batch.as_arrays().items()
+                      if k.startswith("enc_")}
         self._register_prefill_cost(bucket)
         pre = self._jitted("decode/prefill_jit", beam_search.prefill_jit,
-                           params, self._hps, arrays, key=bucket)
+                           params, self._hps, arrays, key=bucket,
+                           phase="decode/prefill/dispatch")
         if self._registry is not None:
+            # the placement on a mesh: inside serve/prefill, in neither
+            # child (the dispatch child is compiled_call's own bracket)
             import jax
 
             reg = self._registry
@@ -977,46 +984,50 @@ class SlotDecodeEngine:
         if not self._active.any():
             return []
         params = self._params()
-        # chunk-level span: tick-scoped, not request-scoped (a chunk
-        # serves every resident at once, so there is no single parent
-        # trace) — a request's timeline correlates with these spans by
-        # timestamp via its slot/tick lifecycle events, not by trace_id
-        with self._prof.phase("decode/slot_chunk",
-                              active=int(self._active.sum())):
-            self._state, finished = self._jitted(
-                "decode/step_slots_jit", beam_search.step_slots_jit,
-                *self._step_args(params))
-            self._state = self._pin_state(self._state)
-            # the one sanctioned chunk-boundary sync: the host scheduler
-            # needs the finished mask to retire and refill slots.  Its
-            # own child phase: dispatching the chunk is host work, this
-            # is the wait for the device
-            with self._prof.phase("serve/dispatch/wait_mask"):
-                mask = np.asarray(finished)
-            return [int(i) for i in np.nonzero(mask)[0]]
+        self._state, finished = self._jitted(
+            "decode/step_slots_jit", beam_search.step_slots_jit,
+            *self._step_args(params))
+        self._state = self._pin_state(self._state)
+        # the one sanctioned chunk-boundary sync: the host scheduler
+        # needs the finished mask to retire and refill slots.  A child
+        # of the scheduler's serve/dispatch: dispatching the chunk is
+        # host work, this is the wait for the device
+        with self._prof.phase("serve/dispatch/wait_mask"):
+            mask = np.asarray(finished)
+        return [int(i) for i in np.nonzero(mask)[0]]
 
     def unpack(self, idx: int, example) -> DecodedResult:
         """Retire slot `idx`: finalize its hypothesis and free the slot.
         `example` is the SummaryExample packed into it (uuid/reference/
-        OOV map travel with the request, not the device state)."""
+        OOV map travel with the request, not the device state).  Its
+        host work is three child phases: `decode/unpack/dispatch` (the
+        unpack program), `decode/unpack/fetch` (every device->host read
+        of its output) and `decode/unpack/result`."""
         if not self._active[idx]:
             raise AssertionError(f"slot {idx} is not resident")
         out = self._jitted("decode/unpack_slot_jit",
                            beam_search.unpack_slot_jit, self._hps,
-                           self._state, idx, self._table[idx])
+                           self._state, idx, self._table[idx],
+                           phase="decode/unpack/dispatch")
         self._free_slot_pages(idx)
         self._active[idx] = False
-        res = self._dec._make_result(
-            np.asarray(out.tokens), int(out.length),
-            np.asarray(out.attn_dists), np.asarray(out.p_gens),
-            avg_log_prob=float(out.avg_log_prob),
-            uuid=example.uuid, article=example.original_article,
-            reference=example.reference,
-            abstract_sents=example.original_abstract_sents,
-            art_oovs=example.article_oovs)
-        self._dec._c_requests.inc()
-        self._dec._c_beams.inc()
-        self._dec._c_tokens.inc(len(res.decoded_words))
+        with self._prof.phase("decode/unpack/fetch"):
+            tokens = np.asarray(out.tokens)
+            length = int(out.length)
+            attn_dists = np.asarray(out.attn_dists)
+            p_gens = np.asarray(out.p_gens)
+            avg_log_prob = float(out.avg_log_prob)
+        with self._prof.phase("decode/unpack/result"):
+            res = self._dec._make_result(
+                tokens, length, attn_dists, p_gens,
+                avg_log_prob=avg_log_prob,
+                uuid=example.uuid, article=example.original_article,
+                reference=example.reference,
+                abstract_sents=example.original_abstract_sents,
+                art_oovs=example.article_oovs)
+            self._dec._c_requests.inc()
+            self._dec._c_beams.inc()
+            self._dec._c_tokens.inc(len(res.decoded_words))
         return res
 
     def _free_slot_pages(self, idx: int) -> None:

@@ -12,7 +12,8 @@ Three ledgers behind one per-registry ``Profiler`` (attached at
     histogram plus a phases-sum-to-wall accounting check
     (``profile/phase_coverage_ratio``).  The clock is injectable so the
     tier-1 gate drives it in virtual time.  ``Profiler.phase(name)`` is
-    the ONE bracket the hot paths use: it books this ledger, records
+    the ONE bracket the hot paths use (``compiled_call(phase=...)``
+    opens one around its call): it books this ledger, records
     the ``obs.spans`` span and opens a ``jax.profiler.TraceAnnotation``
     for the same interval, so a profiler capture holds the host phases
     on the device trace's own clock.  A phase opened inside another on
@@ -56,7 +57,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from textsummarization_on_flink_tpu.obs import flightrec
 from textsummarization_on_flink_tpu.obs import spans as spans_lib
-from textsummarization_on_flink_tpu.obs.registry import Registry
+from textsummarization_on_flink_tpu.obs.registry import (
+    Registry,
+    exponential_buckets,
+)
 
 #: bounded ring of recent phase records — feeds the /profile top-k
 #: slowest-dispatch table and the windowed coverage check in tests
@@ -73,6 +77,10 @@ BASELINE_SAMPLES = 3
 #: default measured-vs-baseline wall inflation that fires the
 #: ``perf_divergence`` dump (overridden by hps.profile_divergence_factor)
 DEFAULT_DIVERGENCE_FACTOR = 5.0
+#: profile/phase_seconds and profile/wall_seconds bounds: a quarter-octave
+#: wide from 10 us to ~141 s (the stage clock's rule, serve/batcher.py),
+#: so a percentile read from the buckets is within 19% of the value
+PHASE_BUCKETS = exponential_buckets(1e-5, 2.0 ** 0.25, 96)
 
 
 def _trace_annotation():
@@ -262,9 +270,14 @@ class Profiler:
         self._div: Dict[Tuple[str, Any], Dict[str, float]] = {}
         self._notes: List[Dict[str, Any]] = []
         # metric families (literal names — the doc-drift gate reads the
-        # source): children are created per label value at record time
-        self._h_phase = registry.histogram("profile/phase_seconds")
-        self._h_wall = registry.histogram("profile/wall_seconds")
+        # source): a child per label value, created on its first record
+        # and kept here, so a bracket's exit does no labels() lookup
+        self._h_phase = registry.histogram("profile/phase_seconds",
+                                           buckets=PHASE_BUCKETS)
+        self._h_wall = registry.histogram("profile/wall_seconds",
+                                          buckets=PHASE_BUCKETS)
+        self._phase_series: Dict[str, Any] = {}
+        self._wall_series: Dict[str, Any] = {}
         self._g_coverage = registry.gauge("profile/phase_coverage_ratio")
         self._c_compiles = registry.counter("profile/compile_events_total")
         self._h_compile = registry.histogram("profile/compile_seconds")
@@ -311,7 +324,6 @@ class Profiler:
         (its parent already accounts for the interval)."""
         dt = self._clock() - t0
         child = self._depth() > 0
-        ts_us = int(time.time() * 1e6)  # serialized epoch stamp only
         with self._lock:
             agg = self._phases.get(phase)
             if agg is None:
@@ -323,10 +335,15 @@ class Profiler:
             if child:
                 self._nested_s += dt
             else:
-                self._recent.append((ts_us, phase, dt, trace_id))
+                # serialized epoch stamp only
+                self._recent.append((int(time.time() * 1e6), phase, dt,
+                                     trace_id))
                 if len(self._recent) > RECENT_PHASES_CAP:
                     del self._recent[:len(self._recent) - RECENT_PHASES_CAP]
-        self._h_phase.labels(phase=phase).observe(dt, trace_id=trace_id)
+        h = self._phase_series.get(phase)
+        if h is None:
+            h = self._phase_series[phase] = self._h_phase.labels(phase=phase)
+        h.observe(dt, trace_id=trace_id)
         return dt
 
     def end_wall(self, name: str, t0: float) -> float:
@@ -342,7 +359,10 @@ class Profiler:
             if dt > agg[2]:
                 agg[2] = dt
             cov = self._coverage_locked()
-        self._h_wall.labels(wall=name).observe(dt)
+        h = self._wall_series.get(name)
+        if h is None:
+            h = self._wall_series[name] = self._h_wall.labels(wall=name)
+        h.observe(dt)
         self._g_coverage.set(cov)
         return dt
 
@@ -640,16 +660,22 @@ def compiled_call(registry: Optional[Registry], site: str, fn: Callable,
     decode/speculative.py, decode/decoder.py).  Cache growth across the
     call = a fresh trace/compile; hit/miss lands in the established
     ``decode/compile_cache_*_total`` counters AND the compile ledger,
-    and `phase` (when given) books the measured wall into the phase
-    ledger too — one timing, both ledgers."""
+    and `phase` (when given) opens a ``Profiler.phase`` around the call
+    — ledger, span and annotation — whose booked wall the compile
+    ledger reuses: one timing, both ledgers."""
     try:  # private jax API; telemetry must never break the dispatch
         before = fn._cache_size()
     except Exception:  # tslint: disable=TS005 — _cache_size is a private jax API; absent on some builds
         before = None
     prof = profiler_for(registry)
-    t0 = prof.start()
-    out = fn(*args, **kw)
-    dt = prof.end(phase, t0) if phase is not None else (prof.start() - t0)
+    if phase is None:
+        t0 = prof.start()
+        out = fn(*args, **kw)
+        dt = prof.start() - t0
+    else:
+        with prof.phase(phase) as ph:
+            out = fn(*args, **kw)
+        dt = ph.dt
     if before is not None:
         try:
             missed = fn._cache_size() > before

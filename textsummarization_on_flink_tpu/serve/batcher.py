@@ -587,24 +587,28 @@ class ContinuousBatcher:
                 continue
             with self._prof.phase("serve/harvest/unpack", slot=idx):
                 res = self._engine.unpack(idx, req.example)
-            self._resident[idx] = None
-            self._h_resident.observe(self._chunks[idx])
-            self._c_tenant_tokens.labels(
-                tenant=req.tenant or "default").inc(
-                len(getattr(res, "decoded_words", ()) or ()))
-            self._c_done.inc()
-            harvest_ms = self._close_stage(req, "harvest")
-            # stamped per request where its future resolves, so the
-            # harvest is inside the latency.  Exemplar (ISSUE 15): the
-            # landing latency bucket remembers THIS request's trace_id,
-            # so a fat p99 bucket on /metrics names a uuid to chase
-            e2e_s = req.stage_t - req.enqueue_t
-            self._h_e2e.observe(e2e_s, trace_id=_trace_id(req))
-            obs.spans.request_event(
-                self._reg, "finish", req.trace, req.uuid, slot=idx,
-                chunks=self._chunks[idx], resident_ms=resident_ms[idx],
-                harvest_ms=harvest_ms, e2e_ms=round(e2e_s * 1e3, 3))
-            req.future._resolve(res)
+            # the bookkeeping and the future's callbacks: the rest of
+            # this request's host work in the harvest
+            with self._prof.phase("serve/harvest/resolve"):
+                self._resident[idx] = None
+                self._h_resident.observe(self._chunks[idx])
+                self._c_tenant_tokens.labels(
+                    tenant=req.tenant or "default").inc(
+                    len(getattr(res, "decoded_words", ()) or ()))
+                self._c_done.inc()
+                harvest_ms = self._close_stage(req, "harvest")
+                # stamped per request where its future resolves, so the
+                # harvest is inside the latency.  Exemplar: the landing
+                # latency bucket remembers THIS request's trace_id, so a
+                # fat p99 bucket on /metrics names a uuid to chase
+                e2e_s = req.stage_t - req.enqueue_t
+                self._h_e2e.observe(e2e_s, trace_id=_trace_id(req))
+                obs.spans.request_event(
+                    self._reg, "finish", req.trace, req.uuid, slot=idx,
+                    chunks=self._chunks[idx],
+                    resident_ms=resident_ms[idx], harvest_ms=harvest_ms,
+                    e2e_ms=round(e2e_s * 1e3, 3))
+                req.future._resolve(res)
         self._set_active_gauge()
 
     def _arena_backpressure(self, needed: int, free: int) -> None:
