@@ -703,8 +703,9 @@ class TestServeChaos:
                 time.sleep(0.1)
                 return self._inner.step()
 
-            def unpack(self, idx, example):
-                return self._inner.unpack(idx, example)
+            def unpack_many(self, pairs, slot_bracket=None):
+                return self._inner.unpack_many(pairs,
+                                               slot_bracket=slot_bracket)
 
             def release(self, idx):
                 self._inner.release(idx)

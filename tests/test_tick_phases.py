@@ -2,11 +2,15 @@
 ``SlotDecodeEngine`` under the ``ContinuousBatcher``, driven one round at
 a time on this thread until every request resolves.
 
-Each request's unpack is three child phases of ``serve/harvest/unpack``
-(``decode/unpack/{dispatch,fetch,result}``), the harvest's bookkeeping is
-``serve/harvest/resolve``, and each prefill is ``decode/prefill/batch`` and
-``decode/prefill/dispatch`` under ``serve/prefill``: booked once each, in
-the span tree under the phase named, and out of the coverage sum.
+A harvest retires its finished slots with one device->host read: each
+request's unpack program is ``decode/unpack/dispatch`` inside its own
+``serve/harvest/unpack``, the one read of every slot's output is
+``decode/unpack/fetch`` (once a harvest), and each request's
+``decode/unpack/result`` and bookkeeping (``serve/harvest/resolve``)
+follow, all three directly under ``serve/harvest``.  Each prefill is
+``decode/prefill/batch`` and ``decode/prefill/dispatch`` under
+``serve/prefill``.  Each is booked as often as named, in the span tree
+under the phase named, and out of the coverage sum.
 """
 
 import pytest
@@ -27,8 +31,8 @@ N = 4
 #: (child phase, the phase it opens inside) on the serving path
 PARENT_OF = {
     "decode/unpack/dispatch": "serve/harvest/unpack",
-    "decode/unpack/fetch": "serve/harvest/unpack",
-    "decode/unpack/result": "serve/harvest/unpack",
+    "decode/unpack/fetch": "serve/harvest",
+    "decode/unpack/result": "serve/harvest",
     "serve/harvest/resolve": "serve/harvest",
     "serve/harvest/unpack": "serve/harvest",
     "decode/prefill/batch": "serve/prefill",
@@ -54,15 +58,15 @@ def model():
     return hps, vocab, params
 
 
-def _serve(hps, vocab, params, tmp_path, reg):
-    """Submit N requests and tick the real server on this thread until
+def _serve(hps, vocab, params, tmp_path, reg, n=N):
+    """Submit `n` requests and tick the real server on this thread until
     all resolve; returns the results and the engine."""
     from textsummarization_on_flink_tpu.serve.server import ServingServer
 
     server = ServingServer(hps, vocab, params=params,
                            decode_root=str(tmp_path), registry=reg)
     futures = [server.submit(ARTICLES[i % len(ARTICLES)], uuid=f"t{i}")
-               for i in range(N)]
+               for i in range(n)]
     for _ in range(200):
         if all(f.done() for f in futures):
             break
@@ -80,11 +84,14 @@ def test_each_child_phase_is_booked_once_under_its_parent(model, tmp_path):
     assert [r.uuid for r in results] == [f"t{i}" for i in range(N)]
     prof = reg.profile
     counts = {k: v[0] for k, v in prof.phase_stats().items()}
-    # once a request: the unpack's three parts and the harvest's rest
+    # once a request: its unpack program, its result and its rest
     for name in ("serve/harvest/unpack", "decode/unpack/dispatch",
-                 "decode/unpack/fetch", "decode/unpack/result",
-                 "serve/harvest/resolve"):
+                 "decode/unpack/result", "serve/harvest/resolve"):
         assert counts[name] == N, name
+    # once a harvest that retires anything: the one read of its slots
+    shared = reg.histogram("serve/harvest_slots")
+    assert counts["decode/unpack/fetch"] == shared.count
+    assert shared.sum == N
     # once a prefill: the batch and the dispatch
     assert counts["serve/prefill"] == N
     assert counts["decode/prefill/batch"] == counts["serve/prefill"]
@@ -111,6 +118,34 @@ def test_each_child_phase_is_booked_once_under_its_parent(model, tmp_path):
         pytest.approx(prof.coverage(), rel=1e-6)
     # the recent ring holds top-level phases only
     assert {r[1] for r in prof.recent_phases()} <= set(TOP_LEVEL)
+
+
+def test_slots_finishing_together_share_one_read(model, tmp_path):
+    """Two articles packed in one tick that cannot stop before
+    max_dec_steps finish in the same chunk: their harvest makes one
+    fetch for both, after both dispatches."""
+    hps, vocab, params = model
+    lockstep = hps.replace(min_dec_steps=hps.max_dec_steps)
+    n = 2
+    with obs.use_registry(Registry()) as reg:
+        results, _ = _serve(lockstep, vocab, params, tmp_path, reg, n=n)
+    assert [r.uuid for r in results] == ["t0", "t1"]
+    counts = {k: v[0] for k, v in reg.profile.phase_stats().items()}
+    assert counts["decode/unpack/fetch"] == 1
+    for name in ("serve/harvest/unpack", "decode/unpack/dispatch",
+                 "decode/unpack/result", "serve/harvest/resolve"):
+        assert counts[name] == n, name
+    shared = reg.histogram("serve/harvest_slots")
+    assert (shared.count, shared.sum) == (1, n)
+    # in the harvest: every dispatch, then the one read, then each
+    # request's result and resolve (siblings finish in the order they
+    # start)
+    harvest = [s.name for s in obs.tracer_for(reg).finished()
+               if s.parent == "serve/harvest"]
+    assert harvest == (["serve/harvest/unpack"] * n
+                       + ["decode/unpack/fetch"]
+                       + ["decode/unpack/result"] * n
+                       + ["serve/harvest/resolve"] * n)
 
 
 def test_a_dark_engine_takes_the_shared_null_bracket(model, tmp_path):

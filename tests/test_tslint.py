@@ -204,6 +204,37 @@ def test_ts002_default_config_names_repo_hot_loops():
     assert any("next_batch" in p for p in pats)
 
 
+def test_ts002_default_config_flags_a_per_slot_read_in_the_batched_retire(
+        tmp_path):
+    # the harvest's batched retire reads every slot's output ONCE, after
+    # its dispatch loop: a per-slot read back inside the loop is flagged
+    # under the default config, the one read outside it is not
+    r = run_snippet(tmp_path, """
+        import jax
+        import numpy as np
+
+        class SlotDecodeEngine:
+            def unpack_many(self, pairs):
+                outs = []
+                for idx, example in pairs:
+                    out = self._dispatch(idx)
+                    outs.append((np.asarray(out.tokens), int(out.length)))
+                return outs
+    """)
+    assert rules_of(r) == ["TS002", "TS002"]
+    r = run_snippet(tmp_path, """
+        import jax
+
+        class SlotDecodeEngine:
+            def unpack_many(self, pairs):
+                outs = [self._dispatch(idx) for idx, _ in pairs]
+                for idx, _ in pairs:
+                    self._release(idx)
+                return jax.device_get(outs)
+    """)
+    assert rules_of(r) == []
+
+
 # --------------------------------------------------------------------------
 # TS003 — monotonic clock
 # --------------------------------------------------------------------------

@@ -23,6 +23,7 @@ Preserved behavior:
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -676,8 +677,9 @@ class SlotDecodeEngine:
     activity mask; the scheduler above it
     (serve/batcher.ContinuousBatcher) owns request bookkeeping.
     Single-threaded by design — the one
-    continuous-dispatch thread calls prefill/pack/step/unpack; the ONLY
-    chunk boundary host sync is reading the `finished` mask in step().
+    continuous-dispatch thread calls prefill/pack/step/unpack_many; the
+    host syncs are reading the `finished` mask in step() and ONE read of
+    every retiring slot's output a harvest in unpack_many().
 
     Shape discipline: the RESIDENT state keeps one shape
     (``hps.max_enc_steps`` wide — that is what makes slot recycling
@@ -999,28 +1001,53 @@ class SlotDecodeEngine:
     def unpack(self, idx: int, example) -> DecodedResult:
         """Retire slot `idx`: finalize its hypothesis and free the slot.
         `example` is the SummaryExample packed into it (uuid/reference/
-        OOV map travel with the request, not the device state).  Its
-        host work is three child phases: `decode/unpack/dispatch` (the
-        unpack program), `decode/unpack/fetch` (every device->host read
-        of its output) and `decode/unpack/result`."""
-        if not self._active[idx]:
-            raise AssertionError(f"slot {idx} is not resident")
-        out = self._jitted("decode/unpack_slot_jit",
-                           beam_search.unpack_slot_jit, self._hps,
-                           self._state, idx, self._table[idx],
-                           phase="decode/unpack/dispatch")
-        self._free_slot_pages(idx)
-        self._active[idx] = False
+        OOV map travel with the request, not the device state).  A
+        harvest of one: see unpack_many."""
+        return self.unpack_many([(idx, example)])[0]
+
+    def unpack_many(self, pairs: List[Tuple[int, Any]],
+                    slot_bracket: Optional[Callable[[int], Any]] = None,
+                    ) -> List[DecodedResult]:
+        """Retire every slot of `pairs` ([(idx, example), ...]) and
+        return their results in that order, with ONE device->host read
+        for all of them.
+
+        Three steps, each a child phase of the caller's: every slot's
+        unpack program is dispatched (`decode/unpack/dispatch`, the one
+        `unpack_slot_jit` compile) and its pages and activity bit
+        released; then every output of every slot comes back in one
+        `decode/unpack/fetch`; then each slot's `decode/unpack/result`.
+        `slot_bracket(idx)`, where given, is the context one slot's
+        dispatch and release run in (the scheduler's per-slot phase)."""
+        import jax
+
+        for idx, _ in pairs:
+            if not self._active[idx]:
+                raise AssertionError(f"slot {idx} is not resident")
+        bracket = slot_bracket or (lambda idx: contextlib.nullcontext())
+        outs = []
+        for idx, _ in pairs:
+            with bracket(idx):
+                outs.append(self._jitted(
+                    "decode/unpack_slot_jit", beam_search.unpack_slot_jit,
+                    self._hps, self._state, idx, self._table[idx],
+                    phase="decode/unpack/dispatch"))
+                self._free_slot_pages(idx)
+                self._active[idx] = False
         with self._prof.phase("decode/unpack/fetch"):
-            tokens = np.asarray(out.tokens)
-            length = int(out.length)
-            attn_dists = np.asarray(out.attn_dists)
-            p_gens = np.asarray(out.p_gens)
-            avg_log_prob = float(out.avg_log_prob)
+            # the harvest's one sanctioned sync: device_get starts every
+            # copy before it waits on any, so the slots' reads overlap
+            fetched = jax.device_get(outs)
+        return [self._slot_result(out, example)
+                for out, (_, example) in zip(fetched, pairs)]
+
+    def _slot_result(self, out, example) -> DecodedResult:
+        """One retired slot's host-side output -> its DecodedResult and
+        the decoder's counters (`decode/unpack/result`)."""
         with self._prof.phase("decode/unpack/result"):
             res = self._dec._make_result(
-                tokens, length, attn_dists, p_gens,
-                avg_log_prob=avg_log_prob,
+                out.tokens, int(out.length), out.attn_dists, out.p_gens,
+                avg_log_prob=float(out.avg_log_prob),
                 uuid=example.uuid, article=example.original_article,
                 reference=example.reference,
                 abstract_sents=example.original_abstract_sents,

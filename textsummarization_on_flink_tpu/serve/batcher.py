@@ -26,6 +26,7 @@ contract as data/batcher.py trickle padding).
 
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 from typing import Any, Deque, List, Optional, Tuple
@@ -49,6 +50,8 @@ from textsummarization_on_flink_tpu.serve.queue import (
     RequestQueue,
     ServeRequest,
 )
+
+log = logging.getLogger(__name__)
 
 #: serve/request_stage_seconds bounds: a quarter-octave wide from 1 ms
 #: to ~131 s, so a percentile read from the buckets is within 19% of
@@ -208,8 +211,10 @@ class ContinuousBatcher:
          mid-decode starts at the NEXT chunk boundary, not the next
          batch;
       4. advances every resident slot one chunk through the engine;
-      5. harvests finished slots — each future resolves the moment ITS
-         sequence completes, independent of its neighbors.
+      5. harvests finished slots — each future resolves in the tick
+         ITS sequence completes, independent of its neighbors; an
+         engine with ``unpack_many`` retires them with one
+         device->host read for all.
 
     The engine (decode/decoder.SlotDecodeEngine, or a test stub) owns
     the device state; this class owns request bookkeeping and obs.  It
@@ -238,6 +243,10 @@ class ContinuousBatcher:
         # prefill surface (stub engines, the SLO gate's uniform
         # baseline) keep the legacy direct-pack refill.
         self._supports_prefill = hasattr(engine, "prefill")
+        # the batched retire: an engine with ``unpack_many`` retires a
+        # harvest's slots with one device->host read; the rest (the
+        # process fleet's remote engine, stubs) keep one unpack a slot
+        self._retires_many = hasattr(engine, "unpack_many")
         self._prefilled: Deque[Tuple[ServeRequest, Any]] = deque()
         self._prefill_depth = max(
             0, int(getattr(hps, "serve_prefill_depth", 0)))
@@ -274,6 +283,11 @@ class ContinuousBatcher:
         self._h_resident = reg.histogram(
             "serve/request_resident_chunks",
             buckets=[1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0])
+        # slots a batched retire reads back together, once a harvest
+        # that retires any: how often the one read is shared
+        self._h_harvest_slots = reg.histogram(
+            "serve/harvest_slots",
+            buckets=[float(2 ** i) for i in range(self.slots.bit_length())])
         self._c_refills = reg.counter("serve/slot_refills_total")
         self._c_evictions = reg.counter("serve/deadline_evictions_total")
         # prefill-stage telemetry (SERVING.md "Prefill/decode
@@ -575,41 +589,76 @@ class ContinuousBatcher:
     def _harvest(self, finished: List[int]) -> None:
         # every finished request stops being resident at ONE instant,
         # the read of the mask that retires it; what follows is its
-        # wait behind the slots unpacked before it (stage `harvest`)
+        # wait for the harvest's retire and for the requests resolved
+        # before it (stage `harvest`)
         mask_t = time.monotonic()
-        resident_ms = {idx: self._close_stage(self._resident[idx],
-                                              "resident", mask_t)
-                       for idx in finished
-                       if self._resident[idx] is not None}
-        for idx in finished:
-            req = self._resident[idx]
-            if req is None:  # pragma: no cover - defensive
-                continue
-            with self._prof.phase("serve/harvest/unpack", slot=idx):
-                res = self._engine.unpack(idx, req.example)
-            # the bookkeeping and the future's callbacks: the rest of
-            # this request's host work in the harvest
-            with self._prof.phase("serve/harvest/resolve"):
-                self._resident[idx] = None
-                self._h_resident.observe(self._chunks[idx])
-                self._c_tenant_tokens.labels(
-                    tenant=req.tenant or "default").inc(
-                    len(getattr(res, "decoded_words", ()) or ()))
-                self._c_done.inc()
-                harvest_ms = self._close_stage(req, "harvest")
-                # stamped per request where its future resolves, so the
-                # harvest is inside the latency.  Exemplar: the landing
-                # latency bucket remembers THIS request's trace_id, so a
-                # fat p99 bucket on /metrics names a uuid to chase
-                e2e_s = req.stage_t - req.enqueue_t
-                self._h_e2e.observe(e2e_s, trace_id=_trace_id(req))
-                obs.spans.request_event(
-                    self._reg, "finish", req.trace, req.uuid, slot=idx,
-                    chunks=self._chunks[idx],
-                    resident_ms=resident_ms[idx], harvest_ms=harvest_ms,
-                    e2e_ms=round(e2e_s * 1e3, 3))
-                req.future._resolve(res)
+        done = [(idx, self._resident[idx]) for idx in finished
+                if self._resident[idx] is not None]
+        resident_ms = {idx: self._close_stage(req, "resident", mask_t)
+                       for idx, req in done}
+        if self._retires_many and done:
+            self._h_harvest_slots.observe(len(done))
+            try:
+                results = self._engine.unpack_many(
+                    [(idx, req.example) for idx, req in done],
+                    slot_bracket=self._unpack_phase)
+            except Exception as e:  # tslint: disable=TS005 — every future of the harvest is rejected with the typed cause and counted in serve/errors_total by _fail_harvest
+                self._fail_harvest(done, e)
+                return
+            for (idx, req), res in zip(done, results):
+                self._resolve(idx, req, res, resident_ms[idx])
+        else:
+            for idx, req in done:
+                with self._unpack_phase(idx):
+                    res = self._engine.unpack(idx, req.example)
+                self._resolve(idx, req, res, resident_ms[idx])
         self._set_active_gauge()
+
+    def _unpack_phase(self, idx: int):
+        """The bracket of one slot's own retire work in the harvest."""
+        return self._prof.phase("serve/harvest/unpack", slot=idx)
+
+    def _resolve(self, idx: int, req: ServeRequest, res: Any,
+                 resident_ms: float) -> None:
+        """The bookkeeping and the future's callbacks: the rest of this
+        request's host work in the harvest."""
+        with self._prof.phase("serve/harvest/resolve"):
+            self._resident[idx] = None
+            self._h_resident.observe(self._chunks[idx])
+            self._c_tenant_tokens.labels(
+                tenant=req.tenant or "default").inc(
+                len(getattr(res, "decoded_words", ()) or ()))
+            self._c_done.inc()
+            harvest_ms = self._close_stage(req, "harvest")
+            # stamped per request where its future resolves, so the
+            # harvest is inside the latency.  Exemplar: the landing
+            # latency bucket remembers THIS request's trace_id, so a
+            # fat p99 bucket on /metrics names a uuid to chase
+            e2e_s = req.stage_t - req.enqueue_t
+            self._h_e2e.observe(e2e_s, trace_id=_trace_id(req))
+            obs.spans.request_event(
+                self._reg, "finish", req.trace, req.uuid, slot=idx,
+                chunks=self._chunks[idx], resident_ms=resident_ms,
+                harvest_ms=harvest_ms, e2e_ms=round(e2e_s * 1e3, 3))
+            req.future._resolve(res)
+
+    def _fail_harvest(self, done: List[Tuple[int, ServeRequest]],
+                      error: BaseException) -> None:
+        """Reject every request of a failed batched retire with `error`
+        and free its slot: a failed unpack dispatch or read fails its
+        harvest only, and the other residents decode on.  Releasing a
+        slot the retire already freed is a no-op."""
+        for idx, req in done:
+            self._engine.release(idx)
+            self._resident[idx] = None
+            self._close_stage(req, "harvest")
+            req.future._reject(error)
+        self._c_errors.inc(len(done))
+        self._set_active_gauge()
+        flightrec.trigger(self._reg, "serve_dispatch",
+                          error=type(error).__name__, harvest=len(done))
+        log.error("harvest retire failed; rejected %d request(s)",
+                  len(done), exc_info=error)
 
     def _arena_backpressure(self, needed: int, free: int) -> None:
         """Account one admit-blocked-on-pages event: count it, and dump

@@ -47,7 +47,7 @@ DEFAULT: Dict[str, Any] = {
                 # request's chunk cadence
                 r"^ContinuousBatcher\.(tick|_refill|_harvest|_evict_expired)$",
                 r"^ServingServer\._run_continuous$",
-                r"^SlotDecodeEngine\.(pack|step|unpack|prefill)$",
+                r"^SlotDecodeEngine\.(pack|step|unpack|unpack_many|prefill)$",
                 # prefill/decode disaggregation (ISSUE 11): the prefill
                 # stage runs once per admission on the dispatch thread,
                 # and the blocked/masked attention closures trace into
